@@ -1,0 +1,156 @@
+"""What can be checked about the chip path WITHOUT a chip: where the
+compile cache lands, that every shipped Pallas variant lowers for the
+TPU at the shapes ``chip_smoke.py`` runs, that the smoke refuses a
+machine with no TPU, and that its phase command lines run (the same
+table at toy widths, on the CPU)."""
+
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+import chip_smoke
+from veles import backends
+from veles.znicz_tpu.parallel import pallas_attention as PA
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture
+def config_updates(monkeypatch):
+    """Every ``jax.config.update`` call made while the fixture is
+    live, recorded instead of applied."""
+    calls = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda name, value: calls.append((name, value)))
+    return calls
+
+
+def test_compile_cache_follows_the_environment(monkeypatch, tmp_path,
+                                               config_updates):
+    """With $JAX_COMPILATION_CACHE_DIR set the helper sets nothing in
+    code: jax reads the variable itself."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert backends.enable_compile_cache() == str(tmp_path)
+    assert config_updates == []
+
+
+def test_compile_cache_defaults_to_the_checkout(monkeypatch, tmp_path,
+                                                config_updates):
+    """Unset, the cache is <checkout>/.jax_compile_cache whatever the
+    working directory — computed from the package's own path."""
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    want = os.path.join(REPO, ".jax_compile_cache")
+    for cwd in (tmp_path, REPO):
+        monkeypatch.chdir(cwd)
+        assert backends.enable_compile_cache() == want
+    assert [value for name, value in config_updates
+            if name.endswith("_dir")] == [want, want]
+
+
+def test_every_pallas_variant_lowers_for_tpu(monkeypatch):
+    """Each variant of the smoke's table, ``interpret=False``, at the
+    full train_long shapes in bf16, lowers to a Mosaic custom call for
+    the TPU platform. Lowering is not compiling (VMEM limits and tile
+    alignment are the chip run's job), but it is where
+    ``acc_dtype=bf16`` was caught asking the MXU for a bf16
+    accumulator — and it needs no chip."""
+    # the fused backward sizes its VMEM grant from the device it
+    # compiles for; there is none here, so name the v5e's 128 MiB
+    monkeypatch.setattr(PA, "_device_vmem_bytes", lambda: 128 << 20)
+    variants = chip_smoke.pallas_variants("full", False, jnp.bfloat16)
+    assert len(variants) == 7
+    for name, (fn, specs) in variants.items():
+        text = fn.trace(*specs).lower(
+            lowering_platforms=("tpu",)).as_text()
+        assert "tpu_custom_call" in text, name
+
+
+#: run in a child: loading libtpu's compiler is kept out of the test
+#: process. Prints NO_TOPOLOGY when libtpu cannot describe a v5e here.
+_COMPILE_FOR_V5E = """
+import sys
+import jax, jax.numpy as jnp
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+import chip_smoke
+from veles.znicz_tpu.parallel import pallas_attention as PA
+try:
+    topo = topologies.get_topology_desc(topology_name="v5e:2x2",
+                                        platform="tpu")
+except Exception as exc:
+    print("NO_TOPOLOGY %s" % exc)
+    sys.exit(0)
+PA._device_vmem_bytes = lambda: 128 << 20
+on_chip = SingleDeviceSharding(topo.devices[0])
+for name, (fn, specs) in chip_smoke.pallas_variants(
+        "full", False, jnp.bfloat16).items():
+    fn.lower(*(jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=on_chip)
+               for s in specs)).compile()
+    print("COMPILED %s" % name, flush=True)
+"""
+
+
+@pytest.mark.slow
+def test_every_pallas_variant_compiles_for_v5e(tmp_path):
+    """The step past lowering, still without a chip: libtpu is
+    installed, so a compile-only v5e topology runs the real TPU
+    compiler — Mosaic's VMEM limits and (8,128) tiling rules included
+    (this is where the pipelined forward's 64-lane DMA window was
+    refused) — over the same table at the same shapes."""
+    proc = subprocess.run(
+        [sys.executable, "-c", _COMPILE_FOR_V5E], cwd=REPO,
+        env=dict(os.environ, JAX_PLATFORMS="cpu",
+                 TPU_WORKER_HOSTNAMES="localhost",
+                 # TPU executables are ~100 MB each: not in the repo's
+                 # cache, which the chip tool copies
+                 JAX_COMPILATION_CACHE_DIR=str(tmp_path)),
+        capture_output=True, text=True, timeout=600)
+    if "NO_TOPOLOGY" in proc.stdout:
+        pytest.skip("libtpu gives no compile-only v5e topology here: "
+                    + proc.stdout.strip()[:200])
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert proc.stdout.count("COMPILED ") == 7, proc.stdout
+
+
+def test_smoke_refuses_a_machine_without_a_tpu():
+    """``python chip_smoke.py`` with no chip: non-zero exit, the
+    missing TPU named, no result line — and the parent process never
+    imported jax."""
+    proc = subprocess.run(
+        [sys.executable, "-c",
+         "import sys, chip_smoke\n"
+         "rc = chip_smoke.main([])\n"
+         "print('PARENT_IMPORTED_JAX=%s' % ('jax' in sys.modules))\n"
+         "sys.exit(rc)"],
+        cwd=REPO, env=dict(os.environ, JAX_PLATFORMS="cpu"),
+        capture_output=True, text=True, timeout=300)
+    assert proc.returncode != 0
+    assert "PARENT_IMPORTED_JAX=False" in proc.stdout
+    assert '"ok"' not in proc.stdout
+    assert "tpu" in proc.stderr.lower()
+    assert "chip_smoke FAILED" in proc.stderr
+
+
+def test_smoke_phases_run_at_tiny_widths_on_the_cpu():
+    """The smoke's own phase functions — the same command lines, from
+    the same table, at the "tiny" entry with ``-d cpu``: both trainer
+    runs, the kernels child (interpreted) and the server with its
+    requests. A typo in a line is found here, not on chip time."""
+    facts = chip_smoke.run_single_chip(
+        "tiny", "cpu", budget_s=600, env={"JAX_PLATFORMS": "cpu"})
+    assert facts["platform"] == "cpu"
+
+
+@pytest.mark.slow
+def test_smoke_four_chip_legs_run_at_tiny_widths_on_the_cpu():
+    """The ``--chips 4`` legs (DP=4, DP2xTP2, ring SP=4) at the tiny
+    entry on four virtual CPU devices."""
+    facts = chip_smoke.run_four_chips(
+        "tiny", "cpu", budget_s=900, env={
+            "JAX_PLATFORMS": "cpu",
+            "XLA_FLAGS": "--xla_force_host_platform_device_count=4"})
+    assert facts["count"] == 4

@@ -258,6 +258,28 @@ def test_cli_optimize_parallel_smoke(tmp_path):
     assert doc["workers"] == 2
 
 
+@pytest.mark.parametrize("device, jax_platforms", [
+    ("xla", None),      # a TPU host: the variable unset, xla = the chip
+    ("xla", "tpu"),
+    ("tpu", "cpu"),     # an explicit tpu spec is never "on the cpu"
+])
+def test_cli_optimize_workers_refuse_a_possible_tpu(
+        monkeypatch, device, jax_platforms):
+    """GENSxPOPxWORKERS spawns WORKERS trainers at once; a TPU chip
+    belongs to one process, so anything not pinned to the CPU is
+    refused before a single child starts."""
+    import veles.__main__ as vmain
+    if jax_platforms is None:
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    else:
+        monkeypatch.setenv("JAX_PLATFORMS", jax_platforms)
+    wf_path = os.path.join(REPO, "veles/znicz_tpu/models/mnist.py")
+    main = vmain.Main([wf_path, "-d", device, "--seed", "5",
+                       "--no-stats", "--optimize", "1x3x2"])
+    with pytest.raises(SystemExit, match="one process at a time"):
+        main.run()
+
+
 # -- GA over slaves (SURVEY §2.7 "runs distributed over slaves") ------
 
 def _quad_fitness(values):

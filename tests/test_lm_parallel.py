@@ -149,6 +149,34 @@ def test_lm_dp_plus_tp(dense_wf):
         assert abs(a - b) < 0.05, (hist, dense)
 
 
+def test_lm_pallas_attention_under_dp_tp(dense_wf):
+    """The Pallas attention path on a data x model mesh. On the chip
+    GSPMD refuses to partition a Mosaic kernel, so under a mesh the
+    unit runs it per shard inside shard_map (batch over data, heads
+    over model); here the same wrapper runs the interpreted kernel,
+    and the trajectory must still match the unsharded dense run."""
+    saved = {k: root.lm.model.get(k)
+             for k in ("attn_impl", "attn_block")}
+    root.lm.model.update({"attn_impl": "pallas", "attn_block": 16})
+    try:
+        wf = _run_lm("LMPallasDPTP", {"data": 2, "model": 2})
+    finally:
+        root.lm.model.update(saved)
+    from veles.znicz_tpu.ops.attention import MultiHeadAttention
+    mha = [f for f in wf.forwards if isinstance(f, MultiHeadAttention)]
+    # the axes come from the setup_* calls, not from names the unit
+    # assumes
+    assert mha and all((f.kernel_batch_axis, f.kernel_head_axis)
+                       == ("data", "model") for f in mha)
+    hlo = wf.xla_step.lowered_epoch_hlo(optimized=False)
+    assert "sdy.manual_computation" in hlo, \
+        "the kernel was not wrapped in shard_map"
+    hist, dense = _history(wf), _history(dense_wf)
+    assert hist[-1] < hist[0]
+    for a, b in zip(hist, dense):
+        assert abs(a - b) < 0.05, (hist, dense)
+
+
 def test_lm_sp_plus_dp(dense_wf):
     """2-way data x 4-way seq on ONE composed mesh: the ring shards
     the sequence while the batch shards over data."""

@@ -465,6 +465,31 @@ def test_http_bad_content_length_answers_400():
         status.close()
 
 
+def test_http_reply_larger_than_the_write_queue_arrives_whole(
+        monkeypatch):
+    """A Content-Length body bigger than ``max_write_buffer`` (a
+    full-vocabulary logits reply is) is fed to the queue slice by
+    slice as the socket drains and arrives intact — it used to
+    overflow the queue and drop the connection mid-reply."""
+    import urllib.request
+    from veles import reactor
+    monkeypatch.setattr(reactor, "DEFAULT_MAX_WRITE_BUFFER", 1 << 16)
+    monkeypatch.setattr(reactor.HttpConnection, "BODY_SLICE", 1 << 14)
+    # well past what the kernel's socket buffers swallow in one send
+    body = bytes(range(256)) * (64 << 10)           # 16 MiB
+    server = reactor.HttpServer(
+        "127.0.0.1", 0,
+        lambda request: request.reply(200, body,
+                                      "application/octet-stream"))
+    try:
+        with urllib.request.urlopen(
+                "http://127.0.0.1:%d/" % server.port,
+                timeout=30) as resp:
+            assert resp.read() == body
+    finally:
+        server.close()
+
+
 def test_http_connections_untracked_without_a_request():
     """TCP-only health checks (open, close, no HTTP request) must not
     accumulate connection objects in the server's tracking set."""

@@ -401,9 +401,15 @@ class Main:
                 print("generated: %s"
                       % ",".join(str(t) for t in out[0].tolist()))
         if args.result_file and self.workflow.decision is not None:
+            from veles.backends import device_report
+            device = self.workflow.device
             with open(args.result_file, "w") as f:
                 json.dump({
                     "workflow": self.workflow.name,
+                    # which device produced these numbers
+                    "device": device_report(device.jax_devices)
+                    if device.is_xla else
+                    {"platform": device.backend_name},
                     "history": self.workflow.decision.history,
                     "best_metric": float(
                         self.workflow.decision.best_metric),
@@ -494,10 +500,28 @@ class Main:
         from veles.genetics import (
             GATaskServer, GeneticOptimizer, ProcessPoolMap,
             SubprocessTrainer, apply_values, find_tunables)
+        device = self.args.device or "numpy"
+        # decided from the spec and the environment alone: asking jax
+        # would make THIS process take the chip. On a TPU host
+        # JAX_PLATFORMS is normally unset and "xla" resolves to the
+        # chip, so anything that is not pinned to the CPU counts.
+        on_cpu = device in ("numpy", "cpu") or (
+            device != "tpu" and os.environ.get(
+                "JAX_PLATFORMS", "").split(",")[0] == "cpu")
+        if workers and not on_cpu:
+            raise SystemExit(
+                "--optimize %s with -d %s: unless JAX_PLATFORMS pins "
+                "jax to the cpu this may be a TPU, and a TPU chip "
+                "belongs to one process at a time, so %d spawned "
+                "trainers would race for it and all but one would "
+                "fail or hang. Drop the x%d (individuals then train "
+                "one after another on the chip), farm them over "
+                "slaves on other hosts with --listen-address, or set "
+                "JAX_PLATFORMS=cpu" % (self.args.optimize, device,
+                                       workers, workers))
         evaluate = SubprocessTrainer(
             self.args.workflow, self.args.config,
-            overrides=self.args.overrides, seed=seed,
-            device=self.args.device or "numpy")
+            overrides=self.args.overrides, seed=seed, device=device)
         if slaves:
             map_cm = GATaskServer(
                 self.args.listen_address,

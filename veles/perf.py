@@ -31,6 +31,7 @@ Both are deterministic functions of the jaxpr, which is what makes
 them comparable across runs and hosts.
 """
 
+import logging
 import os
 import threading
 import time
@@ -237,12 +238,26 @@ _PEAK_ENV = {"bf16": "VELES_PEAK_FLOPS",
              "fp8": "VELES_PEAK_FLOPS_FP8"}
 
 
+def peak_flops_of(device_kind, precision="bf16"):
+    """THE table lookup: peak FLOP/s of a ``jax.Device.device_kind``
+    for ``precision`` ("bf16" | "int8" | "fp8"), or None for a kind
+    the table does not hold. A precision with no entry for the device
+    falls back to the bf16 row — the rate those matmuls actually run
+    at. The runtime gauge (:func:`device_peak_flops`) treats None as
+    "no MFU"; ``bench.py`` treats it as an error."""
+    kind = str(device_kind).lower()
+    for table in (_PEAK_FLOPS_BY_KIND.get(precision, ()),
+                  _PEAK_FLOPS_BY_KIND["bf16"]):
+        for sub, peak in table:
+            if sub.lower() in kind:
+                return peak
+    return None
+
+
 def device_peak_flops(precision="bf16"):
-    """Peak FLOP/s of the default device for ``precision`` ("bf16" |
-    "int8" | "fp8"), or None when unknown (CPU, unrecognized kind).
-    ``$VELES_PEAK_FLOPS`` (and ``_INT8``/``_FP8``) override. A
-    precision with no table entry for the device falls back to the
-    bf16 row — the rate those matmuls actually run at."""
+    """Peak FLOP/s of the default device for ``precision``, or None
+    when unknown (CPU, unrecognized kind). ``$VELES_PEAK_FLOPS`` (and
+    ``_INT8``/``_FP8``) override the table."""
     env = os.environ.get(_PEAK_ENV.get(precision, "VELES_PEAK_FLOPS"))
     if env:
         try:
@@ -254,13 +269,7 @@ def device_peak_flops(precision="bf16"):
         kind = jax.devices()[0].device_kind
     except Exception:
         return None
-    kind = str(kind).lower()
-    for table in (_PEAK_FLOPS_BY_KIND.get(precision, ()),
-                  _PEAK_FLOPS_BY_KIND["bf16"]):
-        for sub, peak in table:
-            if sub.lower() in kind:
-                return peak
-    return None
+    return peak_flops_of(kind, precision)
 
 
 # -- the ledger ---------------------------------------------------------
@@ -283,7 +292,10 @@ class PerfLedger:
     def cost(self, key, fn, args):
         """The cached :class:`StepCost` for ``key``, analyzing
         ``fn(*args)`` on first sight. Analysis failures degrade to a
-        zero cost — accounting must never break a dispatch path.
+        zero cost — accounting must never break a dispatch path — and
+        are logged with their traceback, once per program (the zero
+        is cached like any other cost): a silent zero reads as "this
+        step does no arithmetic" on every later scrape.
 
         Callers key by ``id(fn)``, so each entry holds a weakref to
         its program: a later function reallocated at a freed id must
@@ -300,6 +312,10 @@ class PerfLedger:
         try:
             cost = program_cost(fn, args)
         except Exception:
+            logging.getLogger("veles.perf").warning(
+                "cost analysis of program %r failed; its "
+                "veles_step_flops/bytes/mfu series stay at zero",
+                key, exc_info=True)
             cost = StepCost()
         if telemetry.tracer.active:
             telemetry.tracer.add_complete(

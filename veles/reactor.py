@@ -782,9 +782,13 @@ class HttpConnection(Connection):
         #: cleared by finish_stream, so a NORMAL end never reports a
         #: disconnect (see HttpRequest.begin_stream)
         self._stream_on_close = None
+        #: the part of a Content-Length body not yet handed to the
+        #: write queue (see _feed_body)
+        self._body = None
         super().__init__(reactor, sock)
 
     def on_closed(self, reason):
+        self._body = None
         if self._server is not None:
             self._server.untrack(self)
         cb = self._stream_on_close
@@ -849,9 +853,33 @@ class HttpConnection(Connection):
                 "Content-Length: %d" % len(body),
                 "Connection: close"]
         head.extend("%s: %s" % kv for kv in headers)
-        self.send_parts([("\r\n".join(head) + "\r\n\r\n").encode(),
-                         body])
-        self.close_when_drained()
+        self.send_parts([("\r\n".join(head) + "\r\n\r\n").encode()])
+        self._body = memoryview(body)
+        self._feed_body()
+
+    #: a response body enters the write queue this many bytes at a time
+    BODY_SLICE = 4 << 20
+
+    def _feed_body(self):
+        """Hand the response body to the write queue slice by slice,
+        the next slice once the socket has drained the last: the body's
+        bytes already exist, so queueing it whole would only copy it —
+        and a logits reply (one 512-token row of a 16384-word LM is
+        ~170 MB of JSON) is larger than ``max_write_buffer``, which
+        used to drop the connection mid-reply."""
+        while self._body is not None and not self.closed \
+                and self.write_queued < self.BODY_SLICE:
+            part = self._body[:self.BODY_SLICE]
+            rest = self._body[self.BODY_SLICE:]
+            self._body = rest if len(rest) else None
+            self.send_parts([part])
+        if self._body is None:
+            self.close_when_drained()
+
+    def on_writable(self):
+        super().on_writable()
+        if self._body is not None:
+            self._feed_body()
 
     # -- chunked streaming (loop thread; posted via HttpStream) --------
 

@@ -68,7 +68,6 @@ import http.client
 import json
 import os
 import shlex
-import signal
 import socket
 import subprocess
 import sys
@@ -1494,10 +1493,6 @@ def build_route_argparser():
     return p
 
 
-def _raise_interrupt(_signum, _frame):
-    raise KeyboardInterrupt
-
-
 def route_main(argv=None):
     """``velescli route URL [URL...]`` — run the router until
     interrupted (SIGINT or SIGTERM; both run the cleanup that reaps
@@ -1553,17 +1548,10 @@ def route_main(argv=None):
             "backends": controller.targets(),
             "autoscale": args.autoscale,
         }), flush=True)
-        try:
-            # SIGTERM must run the finally below (reap launched
-            # replicas, close the server) — the default disposition
-            # would kill the interpreter around it
-            signal.signal(signal.SIGTERM, _raise_interrupt)
-        except ValueError:
-            pass                        # non-main-thread caller
-        try:
-            threading.Event().wait()    # route until ^C / SIGTERM
-        except KeyboardInterrupt:
-            pass
+        # SIGTERM must run the finally below (reap launched
+        # replicas, close the server)
+        from veles.serving.frontend import wait_for_interrupt
+        wait_for_interrupt()
     finally:
         if front is not None:
             front.close()

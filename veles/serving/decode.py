@@ -237,6 +237,8 @@ class GenerativeEngine(Logger):
     def __init__(self, model, n_slots=8, max_len=256, donate=None,
                  name="decode-engine"):
         self.name = name
+        from veles import backends
+        backends.enable_compile_cache()
         self.plan = DecodePlan.from_archive(model)
         limit = self.plan.positions_limit(model.params)
         if limit is not None and limit < max_len:
@@ -252,8 +254,7 @@ class GenerativeEngine(Logger):
             # pool-buffer donation is an accelerator win; the CPU
             # donation path is a known use-after-free hazard in this
             # jaxlib (see StepCompiler) — never donate there
-            from veles.serving.engine import InferenceEngine
-            donate = InferenceEngine._on_accelerator()
+            donate = backends.default_platform() != "cpu"
         self.donate = bool(donate)
         self._compiled_prefill = {}   # prompt bucket -> jitted fn
         self._step_fn = None

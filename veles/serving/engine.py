@@ -11,8 +11,8 @@ request ever waits on a fresh compile after :meth:`warmup`.
 
 ``backend="numpy"`` evaluates the same pure function with plain numpy
 (the oracle path — zero compile cost, useful for tests and tiny
-models); ``backend="jit"`` uses jax; ``"auto"`` picks jit when jax
-imports.
+models); ``backend="jit"`` uses jax on its default platform, and
+``"auto"`` means ``"jit"`` (jax is a hard dependency).
 """
 
 import threading
@@ -40,11 +40,7 @@ class InferenceEngine:
     def __init__(self, model, backend="auto", max_batch=64,
                  donate=None, quantize="none"):
         if backend == "auto":
-            try:
-                import jax  # noqa: F401
-                backend = "jit"
-            except Exception:
-                backend = "numpy"
+            backend = "jit"
         if backend not in ("numpy", "jit"):
             raise ValueError("backend must be auto|numpy|jit, got %r"
                              % (backend,))
@@ -64,19 +60,19 @@ class InferenceEngine:
         self._model = None
         self._jit_apply = None
         self._device_params = None
+        #: jax platform the jit backend runs on (None for numpy) —
+        #: reported by /v1/models and the serve CLI's first stdout
+        #: line, so a caller can refuse a server that landed on the CPU
+        self.platform = None
+        if backend == "jit":
+            from veles import backends
+            backends.enable_compile_cache()
+            self.platform = backends.default_platform()
         if donate is None:
             # donation is a TPU/GPU win; on CPU jax only warns
-            donate = self._on_accelerator()
+            donate = self.platform not in (None, "cpu")
         self.donate = bool(donate)
         self.set_model(model)
-
-    @staticmethod
-    def _on_accelerator():
-        try:
-            import jax
-            return jax.devices()[0].platform != "cpu"
-        except Exception:
-            return False
 
     # -- model swap (hot reload) ---------------------------------------
 

@@ -827,9 +827,30 @@ def _parse_kv(pairs, what):
     return out
 
 
+def wait_for_interrupt():
+    """Block the main thread until SIGINT or SIGTERM. SIGTERM is
+    turned into the same KeyboardInterrupt ^C raises, so the caller's
+    ``finally`` runs and the process exits 0 — the default
+    disposition would kill the interpreter around it."""
+    import signal
+
+    def on_sigterm(_signum, _frame):
+        raise KeyboardInterrupt
+
+    try:
+        signal.signal(signal.SIGTERM, on_sigterm)
+    except ValueError:
+        pass                            # non-main-thread caller
+    try:
+        threading.Event().wait()
+    except KeyboardInterrupt:
+        pass
+
+
 def serve_main(argv=None):
     """``velescli.py serve ...`` — build the registry, start the
-    frontend, run until interrupted."""
+    frontend, run until interrupted (SIGINT or SIGTERM; both close
+    the frontend and the registry and exit 0)."""
     from veles.serving.registry import ModelRegistry
     args = build_serve_argparser().parse_args(argv)
     models = _parse_kv(args.model, "--model")
@@ -885,17 +906,22 @@ def serve_main(argv=None):
             from veles.web_status import WebStatus
             status = WebStatus(port=args.web_status, host=args.host)
             front.register_status(status)
-        print(json.dumps({
+        described = registry.describe()
+        doc = {
             "serving": "http://%s:%d" % (front.host, front.port),
             "models": [{"name": d["name"], "version": d["version"],
                         "backend": d["backend"],
+                        "platform": d["platform"],
                         "compiled_buckets": d["compiled_buckets"]}
-                       for d in registry.describe()],
-        }), flush=True)
-        try:
-            threading.Event().wait()    # serve until ^C / SIGTERM
-        except KeyboardInterrupt:
-            pass
+                       for d in described],
+        }
+        if any(d["platform"] for d in described):
+            # a jit engine is up: say what jax runs it on, so a caller
+            # can refuse a server that landed on the wrong device
+            from veles.backends import device_report
+            doc["device"] = device_report()
+        print(json.dumps(doc), flush=True)
+        wait_for_interrupt()
     finally:
         if front is not None:
             front.close()

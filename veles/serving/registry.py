@@ -104,6 +104,7 @@ class ServedModel:
             "input_sample_shape": self.model.input_sample_shape,
             "units": [s["type"] for s in self.model.units],
             "backend": self.engine.backend,
+            "platform": self.engine.platform,
             "quantize": self.engine.quantize,
             "compiled_buckets": self.engine.compiled_buckets,
             "loaded_at": self.loaded_at,

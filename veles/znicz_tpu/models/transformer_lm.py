@@ -298,7 +298,9 @@ class TransformerLMWorkflow(StandardWorkflow):
             axes["expert"] = expert
         if pipe > 1:
             axes["pipe"] = pipe
-        mesh = parallel.make_mesh(axes)
+        # meshed from the devices this workflow's Device holds, not
+        # from whatever jax's default platform is
+        mesh = parallel.make_mesh(axes, self.device.jax_devices)
         if seq > 1:
             parallel.setup_sequence_parallel(
                 self, mesh, batch_axis="data" if data > 1 else None)

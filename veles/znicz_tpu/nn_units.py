@@ -456,19 +456,21 @@ class GradientDescentBase(AcceleratedUnit, IDistributable):
         when the ``fused_bias_grad`` policy keeps the plain XLA
         reduction — call sites fall back to their own masked-reduce
         form then, so the escape hatch costs nothing when off."""
+        from veles.backends import is_tpu
+        on_tpu = is_tpu(ctx._compiler.device.platform)
         if self.fused_bias_grad is None:
             import os
-            from veles.znicz_tpu.parallel.pallas_attention import \
-                TPU_PLATFORMS
             fused = (os.environ.get("VELES_FUSED_BIAS_GRAD") == "1"
-                     and ctx._compiler.device.platform
-                     in TPU_PLATFORMS)
+                     and on_tpu)
         else:
             fused = bool(self.fused_bias_grad)
         if not fused:
             return None
         from veles.znicz_tpu.ops import pallas_grads as PG
-        return PG.bias_grad(err2d, y2d, self.ACTIVATION)
+        # interpret from the platform the step compiles for (a forced
+        # fused_bias_grad=True in the CPU tests runs the interpreter)
+        return PG.bias_grad(err2d, y2d, self.ACTIVATION,
+                            interpret=not on_tpu)
 
     def export_layer_stats(self, ctx, t, grad_w, grad_b, old_w, new_w,
                            old_b, new_b):

@@ -7,20 +7,19 @@ reduction over the batch·space rows:
 
     grad_b[k] = Σ_n  (err ∘ act'(y))[n, k]          (f32 accumulate)
 
-In-program on a v5e, XLA lowers that to a ``convert_reduce`` loop
-fusion that runs at ~11 GB/s effective HBM bandwidth — 16-23× slower
-than the SAME computation isolated (``docs/repro_convert_reduce.py``:
-the isolated form hits 179-250 GB/s, and an A/B with bias grads zeroed
-recovers ~21 ms of a 284 ms AlexNet step). The round-4 deep-dive
-pinned the cause as a fusion *decision*: next to the wgrad/err-input
-conv consumers, XLA duplicates the masked-convert producer into the
-bias-reduce fusion instead of reusing the conv's operand. Four
-semantically equivalent XLA-level rewrites all measured SLOWER
-end-to-end (the note in ``gd_conv.py``), so the fix is to take the
-reduction out of XLA's hands entirely: this kernel IS the masked
-reduction, block-tiled, with the mask recomputed from err/y inside the
-kernel — the surrounding program keeps its dz for the conv consumers
-and XLA no longer sees a bias reduce to (mis)fuse.
+In-program, XLA lowers that to a ``convert_reduce`` loop fusion: next
+to the wgrad/err-input conv consumers it duplicates the masked-convert
+producer into the bias-reduce fusion instead of reusing the conv's
+operand (exemplars in ``docs/convert_reduce_fusion_hlo.txt``). An
+earlier builder's trace read that fusion as far slower than the same
+computation isolated, and found four semantically equivalent XLA-level
+rewrites slower still; none of that is in the driver's record or has
+been re-measured (``docs/repro_convert_reduce.py`` times the variants
+when run on a TPU). This kernel takes the reduction out of XLA's hands
+entirely: it IS the masked reduction, block-tiled, with the mask
+recomputed from err/y inside the kernel — the surrounding program
+keeps its dz for the conv consumers and XLA no longer sees a bias
+reduce to fuse.
 
 Design (same conventions as ``parallel/pallas_attention.py``):
 
@@ -43,9 +42,9 @@ Exactness is pinned by ``tests/test_pallas_grads.py`` against the
 reference ``dz.sum(axis=0)`` math at the existing gd tolerances.
 Consumed via ``GradientDescentBase.bias_grad_xla`` behind the
 ``fused_bias_grad`` escape hatch (None = auto: on TPU when
-$VELES_FUSED_BIAS_GRAD=1 — opt-in until a device window validates
-the kernel end-to-end; True/False force), mirroring the flash
-kernels' ``fused=False`` stance.
+$VELES_FUSED_BIAS_GRAD=1 — opt-in until a paired chip run decides it;
+True/False force), mirroring the flash kernels' ``fused=False``
+stance.
 """
 
 import functools
@@ -115,8 +114,9 @@ def bias_grad(err, y, activation, block_n=None, block_k=None,
     ride any float dtype (bf16 on TPU); the mask and the accumulation
     run in f32. ``activation`` names an ``ACTIVATIONS`` entry (linear
     and softmax derivatives are the identity — the kernel is then the
-    pure f32-accumulating reduction). Real kernel on TPU, interpret
-    mode elsewhere."""
+    pure f32-accumulating reduction). ``interpret`` as in
+    ``pallas_attention.flash_attention_fwd``: False = the Mosaic
+    kernel, True = the interpreter, None = ask jax's default device."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl

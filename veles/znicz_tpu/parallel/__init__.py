@@ -51,7 +51,7 @@ _COLLECTIVE_OPS = ("all-reduce", "all-gather", "all-to-all",
                    "collective-permute", "reduce-scatter")
 
 
-def collective_counts(step, n_epochs=1):
+def collective_counts(step, n_epochs=1, hlo=None):
     """{opcode: count} of cross-device collectives in the OPTIMIZED
     (post-GSPMD-partitioning) HLO of the workflow step's next
     scan-mode dispatch — the strongest hardware-free evidence that a
@@ -60,9 +60,12 @@ def collective_counts(step, n_epochs=1):
     all-reduce (gradient sync), TP all-reduce (row-sharded
     contractions), EP all-to-all (token routing), ring-SP / PP
     collective-permute (neighbour hops). ``step``: an XLAStep whose
-    shardings are already set up (``setup_*`` + ``refresh_device``)."""
+    shardings are already set up (``setup_*`` + ``refresh_device``).
+    ``hlo``: that optimized HLO text, from a caller that already holds
+    it (lowering it again means compiling again)."""
     import re
-    text = step.lowered_epoch_hlo(optimized=True, n_epochs=n_epochs)
+    text = hlo if hlo is not None else step.lowered_epoch_hlo(
+        optimized=True, n_epochs=n_epochs)
     counts = {}
     for op in _COLLECTIVE_OPS:
         # match "op(" and the async "op-start(" spelling, not substrings
@@ -73,11 +76,12 @@ def collective_counts(step, n_epochs=1):
     return counts
 
 
-def assert_collectives(step, expected, n_epochs=1):
+def assert_collectives(step, expected, n_epochs=1, hlo=None):
     """Assert the step's optimized HLO contains >=1 of each expected
     collective (and return the full counts). ``expected``: iterable of
-    opcodes from ``_COLLECTIVE_OPS``."""
-    counts = collective_counts(step, n_epochs=n_epochs)
+    opcodes from ``_COLLECTIVE_OPS``; ``hlo`` as in
+    :func:`collective_counts`."""
+    counts = collective_counts(step, n_epochs=n_epochs, hlo=hlo)
     missing = [op for op in expected if not counts.get(op)]
     if missing:
         raise AssertionError(
@@ -89,7 +93,11 @@ def assert_collectives(step, expected, n_epochs=1):
 
 def make_mesh(axes=None, devices=None):
     """Build a Mesh. ``axes``: dict name->size (ordered); ``None``
-    means one 'data' axis over all visible devices."""
+    means one 'data' axis over all visible devices. ``devices``
+    defaults to jax's default platform; a workflow passes its own
+    Device's set so ``-d tpu`` can only mesh TPU chips. A mesh smaller
+    than the host takes the FIRST devices in enumeration order and
+    leaves the rest idle — logged, because nothing else shows it."""
     import jax
     from jax.sharding import Mesh
     if devices is None:
@@ -102,6 +110,11 @@ def make_mesh(axes=None, devices=None):
     if n_need > len(devices):
         raise ValueError("mesh %r needs %d devices, have %d"
                          % (axes, n_need, len(devices)))
+    if n_need < len(devices):
+        import logging
+        logging.getLogger("veles.parallel").info(
+            "mesh %r uses %d of %d %s devices; the rest stay idle",
+            axes, n_need, len(devices), devices[0].platform)
     grid = numpy.array(devices[:n_need], dtype=object).reshape(sizes)
     return Mesh(grid, names)
 
@@ -130,6 +143,36 @@ def grad_sync_bytes(params):
                    for l in leaves))
 
 
+def kernel_per_shard(kernel, mesh, batch_axis, head_axis, in_kinds,
+                     out_kinds):
+    """A Pallas attention kernel as a step over ``mesh`` needs it.
+    GSPMD cannot split a Mosaic kernel ("Mosaic kernels cannot be
+    automatically partitioned. Please wrap the call in a shard_map"),
+    so under a mesh the call runs per shard: batch over
+    ``batch_axis``, heads over ``head_axis`` (either may be None: that
+    dim is then whole on every device, as it is over every other axis
+    of the mesh) — attention is independent per sample and per head,
+    so the body needs no collective. ``in_kinds`` / ``out_kinds``: one
+    letter per argument / result, "t" for a (B,H,S,dh) tensor and "r"
+    for a (B,H,S) row statistic. No mesh -> ``kernel`` unchanged."""
+    if mesh is None:
+        return kernel
+    from jax.sharding import PartitionSpec as P
+    from veles.znicz_tpu.parallel.ring import _shard_map
+    spec = {"t": P(batch_axis, head_axis, None, None),
+            "r": P(batch_axis, head_axis, None)}
+    return _shard_map(
+        mesh=mesh,
+        in_specs=tuple(spec[kind] for kind in in_kinds),
+        out_specs=tuple(spec[kind] for kind in out_kinds))(kernel)
+
+
+def _attention_units(workflow):
+    from veles.znicz_tpu.ops.attention import MultiHeadAttention
+    return [fwd for fwd in workflow.forwards
+            if isinstance(fwd, MultiHeadAttention)]
+
+
 def setup_data_parallel(workflow, mesh=None, axis="data",
                         refresh=True):
     """Configure an initialized XLA workflow for DP over ``mesh``:
@@ -145,6 +188,9 @@ def setup_data_parallel(workflow, mesh=None, axis="data",
     step.batch_sharding = batch_sharding(mesh, axis)
     step.param_sharding = replicated(mesh)
     step.param_sharding_map = {}
+    for fwd in _attention_units(workflow):
+        fwd.kernel_batch_axis = axis
+        fwd.kernel_head_axis = None     # went with the TP map
     workflow.device.mesh = mesh
     if refresh:
         step.refresh_device()
@@ -160,22 +206,19 @@ def setup_sequence_parallel(workflow, mesh, axis="seq",
     into the trace). The axis size must divide the sequence length.
     ``batch_axis`` names the mesh axis the batch dim is sharded over
     when composing SP with DP on one mesh."""
-    from veles.znicz_tpu.ops.attention import MultiHeadAttention
     n = mesh.shape[axis]
-    touched = 0
-    for fwd in workflow.forwards:
-        if isinstance(fwd, MultiHeadAttention):
-            s = fwd.input.shape[1]
-            if s % n:
-                raise ValueError(
-                    "%s axis size %d does not divide sequence "
-                    "length %d" % (axis, n, s))
-            fwd.seq_mesh = mesh
-            fwd.seq_axis = axis
-            fwd.seq_batch_axis = batch_axis
-            touched += 1
-    if not touched:
+    units = _attention_units(workflow)
+    if not units:
         raise ValueError("no attention units to sequence-parallelize")
+    for fwd in units:
+        s = fwd.input.shape[1]
+        if s % n:
+            raise ValueError(
+                "%s axis size %d does not divide sequence "
+                "length %d" % (axis, n, s))
+        fwd.seq_mesh = mesh
+        fwd.seq_axis = axis
+        fwd.seq_batch_axis = batch_axis
     return mesh
 
 
@@ -405,6 +448,7 @@ def setup_tensor_parallel(workflow, mesh, axis="model", refresh=True):
             put("bias", vec, "vel_bias")
             put("weights_out", row, "vel_weights_out")
             put("bias_out", rep, "vel_bias_out")
+            fwd.kernel_head_axis = axis
             touched += 1
         elif isinstance(fwd, TransformerFFN):
             if fwd.hidden and fwd.hidden % n:

@@ -37,14 +37,12 @@ import numpy
 
 
 def _shard_map(**kw):
-    """Version-portable shard_map partial (the replication-check kwarg
-    was renamed check_rep -> check_vma across jax versions)."""
-    import functools as ft
+    """``jax.shard_map`` without the varying-axes check: the bodies
+    it wraps here and in ``expert.py`` / ``pipeline.py`` are
+    hand-written collectives whose replication the checker cannot
+    infer."""
     import jax
-    if hasattr(jax, "shard_map"):
-        return ft.partial(jax.shard_map, check_vma=False, **kw)
-    from jax.experimental.shard_map import shard_map
-    return ft.partial(shard_map, check_rep=False, **kw)
+    return functools.partial(jax.shard_map, check_vma=False, **kw)
 
 
 def _local_attention_steps(q, k0, v0, axis_name, causal, n_dev):
@@ -106,22 +104,26 @@ def ring_attention_fwd(q, k, v, axis_name, causal, n_dev):
 # runs the single-chip flash formulation (scan or Pallas) instead of
 # the fused dense block
 
-def _inner_kernels(inner, block, dot=None):
+def _inner_kernels(inner, block, dot=None, interpret=None):
     """(fwd, bwd) block-attention kernels for one ring step.
     fwd(q, k, v, causal) -> (out, lse) with out NORMALIZED within the
     block; bwd(q, k, v, out, lse, dout, causal) -> (dq, dk, dv) where
-    out/lse are the GLOBAL-row quantities (flash backward semantics)."""
+    out/lse are the GLOBAL-row quantities (flash backward semantics).
+    ``interpret`` reaches the Pallas kernels unchanged (see
+    ``pallas_attention.flash_attention_fwd``)."""
     if inner == "pallas":
         from veles.znicz_tpu.parallel import pallas_attention as PA
 
         def fwd(q, k, v, causal):
             return PA.flash_attention_fwd(q, k, v, causal=causal,
-                                          block_q=block, block_k=block)
+                                          block_q=block, block_k=block,
+                                          interpret=interpret)
 
         def bwd(q, k, v, out, lse, dout, causal, delta=None):
             return PA.flash_attention_bwd(q, k, v, out, lse, dout,
                                           causal=causal,
                                           block_q=block, block_k=block,
+                                          interpret=interpret,
                                           delta=delta)
     elif inner == "scan":
         from veles.znicz_tpu.parallel import flash
@@ -169,14 +171,14 @@ def _ring_branches(causal, src, my, run_causal, run_full, run_skip):
 
 
 def ring_attention_fwd_flash(q, k0, v0, axis_name, causal, n_dev,
-                             inner, block, dot=None):
+                             inner, block, dot=None, interpret=None):
     """Forward ring with a flash inner block; same contract as
     :func:`ring_attention_fwd`."""
     import jax.numpy as jnp
     from jax import lax
 
     b, h, sb, dh = q.shape
-    kern_fwd, _ = _inner_kernels(inner, block, dot)
+    kern_fwd, _ = _inner_kernels(inner, block, dot, interpret)
     my = lax.axis_index(axis_name)
     perm = [(i, (i + 1) % n_dev) for i in range(n_dev)]
 
@@ -203,14 +205,15 @@ def ring_attention_fwd_flash(q, k0, v0, axis_name, causal, n_dev,
 
 
 def ring_attention_bwd_flash(q, k, v, out, lse, dout, axis_name,
-                             causal, n_dev, inner, block, dot=None):
+                             causal, n_dev, inner, block, dot=None,
+                             interpret=None):
     """Backward ring with a flash inner block; same contract as
     :func:`ring_attention_bwd` (dk/dv accumulate while riding the
     ring a full circle home)."""
     import jax.numpy as jnp
     from jax import lax
 
-    _, kern_bwd = _inner_kernels(inner, block, dot)
+    _, kern_bwd = _inner_kernels(inner, block, dot, interpret)
     my = lax.axis_index(axis_name)
     perm = [(i, (i + 1) % n_dev) for i in range(n_dev)]
     # delta is a property of (out, dout) alone — hoist the
@@ -296,7 +299,7 @@ def ring_attention_bwd(q, k, v, out, lse, dout, axis_name, causal,
 
 def ring_self_attention(q, k, v, mesh, axis="seq", causal=True,
                         batch_axis=None, inner=None, block=128,
-                        dot=None):
+                        dot=None, interpret=None):
     """Dense-equivalent attention with the sequence sharded over
     ``axis``. q/k/v: (B, H, S, dh) global arrays. Returns (out, lse)
     global arrays (out sharded like q). On a composed mesh,
@@ -304,7 +307,8 @@ def ring_self_attention(q, k, v, mesh, axis="seq", causal=True,
     attention is per-sample, so each data-group rings independently.
     ``inner``: None (fused dense block per ring step), "scan" or
     "pallas" — run each step's local block through the flash kernels
-    (module docstring); ``block`` is the inner kernel's tile size."""
+    (module docstring); ``block`` is the inner kernel's tile size and
+    ``interpret`` the Pallas inner kernels' interpret flag."""
     from jax.sharding import PartitionSpec as P
     shard_map = _shard_map()
 
@@ -319,7 +323,8 @@ def ring_self_attention(q, k, v, mesh, axis="seq", causal=True,
         body = functools.partial(ring_attention_fwd_flash,
                                  axis_name=axis, causal=causal,
                                  n_dev=n_dev, inner=inner,
-                                 block=block, dot=dot)
+                                 block=block, dot=dot,
+                                 interpret=interpret)
     fn = shard_map(
         body, mesh=mesh, in_specs=(spec, spec, spec),
         out_specs=(spec, lspec))
@@ -328,7 +333,7 @@ def ring_self_attention(q, k, v, mesh, axis="seq", causal=True,
 
 def ring_self_attention_bwd(q, k, v, out, lse, dout, mesh, axis="seq",
                             causal=True, batch_axis=None, inner=None,
-                            block=128, dot=None):
+                            block=128, dot=None, interpret=None):
     import functools as ft
     from jax.sharding import PartitionSpec as P
     shard_map = _shard_map()
@@ -342,7 +347,7 @@ def ring_self_attention_bwd(q, k, v, out, lse, dout, mesh, axis="seq",
     else:
         body = ft.partial(ring_attention_bwd_flash, axis_name=axis,
                           causal=causal, n_dev=n_dev, inner=inner,
-                          block=block, dot=dot)
+                          block=block, dot=dot, interpret=interpret)
     fn = shard_map(
         body, mesh=mesh,
         in_specs=(spec, spec, spec, spec, lspec, spec),
