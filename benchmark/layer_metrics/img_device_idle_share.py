@@ -1,0 +1,5 @@
+"""``device_idle_share`` of a cell judged on ``train_images_per_s``: a per-layer
+metric names the one end-to-end metric it moves, so the image cells
+report the same reading under a name of their own."""
+
+from benchmark.layer_metrics.device_idle_share import read  # noqa: F401
