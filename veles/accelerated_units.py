@@ -60,6 +60,10 @@ def _compile_cache_event(kind, hit, build_seconds=None, start=None):
 class AcceleratedUnit(Unit):
     """A unit with a numpy oracle and a pure-jax implementation."""
 
+    #: direction of the unit's work in the compiled step's scope name
+    #: (``StepCompiler.trace_step``): "fwd", "bwd" or "loss"
+    scope_role = "fwd"
+
     def __init__(self, workflow, **kwargs):
         super().__init__(workflow, **kwargs)
         self.device = None
@@ -345,14 +349,19 @@ class StepCompiler:
     def trace_step(self, params, state, hyper, key, train, units, bind):
         """The ONE step-body trace shared by per-step and scan
         compilation: build the context, bind the batch (caller-supplied
-        closure), run every unit's ``xla_run``."""
+        closure), run every unit's ``xla_run`` under the scope
+        ``veles.<role>.<Class>.<name>`` — the name a device trace shows
+        for the unit's operations (``tf_op``)."""
+        import jax
         ctx = FlowContext(self, dict(params), dict(state), hyper,
                           key, train)
         bind(ctx)
         for unit in units:
             if not train and getattr(unit, "train_only", False):
                 continue
-            unit.xla_run(ctx)
+            with jax.named_scope("veles.%s.%s.%s" % (
+                    unit.scope_role, type(unit).__name__, unit.name)):
+                unit.xla_run(ctx)
         return ctx
 
     def build_step(self, batch_spec, train=True):
@@ -365,7 +374,7 @@ class StepCompiler:
 
         units = self.units
 
-        def step(params, state, batch, hyper, key):
+        def veles_step(params, state, batch, hyper, key):
             def bind(ctx):
                 for name, (unit, attr) in batch_spec.items():
                     ctx.set(unit, attr, batch[name])
@@ -374,7 +383,7 @@ class StepCompiler:
             return ctx.params, ctx.state, ctx.outputs
 
         donate = (0, 1) if (self.donate and train) else ()
-        return jax.jit(step, donate_argnums=donate)
+        return jax.jit(veles_step, donate_argnums=donate)
 
     def compile(self, batch_spec, train=True):
         key = (tuple(sorted((name, unit.name, attr)
@@ -427,8 +436,8 @@ class StepCompiler:
         if transform is None:
             transform = lambda name, t, train=False: t
 
-        def chunk_fn(params, state, full, idxs, valids, hyper, key0,
-                     offsets):
+        def veles_epoch_scan(params, state, full, idxs, valids, hyper,
+                             key0, offsets):
             def epoch_body(carry, xs):
                 params, state = carry
                 offset, idx_epoch = xs
@@ -469,7 +478,7 @@ class StepCompiler:
             return params, state, outs_all
 
         donate = (0, 1) if self.donate else ()
-        return jax.jit(chunk_fn, donate_argnums=donate)
+        return jax.jit(veles_epoch_scan, donate_argnums=donate)
 
     def compile_epoch_scan(self, batch_spec, segments, transform=None):
         key = ("epoch",
@@ -509,7 +518,8 @@ class StepCompiler:
         units = list(units)
         spec = dict(batch_spec)
 
-        def window_fn(params, state, stacked, valids, hyper, key0):
+        def veles_window_scan(params, state, stacked, valids, hyper,
+                              key0):
             def body(carry, xs):
                 params, state = carry
                 i, batch, valid = xs
@@ -534,7 +544,7 @@ class StepCompiler:
             return params, state, outs
 
         donate = (0, 1) if self.donate else ()
-        return jax.jit(window_fn, donate_argnums=donate)
+        return jax.jit(veles_window_scan, donate_argnums=donate)
 
     def compile_window_scan(self, batch_spec, train, units, transform):
         key = ("window",

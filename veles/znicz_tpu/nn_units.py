@@ -23,6 +23,8 @@ for the compat layer; the hot path is sharded-batch ``psum`` inside the
 jitted step instead.
 """
 
+import functools
+
 import numpy
 
 from veles import prng
@@ -149,6 +151,18 @@ class Forward(AcceleratedUnit):
         raise NotImplementedError
 
 
+def _update_scope(method):
+    """Trace a solver method under the ``veles.update`` sub-scope, so
+    that a device trace tells a GD unit's weight update from its
+    gradient math."""
+    @functools.wraps(method)
+    def scoped(self, ctx, *args):
+        import jax
+        with jax.named_scope("veles.update"):
+            return method(self, ctx, *args)
+    return scoped
+
+
 class GradientDescentBase(AcceleratedUnit, IDistributable):
     """Base backward unit: err_output → err_input + parameter update.
 
@@ -159,6 +173,7 @@ class GradientDescentBase(AcceleratedUnit, IDistributable):
     """
 
     FORWARD = None
+    scope_role = "bwd"
     STATE = ("vel_weights", "vel_bias", "acc_weights", "acc_bias",
              "sq_weights", "sq_bias", "acc_count", "iteration")
     #: (param_name, bias_like) for forward parameters BEYOND
@@ -529,6 +544,7 @@ class GradientDescentBase(AcceleratedUnit, IDistributable):
             vec = compute()
         ctx.export(model_health.STAT_KEY_PREFIX + self.name, vec)
 
+    @_update_scope
     def update_weights_xla(self, ctx, grad_w, grad_b):
         import jax.numpy as jnp
         f = self.forward
@@ -648,6 +664,7 @@ class GradientDescentBase(AcceleratedUnit, IDistributable):
             if q is not None:
                 sq.mem[...] = q
 
+    @_update_scope
     def update_extra_xla(self, ctx, grads):
         """Traced twin of :meth:`update_extra_numpy`; call after
         ``update_weights_xla`` in the same ``xla_run``."""

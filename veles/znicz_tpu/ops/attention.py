@@ -21,6 +21,7 @@ chip); a ``seq_mesh`` shards the sequence ACROSS chips via the
 ``ppermute`` ring (``parallel/ring.py``).
 """
 
+import contextlib
 import functools
 
 import numpy
@@ -291,6 +292,16 @@ class GDTransformerFFN(GradientDescentBase):
 # (parallel/pipeline.py). q/k/v: (B, H, S, dh).
 
 
+def _core_scope(xp=None):
+    """The ``veles.core`` sub-scope of a traced attention unit: the
+    attention proper, apart from the unit's projections and head
+    transposes in a device trace (nothing for the numpy oracle)."""
+    if xp is numpy:
+        return contextlib.nullcontext()
+    import jax
+    return jax.named_scope("veles.core")
+
+
 def dense_attention_core_fwd(xp, q, k, v, causal, scale, dot=None):
     """(probs, ctx) with ctx = softmax(qkᵀ·scale [+ causal mask])·v.
     ``dot``: matmul implementation (``ctx.dot`` on the traced path for
@@ -434,8 +445,9 @@ class MultiHeadAttention(Forward):
         k = self._split(qkv[..., d:2 * d])
         v = self._split(qkv[..., 2 * d:])
         scale = numpy.float32(1.0 / numpy.sqrt(dh))
-        probs, ctx = dense_attention_core_fwd(
-            xp, q, k, v, self.causal, scale, dot)
+        with _core_scope(xp):
+            probs, ctx = dense_attention_core_fwd(
+                xp, q, k, v, self.causal, scale, dot)
         merged = self._merge(ctx)
         y = dot(merged, wo)
         if self.include_bias:
@@ -557,9 +569,10 @@ class MultiHeadAttention(Forward):
         q, k, v = self._project_qkv(x, p, dot)
         if cd is not None:
             q, k, v = q.astype(cd), k.astype(cd), v.astype(cd)
-        out_heads, lse = flash.blocked_attention_fwd(
-            q, k, v, causal=self.causal, block=self.attn_block_size,
-            dot=dot)
+        with _core_scope():
+            out_heads, lse = flash.blocked_attention_fwd(
+                q, k, v, causal=self.causal,
+                block=self.attn_block_size, dot=dot)
         merged = self._merge(out_heads)
         y = self._finish(x, merged, p, dot)
         return y, (q, k, v, out_heads, lse, merged)
@@ -611,7 +624,8 @@ class MultiHeadAttention(Forward):
             pipeline=self.attn_pipeline,
             acc_dtype=jnp.bfloat16 if self.attn_acc == "bf16"
             else None), "ttt", "tr")
-        out_heads, lse = kernel(q, k, v)
+        with _core_scope():
+            out_heads, lse = kernel(q, k, v)
         merged = self._merge(out_heads)
         y = self._finish(x, merged, p, ctx.dot)
         return y, (q, k, v, out_heads, lse, merged)
@@ -663,11 +677,12 @@ class MultiHeadAttention(Forward):
         if inner is not None:
             cd = ctx._compiler.device.compute_dtype
             q, k, v = q.astype(cd), k.astype(cd), v.astype(cd)
-        out_heads, lse = ring.ring_self_attention(
-            q, k, v, self.seq_mesh, axis=self.seq_axis,
-            causal=self.causal, batch_axis=self.seq_batch_axis,
-            inner=inner, block=block, dot=dot,
-            interpret=self._pallas_interpret(ctx))
+        with _core_scope():
+            out_heads, lse = ring.ring_self_attention(
+                q, k, v, self.seq_mesh, axis=self.seq_axis,
+                causal=self.causal, batch_axis=self.seq_batch_axis,
+                inner=inner, block=block, dot=dot,
+                interpret=self._pallas_interpret(ctx))
         merged = self._merge(out_heads)
         y = self._finish(x, merged, p, dot)
         return y, (q, k, v, out_heads, lse, merged)
@@ -691,8 +706,9 @@ class GDMultiHeadAttention(GradientDescentBase):
         gbo = err.reshape(-1, d).sum(axis=0, dtype=xp.float32)
         dmerged = dot(err, wo.T)
         dctx = f._split(dmerged)                       # (B,H,S,dh)
-        dq, dk, dv = dense_attention_core_bwd(
-            xp, q, k, v, probs, dctx, scale, dot)
+        with _core_scope(xp):
+            dq, dk, dv = dense_attention_core_bwd(
+                xp, q, k, v, probs, dctx, scale, dot)
         dqkv = xp.concatenate(
             [f._merge(dq), f._merge(dk), f._merge(dv)], axis=-1)
         gw = dot(x.reshape(-1, d).T, dqkv.reshape(-1, 3 * d))
@@ -733,7 +749,8 @@ class GDMultiHeadAttention(GradientDescentBase):
         gbo = err.reshape(-1, d).sum(axis=0, dtype=xp.float32)
         dmerged = dot(err, p["weights_out"].T)
         dctx = f._split(dmerged)
-        dq, dk, dv = attn_bwd(q, k, v, out_heads, lse, dctx)
+        with _core_scope():
+            dq, dk, dv = attn_bwd(q, k, v, out_heads, lse, dctx)
         dqkv = xp.concatenate(
             [f._merge(dq), f._merge(dk), f._merge(dv)], axis=-1)
         gw = dot(x.reshape(-1, d).T, dqkv.reshape(-1, 3 * d))

@@ -23,6 +23,8 @@ from veles.memory import Array
 class EvaluatorBase(AcceleratedUnit):
     """Common attrs: input (net output), err_output, batch_size."""
 
+    scope_role = "loss"
+
     def __init__(self, workflow, **kwargs):
         super().__init__(workflow, **kwargs)
         self.input = None           # linked: last forward's output
