@@ -96,9 +96,10 @@ def lm_train_flops_per_token(dim, layers, ffn_hidden, vocab, seq):
       S(S+1)/2 causal pairs) -> 6·(S+1)·dim per token per layer.
       Causal, not the 12·L·S·d full-square form: MFU counts the
       FLOPs a perfect implementation NEEDS. The Pallas kernels (auto
-      at S>=1024) really do skip the masked half via their fori_loop
-      bounds; the scan-flash path at shorter S computes the full
-      square and masks (a cond skip measured slower there —
+      from S=256 up) skip the masked half via their fori_loop bounds
+      once a row is more than one tile (S>=1024); the one-tile kernels
+      at S<=512 and the scan-flash path below compute the full square
+      and mask (a cond skip measured slower in the scan —
       parallel/flash.py), which simply reads as lower MFU here."""
     n_mm = layers * (4 * dim * dim + 2 * dim * ffn_hidden) \
         + dim * vocab
@@ -627,8 +628,9 @@ def lm_base_s8k_tokens_per_sec(measure_chunks=3):
 
 def lm_longctx_tokens_per_sec(measure_chunks=3):
     """57.5M-param LM at S=8192 (long-context row): blocked attention
-    with the AUTO impl policy — the Pallas flash kernels take over at
-    this length (measured 2.6x over the XLA scan end-to-end on a v5e;
+    with the AUTO impl policy — the Pallas flash kernels run at this
+    length (an earlier builder's 2.6x over the XLA scan end-to-end on
+    a v5e; the rule and its measured table, from S=256 up since PR 27:
     ops/attention.py PALLAS_AUTO_MIN_S)."""
     return _lm_throughput(*LM_ROWS["57M_s8k"], "BenchLMLongCtx", 1,
                           measure_chunks)

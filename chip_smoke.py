@@ -9,8 +9,8 @@ at the full width of the transformer-base LM (dim 768, 12 heads, 12
 layers, FFN 3072, vocab 16384; random weights from ``--seed 1``):
 
 1. ``train_short`` — ``python -m veles .../transformer_lm.py -d tpu``
-   at S=512 (scan-flash attention), two epochs, exporting the archive
-   the server phase loads;
+   at S=512 (the one-tile Pallas kernels), two epochs, exporting the
+   archive the server phase loads;
 2. ``train_long`` — the same entry point at S=8192, batch 4 (Pallas
    forward + fused backward); its compiled step must hold Mosaic
    kernels (``tpu_custom_call`` in the optimized HLO);
@@ -78,6 +78,9 @@ SIZES = {
         # Pallas tile at the long shape (MultiHeadAttention's auto
         # choice: largest power-of-two divisor of S up to 512)
         "tile": 512,
+        # the short-sequence kernels at train_short's S: the batch of
+        # the benchmark's S=512 cells, one tile a (batch, head) row
+        "kernels_short_batch": 32,
         # bias_grad shapes: AlexNet conv1 (N = 128*55*55, K = 96, the
         # sample's soft "relu") and the LM head (N = 4*8192, K = vocab)
         "bias_grad": [(128 * 55 * 55, 96, "relu"),
@@ -96,6 +99,7 @@ SIZES = {
                        "n_train": 8, "n_valid": 4},
         "train_long_lr": 0.01,
         "tile": 32,
+        "kernels_short_batch": 4,
         "bias_grad": [(700, 24, "relu"), (256, 160, "linear")],
         "sync_matmul": (128, 8),
         "serve": {"decode_slots": 4, "decode_max_len": 32,
@@ -584,9 +588,11 @@ def child_train(size, device, workdir, leg):
 
 def pallas_variants(size, interpret, dtype):
     """{name: (jitted function, argument specs)} — every Pallas variant
-    the repo ships, at the train_long shapes of ``size``. The kernels
-    child runs them; tests/test_chip_smoke.py lowers the same table
-    for the TPU without a chip."""
+    the repo ships, at the train_long shapes of ``size``, and the
+    short-sequence kernels (one tile a row, what the auto rule runs
+    at S=512) at train_short's S. The kernels child runs them;
+    tests/test_chip_smoke.py lowers the same table for the TPU
+    without a chip."""
     import jax
     import jax.numpy as jnp
     from veles.znicz_tpu.ops import pallas_grads as PG
@@ -595,15 +601,21 @@ def pallas_variants(size, interpret, dtype):
     b = cfg["train_long"]["minibatch_size"]
     h = cfg["model"]["heads"]
     s = cfg["train_long"]["seq_len"]
-    t = jax.ShapeDtypeStruct((b, h, s, cfg["model"]["dim"] // h), dtype)
-    row = jax.ShapeDtypeStruct((b, h, s), jnp.float32)
+    dh = cfg["model"]["dim"] // h
 
-    def attn(fn, **kwargs):
+    def shapes(b, s):
+        t = jax.ShapeDtypeStruct((b, h, s, dh), dtype)
+        row = jax.ShapeDtypeStruct((b, h, s), jnp.float32)
+        return (t, t, t), (t, t, t, t, row, t)  # bwd: q k v out lse dout
+
+    def attn(fn, tile=cfg["tile"], **kwargs):
         return jax.jit(functools.partial(
-            fn, causal=True, block_q=cfg["tile"], block_k=cfg["tile"],
+            fn, causal=True, block_q=tile, block_k=tile,
             interpret=interpret, **kwargs))
 
-    fwd, bwd = (t, t, t), (t, t, t, t, row, t)  # bwd: q k v out lse dout
+    fwd, bwd = shapes(b, s)
+    s_short = cfg["train_short"]["seq_len"]
+    fwd_short, bwd_short = shapes(cfg["kernels_short_batch"], s_short)
     variants = {
         "fwd": (attn(PA.flash_attention_fwd), fwd),
         "fwd_pipeline": (attn(PA.flash_attention_fwd, pipeline=True),
@@ -613,6 +625,10 @@ def pallas_variants(size, interpret, dtype):
         "bwd_fused": (attn(PA.flash_attention_bwd, fused=True), bwd),
         "bwd_two_kernel": (attn(PA.flash_attention_bwd, fused=False),
                            bwd),
+        "fwd_short": (attn(PA.flash_attention_fwd, tile=s_short),
+                      fwd_short),
+        "bwd_short": (attn(PA.flash_attention_bwd, tile=s_short),
+                      bwd_short),
     }
     for n, k, act in cfg["bias_grad"]:
         g = jax.ShapeDtypeStruct((n, k), dtype)
@@ -654,67 +670,8 @@ def child_kernels(size, device):
         return jnp.asarray(
             gen.standard_normal(spec.shape, numpy.float32), spec.dtype)
 
-    q, k, v, dout = (rand(variants["fwd"][1][0]) for _ in range(4))
-    b, h, s, dh = q.shape
     rtol = RTOL_BF16_RESULT if cd == jnp.bfloat16 else 0.0
-    got, bounds = {}, {}    # "variant.tensor" -> whole result, (atol, rtol)
-    for name in ("fwd_acc_bf16", "fwd_pipeline", "fwd"):
-        out, lse = variants[name][0](q, k, v)
-        got[name + ".out"], got[name + ".lse"] = out, lse
-        # a bf16 accumulator is in the bf16 regime whatever the inputs
-        bounds[name + ".out"] = (
-            TOL_BF16_ACC if name == "fwd_acc_bf16" else tol, rtol)
-        bounds[name + ".lse"] = (TOL_LSE, 0.0)
-    # the backward forms take the resident forward's out and lse
-    for name in ("bwd_fused", "bwd_two_kernel"):
-        for gname, g in zip(("dq", "dk", "dv"),
-                            variants[name][0](q, k, v, out, lse, dout)):
-            got["%s.%s" % (name, gname)] = g
-            bounds["%s.%s" % (name, gname)] = (tol, rtol)
-    for name, g in got.items():
-        check(g.shape == (q.shape[:3] if name.endswith(".lse")
-                          else q.shape),
-              "kernel %s: shape %r" % (name, g.shape))
-
     hi = functools.partial(jnp.matmul, precision="highest")
-    scale = numpy.float32(1.0 / numpy.sqrt(dh))
-
-    @jax.jit
-    def slice_errors(q, k, v, dout, got):
-        """Per result, on one (1, REF_HEADS, S, dh) slice, against the
-        dense float32 reference: max|err|, and max(|err| - rtol*|ref|)
-        — what the absolute bound is held against."""
-        q32, k32, v32, do32 = (t.astype(jnp.float32)
-                               for t in (q, k, v, dout))
-        probs, ctx = dense_attention_core_fwd(
-            jnp, q32, k32, v32, True, scale, hi)
-        scores = jnp.where(
-            jnp.arange(s)[None, :] > jnp.arange(s)[:, None], -jnp.inf,
-            hi(q32, k32.transpose(0, 1, 3, 2)) * scale)
-        want = dict(zip(("dq", "dk", "dv"), dense_attention_core_bwd(
-            jnp, q32, k32, v32, probs, do32, scale, hi)),
-            out=ctx, lse=jax.nn.logsumexp(scores, axis=-1))
-        errs = {}
-        for name, g in got.items():
-            ref = want[name.rsplit(".", 1)[1]]
-            err = jnp.abs(g.astype(jnp.float32) - ref)
-            errs[name] = jnp.stack([
-                err.max(), (err - bounds[name][1] * jnp.abs(ref)).max()])
-        return errs
-
-    # EVERY batch and head: the reference is O(S^2) memory per head,
-    # so it is taken a few heads at a time. A non-finite result makes
-    # its error non-finite, which no bound admits.
-    worst = {name: numpy.full(2, -numpy.inf) for name in got}
-    for bi in range(b):
-        for h0 in range(0, h, REF_HEADS):
-            cut = (slice(bi, bi + 1), slice(h0, h0 + REF_HEADS))
-            errs = jax.device_get(slice_errors(
-                q[cut], k[cut], v[cut], dout[cut],
-                {name: g[cut] for name, g in got.items()}))
-            for name, pair in errs.items():     # NaN propagates
-                worst[name] = numpy.maximum(worst[name], pair)
-
     errors = {}
 
     def settle(name, err, net, atol):
@@ -725,10 +682,79 @@ def child_kernels(size, device):
         check(net <= atol,
               "kernel %s: error %.3e > %.3e" % (name, net, atol))
 
-    for name in got:
-        settle(name, float(worst[name][0]), float(worst[name][1]),
-               bounds[name][0])
-    del got
+    def check_attention(fwd_names, bwd_names):
+        """Run the forward variants ``fwd_names`` (the last one the
+        plain forward) and the backward variants ``bwd_names`` on one
+        random q, k, v, dout of their shape and hold every result to
+        the dense float32 reference."""
+        q, k, v, dout = (rand(variants[fwd_names[-1]][1][0])
+                         for _ in range(4))
+        b, h, s, dh = q.shape
+        got, bounds = {}, {}    # "variant.tensor" -> result, (atol, rtol)
+        for name in fwd_names:
+            out, lse = variants[name][0](q, k, v)
+            got[name + ".out"], got[name + ".lse"] = out, lse
+            # a bf16 accumulator is in the bf16 regime whatever the
+            # inputs
+            bounds[name + ".out"] = (
+                TOL_BF16_ACC if name == "fwd_acc_bf16" else tol, rtol)
+            bounds[name + ".lse"] = (TOL_LSE, 0.0)
+        # the backward forms take the plain forward's out and lse
+        for name in bwd_names:
+            for gname, g in zip(
+                    ("dq", "dk", "dv"),
+                    variants[name][0](q, k, v, out, lse, dout)):
+                got["%s.%s" % (name, gname)] = g
+                bounds["%s.%s" % (name, gname)] = (tol, rtol)
+        for name, g in got.items():
+            check(g.shape == (q.shape[:3] if name.endswith(".lse")
+                              else q.shape),
+                  "kernel %s: shape %r" % (name, g.shape))
+        scale = numpy.float32(1.0 / numpy.sqrt(dh))
+
+        @jax.jit
+        def slice_errors(q, k, v, dout, got):
+            """Per result, on one (1, REF_HEADS, S, dh) slice, against
+            the dense float32 reference: max|err|, and max(|err| -
+            rtol*|ref|) — what the absolute bound is held against."""
+            q32, k32, v32, do32 = (t.astype(jnp.float32)
+                                   for t in (q, k, v, dout))
+            probs, ctx = dense_attention_core_fwd(
+                jnp, q32, k32, v32, True, scale, hi)
+            scores = jnp.where(
+                jnp.arange(s)[None, :] > jnp.arange(s)[:, None],
+                -jnp.inf, hi(q32, k32.transpose(0, 1, 3, 2)) * scale)
+            want = dict(zip(("dq", "dk", "dv"), dense_attention_core_bwd(
+                jnp, q32, k32, v32, probs, do32, scale, hi)),
+                out=ctx, lse=jax.nn.logsumexp(scores, axis=-1))
+            errs = {}
+            for name, g in got.items():
+                ref = want[name.rsplit(".", 1)[1]]
+                err = jnp.abs(g.astype(jnp.float32) - ref)
+                errs[name] = jnp.stack([
+                    err.max(),
+                    (err - bounds[name][1] * jnp.abs(ref)).max()])
+            return errs
+
+        # EVERY batch and head: the reference is O(S^2) memory per
+        # head, so it is taken a few heads at a time. A non-finite
+        # result makes its error non-finite, which no bound admits.
+        worst = {name: numpy.full(2, -numpy.inf) for name in got}
+        for bi in range(b):
+            for h0 in range(0, h, REF_HEADS):
+                cut = (slice(bi, bi + 1), slice(h0, h0 + REF_HEADS))
+                errs = jax.device_get(slice_errors(
+                    q[cut], k[cut], v[cut], dout[cut],
+                    {name: g[cut] for name, g in got.items()}))
+                for name, pair in errs.items():     # NaN propagates
+                    worst[name] = numpy.maximum(worst[name], pair)
+        for name in got:
+            settle(name, float(worst[name][0]), float(worst[name][1]),
+                   bounds[name][0])
+
+    check_attention(("fwd_acc_bf16", "fwd_pipeline", "fwd"),
+                    ("bwd_fused", "bwd_two_kernel"))
+    check_attention(("fwd_short",), ("bwd_short",))
 
     for n, kk, act in cfg["bias_grad"]:
         name = "bias_grad_%dx%d_%s" % (n, kk, act)

@@ -62,7 +62,7 @@ def test_every_pallas_variant_lowers_for_tpu(monkeypatch):
     # compiles for; there is none here, so name the v5e's 128 MiB
     monkeypatch.setattr(PA, "_device_vmem_bytes", lambda: 128 << 20)
     variants = chip_smoke.pallas_variants("full", False, jnp.bfloat16)
-    assert len(variants) == 7
+    assert len(variants) == 9
     for name, (fn, specs) in variants.items():
         text = fn.trace(*specs).lower(
             lowering_platforms=("tpu",)).as_text()
@@ -113,7 +113,7 @@ def test_every_pallas_variant_compiles_for_v5e(tmp_path):
         pytest.skip("libtpu gives no compile-only v5e topology here: "
                     + proc.stdout.strip()[:200])
     assert proc.returncode == 0, proc.stderr[-3000:]
-    assert proc.stdout.count("COMPILED ") == 7, proc.stdout
+    assert proc.stdout.count("COMPILED ") == 9, proc.stdout
 
 
 def test_smoke_refuses_a_machine_without_a_tpu():

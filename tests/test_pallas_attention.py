@@ -321,3 +321,132 @@ def test_lm_trains_with_pallas_attention():
         root.lm.decision.max_epochs = saved_epochs
     hist = [h["validation"]["metric"] for h in wf.decision.history]
     assert hist[-1] < hist[0], hist
+
+
+# -- the auto rule: which S takes the kernels, at which tile (PR 27) ----
+
+
+def _platform_ctx(platform):
+    """What ``_pallas_interpret`` reads of a trace context: the
+    platform of the device the step compiles for."""
+    from types import SimpleNamespace
+    return SimpleNamespace(_compiler=SimpleNamespace(
+        device=SimpleNamespace(platform=platform)))
+
+
+def _auto_unit(**kwargs):
+    from veles.workflow import Workflow
+    return MultiHeadAttention(Workflow(None, name="wf-auto"), heads=2,
+                              attn_block_size=256, **kwargs)
+
+
+@pytest.mark.parametrize("s,mode", [
+    (8192, "pallas"), (1024, "pallas"),
+    (1032, "pallas"),   # from 1024 up any tile goes, as before PR 27
+    (768, "pallas"),    # tile 256
+    (512, "pallas"),    # one 512 tile a row: the S=512 cells
+    (256, "pallas"),    # the bound itself
+    (255, "scan"), (128, "scan"),
+    (640, "scan"), (384, "scan"),   # over the bound, but only a 128
+                                    # tile divides
+])
+def test_auto_rule_takes_the_kernels_from_s256_on_a_tpu(s, mode):
+    unit = _auto_unit()
+    assert unit._traced_mode(_platform_ctx("tpu"), s) == mode
+    # ... and never off the TPU (the interpreter is no fast path),
+    # nor against an explicit choice
+    assert unit._traced_mode(_platform_ctx("cpu"), s) == "scan"
+    assert _auto_unit(attn_impl="scan")._traced_mode(
+        _platform_ctx("tpu"), s) == "scan"
+    assert _auto_unit(attn_impl="pallas")._traced_mode(
+        _platform_ctx("cpu"), s) == "pallas"
+
+
+@pytest.mark.parametrize("s,tile", [
+    (128, 128), (256, 256), (512, 512), (768, 256),
+    (1024, 512), (8192, 512)])
+def test_pallas_block_is_the_measured_table(s, tile):
+    assert _auto_unit()._pallas_block(s) == tile
+    assert _auto_unit(pallas_tile=128)._pallas_block(s) == 128
+
+
+@pytest.mark.parametrize("s_loc,inner", [
+    (2048, "pallas"), (512, "pallas"), (256, "pallas"), (128, "scan")])
+def test_ring_inner_follows_the_auto_rule(s_loc, inner):
+    from types import SimpleNamespace
+    unit = _auto_unit()
+    unit.seq_mesh, unit.seq_axis = SimpleNamespace(shape={"seq": 4}), "seq"
+    unit.input = SimpleNamespace(shape=(2, 4 * s_loc, 16))
+    got, block = unit._ring_inner(_platform_ctx("tpu"))
+    assert got == inner
+    assert block == (min(s_loc, 512) if inner == "pallas" else 128)
+
+
+# -- the short-sequence (one-tile) kernels -------------------------------
+
+
+@pytest.mark.parametrize("bh,rows", [(384, 4), (6, 2), (3, 1), (8, 4)])
+def test_tile_rows_divide_the_batch_of_heads(bh, rows):
+    assert PA._tile_rows(bh) == rows
+
+
+def _dense_core(q, k, v, dout, causal):
+    from veles.znicz_tpu.ops.attention import (
+        dense_attention_core_bwd, dense_attention_core_fwd)
+    scale = numpy.float32(1.0 / numpy.sqrt(q.shape[-1]))
+    probs, out = dense_attention_core_fwd(numpy, q, k, v, causal, scale)
+    return out, dense_attention_core_bwd(numpy, q, k, v, probs, dout,
+                                         scale)
+
+
+@pytest.mark.parametrize("b,h,s,dh,causal", [
+    (1, 4, 512, 64, True),      # the benchmark's shape, 4 rows a program
+    (1, 3, 512, 64, True),      # a row count only 1 divides
+    (2, 2, 128, 8, False),
+], ids=str)
+def test_tile_kernels_match_dense_core(b, h, s, dh, causal):
+    """S=512, head 64, tile 512 — what ``_pallas_block`` picks for the
+    S=512 cells — forward and fused backward against the dense float32
+    core, at this file's bounds."""
+    prng.seed_all(913)
+    gen = prng.get("pa5")
+    q, k, v, dout = (gen.normal(0, 1.0, (b, h, s, dh)).astype(
+        numpy.float32) for _ in range(4))
+    out_ref, grads_ref = _dense_core(q, k, v, dout, causal)
+    out, lse = PA.flash_attention_fwd(
+        q, k, v, causal=causal, block_q=512, block_k=512,
+        interpret=True)
+    assert lse.shape == (b, h, s)
+    assert numpy.allclose(numpy.asarray(out), out_ref, atol=2e-5), \
+        numpy.abs(numpy.asarray(out) - out_ref).max()
+    got = PA.flash_attention_bwd(
+        q, k, v, out, lse, dout, causal=causal, block_q=512,
+        block_k=512, interpret=True)
+    for name, r, g in zip(("dq", "dk", "dv"), grads_ref, got):
+        assert numpy.allclose(numpy.asarray(g), r, atol=2e-4), \
+            (name, numpy.abs(numpy.asarray(g) - r).max())
+
+
+def test_tile_kernels_equal_the_general_kernels(monkeypatch):
+    """One tile a row: the short-sequence kernels and the general
+    ones (taken here by switching the short ones off) are the same
+    arithmetic."""
+    q, k, v = _qkv(64)
+    prng.seed_all(914)
+    dout = prng.get("pa6").normal(0, 1.0, q.shape).astype(
+        numpy.float32)
+
+    def both():
+        out, lse = PA.flash_attention_fwd(
+            q, k, v, causal=True, block_q=64, block_k=64,
+            interpret=True)
+        return (out, lse) + PA.flash_attention_bwd(
+            q, k, v, out, lse, dout, causal=True, block_q=64,
+            block_k=64, interpret=True)
+
+    short = both()
+    monkeypatch.setattr(PA, "TILE_MAX_S", 0)
+    for name, a, b in zip(("out", "lse", "dq", "dk", "dv"), short,
+                          both()):
+        assert numpy.allclose(numpy.asarray(a), numpy.asarray(b),
+                              atol=2e-6), name

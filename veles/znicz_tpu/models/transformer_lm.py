@@ -34,11 +34,13 @@ root.lm.update({
     # stack into ONE transformer_stack unit (lax.scan over layers —
     # flat compile time in depth, and the vehicle for pipeline
     # parallelism via root.lm.parallel.pipe).
-    # attn_impl: None/"scan" = lax.scan flash formulation when
-    # attn_block is set; "pallas" = the hand-written Pallas TPU
-    # kernels (parallel/pallas_attention.py). pallas_tile: explicit
-    # kernel tile override (None = measured auto, up to 512 — the
-    # VMEM escape hatch for large head dims)
+    # attn_impl (with attn_block set): None = chosen from S and the
+    # device (MultiHeadAttention.PALLAS_AUTO_MIN_S: the Pallas TPU
+    # kernels of parallel/pallas_attention.py from S=256 up on a TPU,
+    # else the lax.scan flash formulation); "scan" / "pallas" force
+    # either. pallas_tile: explicit kernel tile override (None =
+    # measured auto, up to 512 — the VMEM escape hatch for large
+    # head dims)
     # remat (with stacked=True): activation-checkpoint the block scan
     # — stash only layer inputs, recompute caches in the backward;
     # ~+1/3 compute for an O(heads*seq/12) stash cut (the (B, S)

@@ -22,7 +22,6 @@ chip); a ``seq_mesh`` shards the sequence ACROSS chips via the
 """
 
 import contextlib
-import functools
 
 import numpy
 
@@ -468,18 +467,42 @@ class MultiHeadAttention(Forward):
         self._cache = cache
 
     #: blocked-attention auto policy: with ``attn_impl=None`` the
-    #: Pallas kernels take over on a real TPU once S reaches this
-    #: bound. Measured end-to-end on a v5e 57M LM with the round-4
-    #: auto tile (2026-07-31, pallas vs scan tok/s): S=512 150k vs
-    #: 164k (scan wins — pallas_call's fusion boundary dominates),
-    #: S=1024 174k vs 161k, S=2048 156k vs 119k, S=4096 111k vs 82k,
-    #: S=8192 85k vs 53k (the causal loop bound SKIPS fully-masked K
-    #: blocks, which the scan schedule cannot). The round-3 threshold
-    #: of 4096 was an artifact of the kernel inheriting attn_block=256
-    #: as its tile; with the tile freed (``_pallas_block``) the
-    #: crossover sits between 512 and 1024. ``attn_impl="scan"``
-    #: forces the scan at any S.
-    PALLAS_AUTO_MIN_S = 1024
+    #: Pallas kernels take the attention proper on a real TPU once S
+    #: reaches this bound; below it the XLA scan (``parallel/flash.py``)
+    #: does. Measured on a v5e with the 110M LM at 16,384 tokens a
+    #: step: ``benchmark/run.py``'s S=512 cell with the sequence length
+    #: and batch varied (PERF.md section 6, PR 27; tokens/s: scan |
+    #: the general K-loop kernels by tile | the short-sequence kernels,
+    #: one tile a row):
+    #:
+    #:   S=512 batch 32   111.3k | 512: 136.2k 256: 124.1k 128: 107.9k | 151.3k
+    #:   S=256 batch 64   152.8k |             256: 138.6k 128: 125.4k | 159.0k
+    #:   S=128 batch 128  167.8k |                         128: 131.2k | 155.1k
+    #:
+    #: At S=512 the scan's (B, H, S, block) score tile is 201 MB at
+    #: batch 32 and every pass over it an HBM round trip; the kernels
+    #: keep it in VMEM. The shorter S, the smaller that tile and the
+    #: better the scan's one step fuses, while the kernels' cost a
+    #: token does not fall with S. From 1024 up the kernels also SKIP
+    #: the fully-masked K blocks through their causal loop bound,
+    #: which the scan cannot. ``attn_impl="scan"`` forces the scan at
+    #: any S.
+    PALLAS_AUTO_MIN_S = 256
+    #: ... and below S=1024 only where ``_pallas_block`` finds a tile
+    #: of at least this size: the 128 tile lost to the scan at S=512,
+    #: 256 and 128 (table above). From 1024 up any tile goes, as it
+    #: always did.
+    PALLAS_AUTO_MIN_TILE = 256
+
+    def _auto_pallas(self, ctx, s):
+        """Does ``attn_impl=None`` take the Pallas kernels for a
+        (per-shard) sequence of length ``s``? The one rule behind
+        ``_traced_mode`` and the ring's ``_ring_inner``."""
+        if self.attn_impl is not None or s < self.PALLAS_AUTO_MIN_S \
+                or self._pallas_interpret(ctx):
+            return False
+        return s >= 1024 or \
+            self._pallas_block(s) >= self.PALLAS_AUTO_MIN_TILE
 
     def _traced_mode(self, ctx, s):
         """ONE dispatch resolver for the traced forward AND backward
@@ -491,8 +514,7 @@ class MultiHeadAttention(Forward):
             mode = "pallas"
         elif not self.attn_block_size:
             mode = "dense"
-        elif self.attn_impl is None and s >= self.PALLAS_AUTO_MIN_S \
-                and not self._pallas_interpret(ctx):
+        elif self._auto_pallas(ctx, s):
             mode = "pallas"
         else:
             mode = "scan"
@@ -582,10 +604,13 @@ class MultiHeadAttention(Forward):
         the unit's full sequence; the ring path passes its per-shard
         length): ``pallas_tile`` when set (the explicit VMEM escape
         hatch — must divide), else the largest power-of-two divisor
-        of ``s`` up to 512 — the measured v5e optimum in the
-        auto-select regime (57M LM, tile 512 vs the old
-        attn_block=256: 111k vs 82k tok/s at S=4096, 80k vs 53k at
-        S=8192; tile 1024 blows scoped VMEM). ``attn_block_size``
+        of ``s`` up to 512 — the measured v5e optimum at both ends of
+        the auto-select regime: at S=512 one 512 tile a row beat 256
+        and 128 (the table above ``PALLAS_AUTO_MIN_S``; a sequence of
+        one tile runs ``pallas_attention``'s short-sequence kernels),
+        at long S an earlier builder's 57M LM read tile 512 vs the
+        old attn_block=256 as 111k vs 82k tok/s at S=4096, 80k vs 53k
+        at S=8192 (tile 1024 blows scoped VMEM). ``attn_block_size``
         tunes the SCAN formulation and no longer constrains the
         kernel tile (honoring it cost 36-50% at long S, round 4)."""
         if s is None:
@@ -618,7 +643,7 @@ class MultiHeadAttention(Forward):
         cd = ctx._compiler.device.compute_dtype
         q, k, v = (t.astype(cd)
                    for t in self._project_qkv(x, p, ctx.dot))
-        kernel = self._pallas_on_mesh(ctx, functools.partial(
+        kernel = self._pallas_on_mesh(ctx, PA.jitted(
             PA.flash_attention_fwd, causal=self.causal, block_q=blk,
             block_k=blk, interpret=self._pallas_interpret(ctx),
             pipeline=self.attn_pipeline,
@@ -637,8 +662,9 @@ class MultiHeadAttention(Forward):
         and backward (the cache layout is the same either way, but
         the traced programs must agree). Policy mirrors
         ``_traced_mode``: explicit ``attn_impl`` wins; auto takes the
-        Pallas kernels on a real TPU once the PER-SHARD sequence
-        reaches PALLAS_AUTO_MIN_S; a set ``attn_block_size`` routes
+        Pallas kernels on a real TPU where the PER-SHARD sequence
+        passes the same rule (``_auto_pallas``); a set
+        ``attn_block_size`` routes
         the local block through the scan flash; otherwise the fused
         dense block (the short-shard default)."""
         s_loc = self.input.shape[1] // self.seq_mesh.shape[self.seq_axis]
@@ -646,9 +672,7 @@ class MultiHeadAttention(Forward):
             inner = "pallas"
         elif self.attn_impl == "scan":
             inner = "scan"
-        elif self.attn_impl is None \
-                and s_loc >= self.PALLAS_AUTO_MIN_S \
-                and not self._pallas_interpret(ctx):
+        elif self._auto_pallas(ctx, s_loc):
             inner = "pallas"
         elif self.attn_block_size:
             inner = "scan"
@@ -795,7 +819,7 @@ class GDMultiHeadAttention(GradientDescentBase):
         f = self.forward
         blk = f._pallas_block()
         cd = ctx._compiler.device.compute_dtype
-        kernel = f._pallas_on_mesh(ctx, functools.partial(
+        kernel = f._pallas_on_mesh(ctx, PA.jitted(
             PA.flash_attention_bwd, causal=f.causal, block_q=blk,
             block_k=blk, interpret=f._pallas_interpret(ctx)),
             "ttttrt", "ttt")     # q k v out lse dout -> dq dk dv

@@ -1,28 +1,33 @@
-"""Hand-written Pallas TPU flash-attention kernels — the LONG-CONTEXT
-fast path.
+"""Hand-written Pallas TPU flash-attention kernels — the attention
+proper from S=256 up.
 
-Real TPU kernels keeping the (m, l, acc) online-softmax state in VMEM
-across K/V blocks (SURVEY.md §2.5, §7 stage 6). Where they win, and
-why (measured on a v5e, round-4 auto tile 2026-07-31, bf16 inputs,
-57.5M LM training step, readback timing; pallas vs scan tok/s):
+Real TPU kernels keeping the score tile and the softmax state in VMEM
+(SURVEY.md §2.5, §7 stage 6). Where they win, and why:
 
-* S=512: the XLA scan (``parallel/flash.py``) wins end-to-end (164k
-  vs 150k) — ``pallas_call`` is a fusion boundary, so the qkv
-  projection and surrounding elementwise work can no longer fuse into
-  the attention loop, and at short S that overhead dominates.
-* S>=1024: these kernels win END-TO-END — 174k vs 161k at S=1024,
-  156k vs 119k at S=2048, 111k vs 82k at S=4096, 85k vs 53k at
-  S=8192 — because the causal ``fori_loop`` bound SKIPS fully-masked
-  K blocks entirely, halving the quadratic work, which the scan
+* S>=1024 (an earlier builder's v5e readings, 57.5M LM, 2026-07-31,
+  not in the driver's record; pallas vs scan tok/s): 174k vs 161k at
+  S=1024, 156k vs 119k at S=2048, 111k vs 82k at S=4096, 85k vs 53k
+  at S=8192 — the causal ``fori_loop`` bound SKIPS fully-masked K
+  blocks entirely, halving the quadratic work, which the scan
   schedule cannot do (a lax.cond block-skip was measured SLOWER: TPU
   conditionals break scan pipelining; inside a Pallas kernel the loop
-  bound is a plain scalar and costs nothing). Round 3 put the
-  crossover at 4096 — an artifact of the kernel inheriting
-  attn_block=256 as its tile; the freed tile
-  (``MultiHeadAttention._pallas_block``, up to 512) moved it.
+  bound is a plain scalar and costs nothing). The tile is free of
+  attn_block (``MultiHeadAttention._pallas_block``, up to 512).
+* S=512 and 256 (PERF.md section 6, PR 27: the 110M LM at 16,384
+  tokens a step on a v5e): 151.3k vs the scan's 111.3k tok/s at
+  S=512, 159.0k vs 152.8k at S=256. At S=512, batch 32 the scan's
+  (B, H, S, block) score tile is 201 MB and every pass over it an HBM
+  round trip. A sequence of one tile has its own kernels
+  (``_tile_fwd_kernel`` / ``_tile_bwd_kernel``: no K loop, several
+  (batch, head) rows a program, the tile transposed so that the
+  per-query statistics are lane vectors); the general kernels read
+  136.2k at S=512 (tile 512) and 138.6k at S=256, under the scan.
+* S=128: the XLA scan (``parallel/flash.py``) wins, 167.8k vs 155.1k:
+  the shorter S, the smaller the scan's tile and the better its one
+  step fuses, while the kernels' cost a token does not fall with S.
 
 ``MultiHeadAttention`` therefore auto-selects: ``attn_impl=None``
-uses the scan below ``PALLAS_AUTO_MIN_S`` (1024) and these kernels at
+uses the scan below ``PALLAS_AUTO_MIN_S`` (256) and these kernels at
 or above it on a real TPU; ``attn_impl="scan"|"pallas"`` forces
 either. Inputs ride in the compute dtype (bf16 on TPU): half the
 VMEM — at S=8192 the difference between fitting and a scoped-vmem
@@ -60,6 +65,20 @@ escape hatch, not needed at current model scale).
 import functools
 
 import numpy
+
+
+@functools.lru_cache(maxsize=None)
+def jitted(fn, **static):
+    """``fn`` — :func:`flash_attention_fwd` or
+    :func:`flash_attention_bwd` — under ``jax.jit`` with its keyword
+    arguments bound, the SAME object for the same keywords: a step
+    that calls the kernel once a layer then traces its body once, not
+    once a layer. Tracing the 36 kernel calls of the 12-layer LM's
+    step one by one was 11 of the 14 s its program took to trace on
+    the v5e's host (PERF.md, PR 27), paid at every start, compile
+    cache or not."""
+    import jax
+    return jax.jit(functools.partial(fn, **static))
 
 
 def _on_tpu():
@@ -480,6 +499,127 @@ def _dkvq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
+#: the short-sequence kernels (``_tile_fwd_kernel`` /
+#: ``_tile_bwd_kernel``) take a sequence whose one tile is the whole
+#: (S, S) square, up to this S (the largest tile the general kernels
+#: use; 1024 blows scoped VMEM) ...
+TILE_MAX_S = 512
+#: ... and this many (batch, head) rows a program, where the number
+#: of rows allows
+TILE_ROWS = 4
+
+
+def _tile_rows(bh):
+    """Rows a program of the short-sequence kernels: the largest
+    power-of-two divisor of ``bh`` (= batch x heads as the kernel sees
+    them: per shard under ``parallel.kernel_per_shard``) up to
+    ``TILE_ROWS``."""
+    rows = 1
+    while rows * 2 <= TILE_ROWS and bh % (rows * 2) == 0:
+        rows *= 2
+    return rows
+
+
+def _tile_params(rows, s, dh, itemsize, interpret):
+    """``pallas_call`` keywords granting the short-sequence kernels
+    their scoped VMEM: per row the double-buffered operands (at most
+    four in, three out) and five (S, S) f32-sized temporaries, with a
+    2x margin for Mosaic's own slack; never under the 16MB default,
+    clamped to the device."""
+    if interpret:
+        return {}
+    from jax.experimental.pallas import tpu as pltpu
+    per_row = 2 * 7 * s * dh * itemsize + 5 * s * s * 4
+    limit = min(max(2 * rows * per_row, 16 << 20), _device_vmem_bytes())
+    return {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=limit)}
+
+
+def _tile_mask(jnp, s):
+    """(s, s) bool over (key, query): True where the key comes after
+    the query."""
+    from jax import lax
+    return lax.broadcasted_iota(jnp.int32, (s, s), 0) \
+        > lax.broadcasted_iota(jnp.int32, (s, s), 1)
+
+
+def _tile_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, rows,
+                     causal, scale):
+    """SHORT-SEQUENCE forward: the whole (S, S) score square of a
+    (batch, head) row is ONE tile, so there is no K loop and no
+    running state — plain softmax in VMEM — and a program takes
+    ``rows`` rows, straight-line, to share its fixed cost (~0.7 us a
+    program, a third of a one-row program at S=512).
+
+    The tile is held TRANSPOSED, keys on sublanes and queries on
+    lanes: the softmax's max and sum then run down the sublanes
+    (elementwise over vregs, no cross-lane reduction), the per-query
+    statistics are lane vectors that divide the (dh, S) context
+    without a relayout, and lse leaves lane-dense as (1, S) — a
+    (S, 1) block pads every value to a 128-lane row, which cost more
+    to write and to repack than the softmax (PERF.md, PR 27). The
+    price is two small transposes, v (S, dh) and the context."""
+    import jax.numpy as jnp
+
+    if causal:
+        after = _tile_mask(jnp, q_ref.shape[1])
+    for r in range(rows):
+        v = v_ref[r]
+        st = jnp.dot(k_ref[r], q_ref[r].T,
+                     preferred_element_type=jnp.float32) * scale
+        if causal:
+            st = jnp.where(after, jnp.float32(-1e9), st)
+        m = st.max(axis=0, keepdims=True)           # (1, s)
+        pt = jnp.exp(st - m)
+        l = pt.sum(axis=0, keepdims=True)
+        ctx_t = jnp.dot(v.T, pt.astype(v.dtype),
+                        preferred_element_type=jnp.float32)
+        o_ref[r] = (ctx_t / l).T.astype(o_ref.dtype)
+        lse_ref[r] = m + jnp.log(l)
+
+
+def _tile_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                     dq_ref, dk_ref, dv_ref, *, rows, causal, scale):
+    """SHORT-SEQUENCE backward, the pair of ``_tile_fwd_kernel`` and
+    transposed like it (lse and delta arrive as the (1, S) lane
+    vectors they are stored as; dv and dk are plain products of the
+    transposed tile, only dq transposes it back). One tile a row
+    means dq needs no accumulator across programs, so all three
+    gradients leave in the storage dtype: no f32 dq row and no
+    convert pass after the kernel. The same 5 products + 1 exp as
+    ``_dkvq_kernel``."""
+    import jax.numpy as jnp
+
+    if causal:
+        after = _tile_mask(jnp, q_ref.shape[1])
+    for r in range(rows):
+        q, k, v, do = q_ref[r], k_ref[r], v_ref[r], do_ref[r]
+        st = jnp.dot(k, q.T, preferred_element_type=jnp.float32) * scale
+        if causal:
+            st = jnp.where(after, jnp.float32(-1e9), st)
+        pt = jnp.exp(st - lse_ref[r])
+        dv_ref[r] = jnp.dot(
+            pt.astype(do.dtype), do,
+            preferred_element_type=jnp.float32).astype(dv_ref.dtype)
+        dpt = jnp.dot(v, do.T, preferred_element_type=jnp.float32)
+        dst = (pt * (dpt - delta_ref[r]) * scale).astype(q.dtype)
+        dk_ref[r] = jnp.dot(
+            dst, q,
+            preferred_element_type=jnp.float32).astype(dk_ref.dtype)
+        dq_ref[r] = jnp.dot(
+            dst.T, k,
+            preferred_element_type=jnp.float32).astype(dq_ref.dtype)
+
+
+def _tile_specs(rows, s, dh):
+    """Specs of the short-sequence kernels, ``rows`` whole (batch,
+    head) rows a program: (rows, S, dh) tensors and (rows, 1, S)
+    per-row scalars, the sequence on the lane dim."""
+    from jax.experimental import pallas as pl
+    return (pl.BlockSpec((rows, s, dh), lambda i: (i, 0, 0)),
+            pl.BlockSpec((rows, 1, s), lambda i: (i, 0, 0)))
+
+
 def _specs(block_rows, s, dh):
     """Row-blocked / full-rows specs for (BH, S, dh) tensors plus the
     matching specs for (BH, S, 1) per-row scalars (lse, delta) — the
@@ -512,7 +652,9 @@ def flash_attention_fwd(q, k, v, causal=True, block_q=128,
     running-context accumulator dtype; ``jnp.bfloat16`` is the gated
     accumulation experiment — lse/softmax statistics stay f32 either
     way, so only the PV accumulation chain narrows (error bound
-    pinned by the numerics test)."""
+    pinned by the numerics test). Both are experiments of the general
+    kernel; without them a sequence that is one tile (blocks == S <=
+    ``TILE_MAX_S``) runs the short-sequence ``_tile_fwd_kernel``."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -530,6 +672,23 @@ def flash_attention_fwd(q, k, v, causal=True, block_q=128,
     scale = numpy.float32(1.0 / numpy.sqrt(dh))
     qf = q.reshape(b * h, s, dh)
     kv_shape = (b * h, s, dh)
+    if block_q == block_k == s <= TILE_MAX_S and not pipeline \
+            and acc_dtype == jnp.float32:
+        rows = _tile_rows(b * h)
+        tensor, lanes = _tile_specs(rows, s, dh)
+        out, lse = pl.pallas_call(
+            functools.partial(_tile_fwd_kernel, rows=rows,
+                              causal=causal, scale=scale),
+            grid=(b * h // rows,),
+            in_specs=[tensor, tensor, tensor],
+            out_specs=[tensor, lanes],
+            out_shape=[jax.ShapeDtypeStruct(kv_shape, q.dtype),
+                       jax.ShapeDtypeStruct((b * h, 1, s),
+                                            jnp.float32)],
+            interpret=interpret,
+            **_tile_params(rows, s, dh, q.dtype.itemsize, interpret),
+        )(qf, k.reshape(kv_shape), v.reshape(kv_shape))
+        return (out.reshape(b, h, s, dh), lse.reshape(b, h, s))
     blocked, full, vec, _ = _specs(block_q, s, dh)
     if pipeline:
         pack = _kv_lane_pack(dh)
@@ -578,7 +737,9 @@ def flash_attention_bwd(q, k, v, out, lse, dout, causal=True,
     whole backward at the 110M S=8k shapes. ``fused=False`` keeps the
     classic dq-kernel + dkv-kernel pair (the reference formulation,
     retained for A/B and as the fallback if a Pallas/Mosaic change
-    ever breaks output-ref revisiting)."""
+    ever breaks output-ref revisiting). A sequence that is one tile
+    (blocks == S <= ``TILE_MAX_S``) runs the short-sequence
+    ``_tile_bwd_kernel`` in place of the fused one."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -604,9 +765,25 @@ def flash_attention_bwd(q, k, v, out, lse, dout, causal=True,
     delta_rows = delta_rows.astype(jnp.float32)
     delta = delta_rows.reshape(b * h, s, 1)
     delta_lanes = delta_rows.reshape(b * h, 1, s)
+    shape = (b, h, s, dh)
+    if fused and block_q == block_k == s <= TILE_MAX_S:
+        rows = _tile_rows(b * h)
+        tensor, lanes = _tile_specs(rows, s, dh)
+        grad = jax.ShapeDtypeStruct(flat, q.dtype)
+        dq, dk, dv = pl.pallas_call(
+            functools.partial(_tile_bwd_kernel, rows=rows,
+                              causal=causal, scale=scale),
+            grid=(b * h // rows,),
+            in_specs=[tensor, tensor, tensor, tensor, lanes, lanes],
+            out_specs=[tensor, tensor, tensor],
+            out_shape=[grad, grad, grad],
+            interpret=interpret,
+            **_tile_params(rows, s, dh, q.dtype.itemsize, interpret),
+        )(qf, kf, vf, dof, lse_lanes, delta_lanes)
+        return (dq.reshape(shape), dk.reshape(shape),
+                dv.reshape(shape))
     qblocked, qfull, qvec, qfull_vec = _specs(block_q, s, dh)
     kblocked, _, _, _ = _specs(block_k, s, dh)
-    shape = (b, h, s, dh)
 
     if fused:
         dkvq = functools.partial(_dkvq_kernel, block_q=block_q,
