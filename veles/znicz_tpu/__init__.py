@@ -2,8 +2,12 @@
 
 Rebuild of the reference znicz repo (SURVEY.md §2.4): every op is a
 *pair* of units — a ``Forward`` and a matching ``GradientDescent*``
-(explicit backprop as graph nodes, no autodiff on the main path;
-``jax.grad`` appears only as a test oracle, SURVEY.md §7 "Hard parts").
+(explicit backprop as graph nodes). The upstream zoo's backwards are
+derived by hand with a numpy oracle beside them; units added since
+PR 28 (``ops/vjp_units.py``: RMS norm, gated short convolution,
+grouped-query attention, SwiGLU, the no-drop expert layer) take their
+backward from ``jax.vjp`` of the same traced forward, and their oracle
+is the float32 reference under ``benchmark/reference/``.
 
 Subpackages:
 

@@ -45,12 +45,32 @@ root.lm.update({
     # — stash only layer inputs, recompute caches in the backward;
     # ~+1/3 compute for an O(heads*seq/12) stash cut (the (B, S)
     # envelope knob for the stacked path; docs/PARALLELISM.md)
+    # block: "post_ln" (the block above) or "pre_norm": pre-norm (RMS)
+    # residual layers, each an operator and a feed-forward, no bias, no
+    # absolute positions, a final RMS norm before the head. `layers`
+    # is a count of "full_attention" layers or, in either block, the
+    # list of the layers' operators; pre_norm also has "conv" (gated
+    # short convolution, conv_kernel taps), and its "full_attention" is
+    # heads query heads over kv_heads K/V heads of head_dim, q/k RMS
+    # norm, rotary positions at rope_theta. Its feed-forward is SwiGLU
+    # of width ffn_hidden in the first dense_layers layers, then a
+    # no-drop top-moe_top_k layer of moe_experts experts of width
+    # moe_hidden (sigmoid scores times moe_scaling, selection biases
+    # drawn at moe_bias_stddev), of which this job holds experts_held =
+    # [lo, hi) (None: all; the rest live on other chips and their part
+    # of the sum is left out). Every key is a shape or a constant the
+    # model's config states, none a tuning knob.
     "model": {"dim": 64, "heads": 4, "layers": 2, "ffn_hidden": 128,
               "attn_block": None, "attn_impl": None,
               "pallas_tile": None, "attn_pipeline": False,
               "attn_acc": None, "moe_experts": 0,
               "moe_capacity_factor": 2.0, "moe_aux_weight": 0.01,
-              "stacked": False, "remat": False},
+              "stacked": False, "remat": False,
+              "block": "post_ln", "kv_heads": None, "head_dim": None,
+              "dense_layers": 0, "moe_hidden": None, "moe_top_k": 1,
+              "experts_held": None, "moe_scaling": 1.0,
+              "moe_bias_stddev": 0.0, "conv_kernel": 3,
+              "rope_theta": 1e6, "norm_eps": 1e-5},
     "train": {"learning_rate": 0.05, "gradient_moment": 0.9,
               "weights_decay": 0.0},
     "decision": {"max_epochs": 8, "fail_iterations": 50},
@@ -188,13 +208,98 @@ class PeriodicLMLoader(FullBatchLoader):
         self.original_labels.mem = self.original_labels.mem[order]
 
 
+def layer_operators(m):
+    """``root.lm.model.layers`` as the list of the layers' operators:
+    an int N is N ``"full_attention"`` layers."""
+    layers = m.layers
+    if isinstance(layers, int):
+        return ["full_attention"] * layers
+    if not isinstance(layers, (list, tuple)) or not layers:
+        raise ValueError("layers is a count or the list of the "
+                         "layers' operators, got %r" % (layers,))
+    return list(layers)
+
+
+def pre_norm_body(m, t):
+    """The layers of ``block="pre_norm"`` between embedding and head
+    (the keys' meaning is beside ``root.lm.model``)."""
+    operators = layer_operators(m)
+    if set(operators) - {"conv", "full_attention"}:
+        raise ValueError("block='pre_norm' has the operators 'conv' "
+                         "and 'full_attention', got %r" % (operators,))
+    busy = {k: v for k, v in root.lm.parallel.to_dict().items()
+            if k in ("seq", "model", "expert", "pipe") and v > 1}
+    if busy or m.get("stacked"):
+        raise ValueError(
+            "block='pre_norm' trains on the per-unit path, one chip or "
+            "data-parallel; not with stacked=%r / parallel %r"
+            % (m.get("stacked"), busy))
+    eps = m.get("norm_eps", 1e-5)
+    body = []
+    for index, kind in enumerate(operators):
+        if kind == "conv":
+            body.append({
+                "type": "short_conv",
+                "->": {"kernel": m.get("conv_kernel", 3), "eps": eps},
+                "<-": dict(t)})
+        else:
+            body.append({
+                "type": "gqa_attention",
+                "->": dict(attention_kernel_keys(m), heads=m.heads,
+                           kv_heads=m.get("kv_heads"),
+                           head_dim=m.get("head_dim"),
+                           rope_theta=m.get("rope_theta", 1e6),
+                           eps=eps),
+                "<-": dict(t)})
+        if index < m.get("dense_layers", 0):
+            body.append({"type": "swiglu_ffn",
+                         "->": {"hidden": m.ffn_hidden, "eps": eps},
+                         "<-": dict(t)})
+        else:
+            body.append({
+                "type": "expert_ffn",
+                "->": {"experts": m.moe_experts,
+                       "top_k": m.get("moe_top_k", 1),
+                       "hidden": m.moe_hidden,
+                       "experts_held": m.get("experts_held"),
+                       "scaling": m.get("moe_scaling", 1.0),
+                       "bias_stddev": m.get("moe_bias_stddev", 0.0),
+                       "eps": eps},
+                "<-": dict(t)})
+    body.append({"type": "rms_norm", "->": {"eps": eps}, "<-": dict(t)})
+    return body
+
+
+def attention_kernel_keys(m):
+    """The keys that choose the attention proper's kernel, the same
+    for every block's attention unit."""
+    return {"attn_block_size": m.get("attn_block"),
+            "attn_impl": m.get("attn_impl"),
+            "pallas_tile": m.get("pallas_tile"),
+            "attn_pipeline": m.get("attn_pipeline", False),
+            "attn_acc": m.get("attn_acc")}
+
+
 def build_layers():
     m = root.lm.model
     t = root.lm.train.to_dict()
+    block = m.get("block", "post_ln")
+    if block not in ("post_ln", "pre_norm"):
+        raise ValueError("unknown block %r" % (block,))
+    pre_norm = block == "pre_norm"
+    vocab = root.lm.loader.vocab
+    # pre-norm: rotary positions inside the attention units, no bias
     layers = [{"type": "embedding",
-               "->": {"vocab_size": root.lm.loader.vocab,
-                      "dim": m.dim},
+               "->": dict({"vocab_size": vocab, "dim": m.dim},
+                          **({"add_positions": False} if pre_norm
+                             else {})),
                "<-": dict(t)}]
+    head = {"type": "token_dense",
+            "->": dict({"output_features": vocab},
+                       **({"include_bias": False} if pre_norm else {})),
+            "<-": dict(t)}
+    if pre_norm:
+        return layers + pre_norm_body(m, t) + [head]
     if m.get("stacked"):
         if m.get("moe_experts"):
             raise ValueError(
@@ -217,9 +322,7 @@ def build_layers():
                     "hidden": m.ffn_hidden, "causal": True,
                     "remat": bool(m.get("remat"))},
              "<-": dict(t)},
-            {"type": "token_dense",
-             "->": {"output_features": root.lm.loader.vocab},
-             "<-": dict(t)}]
+            head]
         return layers
     if m.get("moe_experts"):
         ffn_layer = {
@@ -233,24 +336,21 @@ def build_layers():
         ffn_layer = {"type": "transformer_ffn",
                      "->": {"hidden": m.ffn_hidden, "residual": True},
                      "<-": dict(t)}
-    for _ in range(m.layers):
+    operators = layer_operators(m)
+    if set(operators) - {"full_attention"}:
+        raise ValueError("block='post_ln' has the operator "
+                         "'full_attention' alone, got %r" % (operators,))
+    for _ in operators:
         layers += [
             {"type": "attention",
-             "->": {"heads": m.heads, "causal": True,
-                    "residual": True,
-                    "attn_block_size": m.get("attn_block"),
-                    "attn_impl": m.get("attn_impl"),
-                    "pallas_tile": m.get("pallas_tile"),
-                    "attn_pipeline": m.get("attn_pipeline", False),
-                    "attn_acc": m.get("attn_acc")},
+             "->": dict(attention_kernel_keys(m), heads=m.heads,
+                        causal=True, residual=True),
              "<-": dict(t)},
             {"type": "layernorm", "<-": dict(t)},
             dict(ffn_layer),
             {"type": "layernorm", "<-": dict(t)},
         ]
-    layers.append({"type": "token_dense",
-                   "->": {"output_features": root.lm.loader.vocab},
-                   "<-": dict(t)})
+    layers.append(head)
     return layers
 
 

@@ -57,6 +57,17 @@ from veles.znicz_tpu.ops.attention import (  # noqa: F401
 from veles.znicz_tpu.ops.moe import (  # noqa: F401
     MoEFFN, GDMoEFFN,
 )
+from veles.znicz_tpu.ops.rmsnorm import RMSNorm, GDRMSNorm  # noqa: F401
+from veles.znicz_tpu.ops.short_conv import (  # noqa: F401
+    ShortConv, GDShortConv,
+)
+from veles.znicz_tpu.ops.swiglu import SwiGLUFFN, GDSwiGLUFFN  # noqa: F401
+from veles.znicz_tpu.ops.gqa_attention import (  # noqa: F401
+    GQAttention, GDGQAttention,
+)
+from veles.znicz_tpu.ops.expert_ffn import (  # noqa: F401
+    ExpertFFN, GDExpertFFN,
+)
 from veles.znicz_tpu.ops.transformer_stack import (  # noqa: F401
     TransformerBlockStack, GDTransformerBlockStack,
 )

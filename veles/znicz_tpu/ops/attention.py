@@ -22,6 +22,7 @@ chip); a ``seq_mesh`` shards the sequence ACROSS chips via the
 """
 
 import contextlib
+import functools
 
 import numpy
 
@@ -587,14 +588,11 @@ class MultiHeadAttention(Forward):
         consumer is a matmul, the probs/ds tiles inside the scan
         inherit it (halving their HBM traffic), and the backward
         caches cost half the memory."""
-        from veles.znicz_tpu.parallel import flash
         q, k, v = self._project_qkv(x, p, dot)
         if cd is not None:
             q, k, v = q.astype(cd), k.astype(cd), v.astype(cd)
         with _core_scope():
-            out_heads, lse = flash.blocked_attention_fwd(
-                q, k, v, causal=self.causal,
-                block=self.attn_block_size, dot=dot)
+            out_heads, lse = self.core_fwd(None, "scan", q, k, v, dot)
         merged = self._merge(out_heads)
         y = self._finish(x, merged, p, dot)
         return y, (q, k, v, out_heads, lse, merged)
@@ -632,25 +630,58 @@ class MultiHeadAttention(Forward):
             kernel, ctx._compiler.device.mesh, self.kernel_batch_axis,
             self.kernel_head_axis, in_kinds, out_kinds)
 
+    def core_fwd(self, ctx, mode, q, k, v, dot=None):
+        """The attention proper of the flash modes, "pallas" and
+        "scan", on (B, H, S, dh) heads in the compute type ->
+        (out_heads, lse). The one place that turns a mode into its
+        kernel: a unit that brings its own projections
+        (``ops/gqa_attention.py``) calls this and :meth:`core_bwd`,
+        like the paths below, inside ``_core_scope()``."""
+        if mode == "pallas":
+            import jax.numpy as jnp
+            from veles.znicz_tpu.parallel import pallas_attention as PA
+            blk = self._pallas_block()
+            kernel = self._pallas_on_mesh(ctx, PA.jitted(
+                PA.flash_attention_fwd, causal=self.causal,
+                block_q=blk, block_k=blk,
+                interpret=self._pallas_interpret(ctx),
+                pipeline=self.attn_pipeline,
+                acc_dtype=jnp.bfloat16 if self.attn_acc == "bf16"
+                else None), "ttt", "tr")
+            return kernel(q, k, v)
+        from veles.znicz_tpu.parallel import flash
+        return flash.blocked_attention_fwd(
+            q, k, v, causal=self.causal, block=self.attn_block_size,
+            dot=dot or ctx.dot)
+
+    def core_bwd(self, ctx, mode, q, k, v, out_heads, lse, dctx):
+        """-> (dq, dk, dv) of :meth:`core_fwd`; ``dctx`` by head."""
+        cd = ctx._compiler.device.compute_dtype
+        if mode == "pallas":
+            from veles.znicz_tpu.parallel import pallas_attention as PA
+            blk = self._pallas_block()
+            kernel = self._pallas_on_mesh(ctx, PA.jitted(
+                PA.flash_attention_bwd, causal=self.causal,
+                block_q=blk, block_k=blk,
+                interpret=self._pallas_interpret(ctx)),
+                "ttttrt", "ttt")     # q k v out lse dout -> dq dk dv
+            return kernel(q, k, v, out_heads, lse, dctx.astype(cd))
+        from veles.znicz_tpu.parallel import flash
+        return flash.blocked_attention_bwd(
+            q, k, v, out_heads, lse, dctx.astype(cd),
+            causal=self.causal, block=self.attn_block_size,
+            dot=ctx.dot)
+
     def _fwd_pallas(self, xp, x, p, ctx):
         """Flash forward on the hand-written Pallas TPU kernel.
         q/k/v in the compute dtype (bf16 on TPU): half the kernel's
         VMEM (K/V ride whole rows — the difference between S=8k
         fitting and a scoped-vmem OOM) and matched MXU input dtypes."""
-        import jax.numpy as jnp
-        from veles.znicz_tpu.parallel import pallas_attention as PA
-        blk = self._pallas_block()
         cd = ctx._compiler.device.compute_dtype
         q, k, v = (t.astype(cd)
                    for t in self._project_qkv(x, p, ctx.dot))
-        kernel = self._pallas_on_mesh(ctx, PA.jitted(
-            PA.flash_attention_fwd, causal=self.causal, block_q=blk,
-            block_k=blk, interpret=self._pallas_interpret(ctx),
-            pipeline=self.attn_pipeline,
-            acc_dtype=jnp.bfloat16 if self.attn_acc == "bf16"
-            else None), "ttt", "tr")
         with _core_scope():
-            out_heads, lse = kernel(q, k, v)
+            out_heads, lse = self.core_fwd(ctx, "pallas", q, k, v)
         merged = self._merge(out_heads)
         y = self._finish(x, merged, p, ctx.dot)
         return y, (q, k, v, out_heads, lse, merged)
@@ -802,31 +833,13 @@ class GDMultiHeadAttention(GradientDescentBase):
                 batch_axis=f.seq_batch_axis, inner=inner, block=block,
                 dot=ctx.dot, interpret=f._pallas_interpret(ctx)))
 
-    def _bwd_blocked(self, xp, x, p, ctx, err):
-        """Single-chip flash-style backward (block recomputation)."""
-        from veles.znicz_tpu.parallel import flash
-        f = self.forward
-        cd = ctx._compiler.device.compute_dtype
+    def _bwd_flash(self, xp, x, p, ctx, err, mode):
+        """Backward of the single-chip flash modes ("scan": block
+        recomputation; "pallas": the kernels), by the forward's
+        ``core_bwd``."""
         return self._bwd_outer(
             xp, x, p, ctx, err,
-            lambda q, k, v, o, lse, dctx: flash.blocked_attention_bwd(
-                q, k, v, o, lse, dctx.astype(cd), causal=f.causal,
-                block=f.attn_block_size, dot=ctx.dot))
-
-    def _bwd_pallas(self, xp, x, p, ctx, err):
-        """Flash backward on the Pallas kernels."""
-        from veles.znicz_tpu.parallel import pallas_attention as PA
-        f = self.forward
-        blk = f._pallas_block()
-        cd = ctx._compiler.device.compute_dtype
-        kernel = f._pallas_on_mesh(ctx, PA.jitted(
-            PA.flash_attention_bwd, causal=f.causal, block_q=blk,
-            block_k=blk, interpret=f._pallas_interpret(ctx)),
-            "ttttrt", "ttt")     # q k v out lse dout -> dq dk dv
-        return self._bwd_outer(
-            xp, x, p, ctx, err,
-            lambda q, k, v, o, lse, dctx: kernel(
-                q, k, v, o, lse, dctx.astype(cd)))
+            functools.partial(self.forward.core_bwd, ctx, mode))
 
     def xla_run(self, ctx):
         import jax.numpy as jnp
@@ -837,12 +850,9 @@ class GDMultiHeadAttention(GradientDescentBase):
         mode = f._traced_mode(ctx, x.shape[1])
         if mode == "ring":
             dx, gw, gb, gwo, gbo = self._bwd_ring(jnp, x, p, ctx, err)
-        elif mode == "pallas":
-            dx, gw, gb, gwo, gbo = self._bwd_pallas(
-                jnp, x, p, ctx, err)
-        elif mode == "scan":
-            dx, gw, gb, gwo, gbo = self._bwd_blocked(
-                jnp, x, p, ctx, err)
+        elif mode in ("pallas", "scan"):
+            dx, gw, gb, gwo, gbo = self._bwd_flash(
+                jnp, x, p, ctx, err, mode)
         else:
             cache = tuple(ctx.get(f, "cache_" + n)
                           for n in ("q", "k", "v", "probs", "merged"))
