@@ -590,7 +590,10 @@ def pallas_variants(size, interpret, dtype):
     """{name: (jitted function, argument specs)} — every Pallas variant
     the repo ships, at the train_long shapes of ``size``, and the
     short-sequence kernels (one tile a row, what the auto rule runs
-    at S=512) at train_short's S. The kernels child runs them;
+    at S=512) at train_short's S. ``fwd`` and ``bwd_fused`` are the
+    K-loop kernels a step runs (score tile held (keys, queries), V
+    handed over as (BH, dh, S), lse and dq leaving lane-dense). The
+    kernels child runs them;
     tests/test_chip_smoke.py lowers the same table for the TPU
     without a chip."""
     import jax

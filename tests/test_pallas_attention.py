@@ -364,7 +364,7 @@ def test_auto_rule_takes_the_kernels_from_s256_on_a_tpu(s, mode):
 
 @pytest.mark.parametrize("s,tile", [
     (128, 128), (256, 256), (512, 512), (768, 256),
-    (1024, 512), (8192, 512)])
+    (1024, 512), (2048, 512), (4096, 512), (8192, 512)])
 def test_pallas_block_is_the_measured_table(s, tile):
     assert _auto_unit()._pallas_block(s) == tile
     assert _auto_unit(pallas_tile=128)._pallas_block(s) == 128
@@ -425,6 +425,93 @@ def test_tile_kernels_match_dense_core(b, h, s, dh, causal):
     for name, r, g in zip(("dq", "dk", "dv"), grads_ref, got):
         assert numpy.allclose(numpy.asarray(g), r, atol=2e-4), \
             (name, numpy.abs(numpy.asarray(g) - r).max())
+
+
+def _dense_lse(q, k, causal):
+    """log-sum-exp of the scaled (masked) scores, dense float64."""
+    scale = 1.0 / numpy.sqrt(q.shape[-1])
+    s = numpy.einsum("bhqd,bhkd->bhqk", q.astype(numpy.float64),
+                     k.astype(numpy.float64)) * scale
+    if causal:
+        n = q.shape[2]
+        s = numpy.where(numpy.arange(n)[None, :]
+                        > numpy.arange(n)[:, None], -numpy.inf, s)
+    top = s.max(axis=-1, keepdims=True)
+    return (top + numpy.log(numpy.exp(s - top).sum(
+        axis=-1, keepdims=True)))[..., 0]
+
+
+@pytest.mark.parametrize("b,h,s,bq,bk,causal,hoist_delta", [
+    (1, 3, 512, 128, 128, True, False),     # BH odd, 4 tiles a row
+    (1, 2, 512, 128, 128, False, True),
+    (1, 2, 1024, 256, 256, True, True),
+    (1, 1, 1024, 256, 256, False, False),
+    (1, 2, 512, 256, 128, True, False),     # block_q > block_k
+    (1, 2, 512, 128, 256, True, True),      # block_q < block_k
+    (1, 1, 512, 256, 128, False, True),
+    (1, 1, 512, 128, 256, False, False),
+], ids=str)
+def test_kloop_kernels_match_dense_core(b, h, s, bq, bk, causal,
+                                        hoist_delta):
+    """The K-loop kernels (several tiles a row: what every S above
+    512 runs, S=8192 at tile 512 on the chip) at head size 64 against
+    the dense float32 core: out, the lane-dense lse, the fused
+    backward — with ``delta`` hoisted by the caller, as the ring
+    does, and not — and the fused backward against ``fused=False``."""
+    prng.seed_all(915)
+    gen = prng.get("pa7")
+    q, k, v, dout = (gen.normal(0, 1.0, (b, h, s, 64)).astype(
+        numpy.float32) for _ in range(4))
+    out_ref, grads_ref = _dense_core(q, k, v, dout, causal)
+    out, lse = PA.flash_attention_fwd(
+        q, k, v, causal=causal, block_q=bq, block_k=bk,
+        interpret=True)
+    assert lse.shape == (b, h, s) and lse.dtype == numpy.float32
+    assert out.shape == (b, h, s, 64)
+    assert numpy.allclose(numpy.asarray(lse), _dense_lse(q, k, causal),
+                          atol=2e-5)
+    assert numpy.allclose(numpy.asarray(out), out_ref, atol=2e-5), \
+        numpy.abs(numpy.asarray(out) - out_ref).max()
+    delta = (dout * out_ref).sum(axis=-1) if hoist_delta else None
+    got = PA.flash_attention_bwd(
+        q, k, v, out, lse, dout, causal=causal, block_q=bq,
+        block_k=bk, interpret=True, delta=delta)
+    two = PA.flash_attention_bwd(
+        q, k, v, out, lse, dout, causal=causal, block_q=bq,
+        block_k=bk, interpret=True, delta=delta, fused=False)
+    for name, r, g, t in zip(("dq", "dk", "dv"), grads_ref, got, two):
+        assert g.shape == (b, h, s, 64)
+        assert numpy.allclose(numpy.asarray(g), r, atol=2e-4), \
+            (name, numpy.abs(numpy.asarray(g) - r).max())
+        assert numpy.allclose(numpy.asarray(g), numpy.asarray(t),
+                              atol=2e-5), name
+
+
+#: what the v5e compiler needs for the fused backward, MB of scoped
+#: VMEM, found by lowering the grant until the compile fails (PR 29;
+#: s, dh, block_q, block_k -> need)
+_FUSED_BWD_NEED_MB = [
+    ((8192, 64, 512, 512), 15.7),       # the S=8192 cells
+    ((8192, 64, 256, 256), 13.6),
+    ((8192, 64, 128, 128), 12.7),
+    ((16384, 64, 512, 512), 28.0),
+    ((8192, 128, 512, 512), 16.2),
+    ((8192, 64, 1024, 1024), 22.4),
+]
+
+
+@pytest.mark.parametrize("shape,need_mb", _FUSED_BWD_NEED_MB, ids=str)
+def test_fused_bwd_vmem_limit_covers_the_transposed_resident_set(
+        shape, need_mb):
+    """The grant follows the kernel's resident set — q and do rows,
+    the (dh, S) float32 dq accumulator, lse/delta lanes, double
+    buffered — and covers what the compiler was seen to need without
+    claiming three times that."""
+    grant = PA._fused_bwd_vmem_limit(*shape, 2, device_vmem=128 << 20)
+    assert need_mb * 2 ** 20 < grant < 3 * need_mb * 2 ** 20
+    # a device that cannot hold it is refused, the escape hatch named
+    with pytest.raises(ValueError, match="fused=False"):
+        PA._fused_bwd_vmem_limit(*shape, 2, device_vmem=8 << 20)
 
 
 def test_tile_kernels_equal_the_general_kernels(monkeypatch):

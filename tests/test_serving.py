@@ -698,11 +698,12 @@ def test_fused_bwd_vmem_limit_tracks_footprint():
     small = _fused_bwd_vmem_limit(512, 64, 128, 128, 2,
                                   device_vmem=128 << 20)
     assert small == 16 << 20
-    # the measured S=8k case: grant covers the observed 16.75MB need
-    # without claiming the whole chip
+    # the measured S=8k case: grant covers the observed 12.7MB need
+    # (the v5e compiler's, PR 29: the transposed kernel's resident
+    # set; 16.75MB before it) without claiming the whole chip
     grant = _fused_bwd_vmem_limit(8192, 64, 128, 128, 2,
                                   device_vmem=128 << 20)
-    assert (17 << 20) < grant < (64 << 20)
+    assert (13 << 20) < grant < (32 << 20)
     # monotone in S, never past the device capacity
     bigger = _fused_bwd_vmem_limit(16384, 64, 128, 128, 2,
                                    device_vmem=128 << 20)

@@ -605,12 +605,27 @@ class MultiHeadAttention(Forward):
         of ``s`` up to 512 — the measured v5e optimum at both ends of
         the auto-select regime: at S=512 one 512 tile a row beat 256
         and 128 (the table above ``PALLAS_AUTO_MIN_S``; a sequence of
-        one tile runs ``pallas_attention``'s short-sequence kernels),
-        at long S an earlier builder's 57M LM read tile 512 vs the
-        old attn_block=256 as 111k vs 82k tok/s at S=4096, 80k vs 53k
-        at S=8192 (tile 1024 blows scoped VMEM). ``attn_block_size``
-        tunes the SCAN formulation and no longer constrains the
-        kernel tile (honoring it cost 36-50% at long S, round 4)."""
+        one tile runs ``pallas_attention``'s short-sequence kernels).
+        Above it (PERF.md section 6, PR 29: the K-loop kernels alone,
+        a layer call of 32,768 tokens, 12 heads of 64, ms forward /
+        fused backward by tile):
+
+          S=8192   256: 13.30 / 20.29   512: 6.77 / 13.08   1024: 6.08 / 12.73
+          S=4096                        512: 3.80 /  7.61   1024: 3.54 /  7.69
+          S=2048                        512: 2.35 /  4.61   1024: 2.37 /  5.00
+          S=1024                        512: 1.63 /  3.22   1024: 1.73 /  3.28
+
+        (unequal tiles at S=8192: 1024x512 6.12 / 13.02, 512x1024
+        6.45 / 13.43, 2048x512 6.49 / 13.63; a 2048 key tile does not
+        fit VMEM). 1024 is 3% of a step faster from S=4096 up — a
+        tile costs a fixed ~0.1 us of loop overhead whatever its
+        size, against 6% more masked work on the diagonal — and was
+        NOT taken: its kernels are 0.4 MB of code larger at each of a
+        step's 36 call sites, and the 110M step's peak memory at
+        S=8192 rose 19 MB where the 512 tile lowers it
+        (``pallas_tile=1024`` sets it by hand). ``attn_block_size``
+        tunes the SCAN formulation and does not constrain the kernel
+        tile (honoring it cost 36-50% at long S, round 4)."""
         if s is None:
             s = self.input.shape[1]
         if self.pallas_tile:

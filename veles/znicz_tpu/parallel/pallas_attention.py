@@ -13,6 +13,13 @@ Real TPU kernels keeping the score tile and the softmax state in VMEM
   conditionals break scan pipelining; inside a Pallas kernel the loop
   bound is a plain scalar and costs nothing). The tile is free of
   attn_block (``MultiHeadAttention._pallas_block``, up to 512).
+  These K-loop kernels hold the score tile TRANSPOSED, keys on
+  sublanes and queries on lanes, as the one-tile kernels below do
+  (PERF.md section 6, PR 29: a layer call at B=4, H=12, S=8192,
+  dh=64 alone on a v5e, tile 512, 8.33 -> 6.77 ms forward, 16.06 ->
+  13.08 ms fused backward; 6.08 / 12.73 at tile 1024): every
+  per-query statistic is a lane vector, no (tile, tile) transpose
+  and no lane-to-column relayout is left in a pair loop.
 * S=512 and 256 (PERF.md section 6, PR 27: the 110M LM at 16,384
   tokens a step on a v5e): 151.3k vs the scan's 111.3k tok/s at
   S=512, 159.0k vs 152.8k at S=256. At S=512, batch 32 the scan's
@@ -32,9 +39,13 @@ or above it on a real TPU; ``attn_impl="scan"|"pallas"`` forces
 either. Inputs ride in the compute dtype (bf16 on TPU): half the
 VMEM — at S=8192 the difference between fitting and a scoped-vmem
 OOM — and matched MXU input dtypes. Per-row lse/delta tensors are
-shipped as (BH, 1, S) with the sequence on the LANE dim: a (BH, S, 1)
-layout pads its trailing singleton to 128 lanes and explodes VMEM
-(S·128·4 bytes per ref — the original S=8k backward compile failure).
+shipped as (BH, 1, S) with the sequence on the LANE dim, out of the
+forward and into the backward alike: a (BH, S, 1) layout pads its
+trailing singleton to 128 lanes and explodes VMEM (S·128·4 bytes per
+ref — the original S=8k backward compile failure) and HBM (201 MB a
+layer call at S=8k, B=4, which the forward wrote and XLA repacked
+until PR 29). Only the default-off pipelined forward and two-kernel
+backward still use that layout.
 
 Exact math (same online softmax as flash.py / ring.py; verified
 against both in tests — interpret mode on CPU, real kernels on TPU):
@@ -43,10 +54,21 @@ against both in tests — interpret mode on CPU, real kernels on TPU):
 * :func:`flash_attention_bwd` — block-recomputation backward from the
   saved logsumexp. Default (round 5): ONE fused kernel computes
   dq/dk/dv in a single pass over the k-block grid (``_dkvq_kernel``;
-  dq accumulates in a VMEM-resident revisited output ref — legal
-  because the TPU Pallas grid is sequential), 5 block matmuls + 1 exp
-  per causal pair vs the classic two-pass form's 7 + 2 (retained
-  behind ``fused=False``); measured +38% at the 110M S=8k shapes.
+  dq accumulates, transposed and lane-dense, in a VMEM-resident
+  revisited output ref — legal because the TPU Pallas grid is
+  sequential), 5 block matmuls + 1 exp per causal pair vs the classic
+  two-pass form's 7 + 2 (retained behind ``fused=False``); measured
+  +38% at the 110M S=8k shapes.
+
+What bounds them at head size 64 (PR 29, a trivial Pallas kernel of
+chained bf16 products on the v5e): every product has 64 as its
+contraction or its output width, which half-fills the 128x128 MXU —
+(512x64)·(512x64)^T reads 68 TFLOP/s, (512x512)·(512x64) 71,
+(64x512)·(512x512) 113, a full-depth (512x512)·(512x512) 154. The
+forward uses the first and the third form, the backward 2 + 2 + 1:
+timed one form at a time that is 0.79 and 2.2 us a 512 pair, and in
+a step the kernels run a pair in 0.99 and 1.92 us — the backward,
+interleaving its five products, is at their speed.
 
 Causal masking is paid only where it can matter (round 5): the
 fori_loops split at the diagonal — blocks fully below it skip the
@@ -56,10 +78,17 @@ Consumed by ``MultiHeadAttention(attn_impl="pallas")``; backward is
 wired through the explicit GD unit (znicz style), so no custom-VJP
 registration is needed — autodiff never touches these.
 
-VMEM budget: K and V ride whole per-(batch·head) rows in VMEM, so
-S·dh·8 bytes must fit comfortably (≈16 MB/core) — S up to ~16k at
-dh=64. Beyond that, block K/V from HBM with manual DMA (documented
+VMEM budget: K and V ride whole per-(batch·head) rows in VMEM — the
+forward's K row (lane-padded) and transposed V row are S·dh·6 bytes
+at dh=64, double-buffered inside the 16 MB default up to S=16k (it
+compiles there for a described v5e, dh=64 and 128; the parent's
+failed from S=12k) — and the fused backward q, do and the f32 dq row,
+granted 1.5x their footprint of the v5e's 128 MB
+(``_fused_bwd_vmem_limit``: 27 MB at S=8k, tile 512). Beyond that,
+block K/V from HBM with manual DMA (``pipeline=True``: documented
 escape hatch, not needed at current model scale).
+On the chip a tile is a multiple of 128 lanes, or the whole S: the
+lane-dense lse and dq blocks want it (the backward always did).
 """
 
 import functools
@@ -106,19 +135,29 @@ def _fused_bwd_vmem_limit(s, dh, block_q, block_k, itemsize,
     generations before v5, where the fused path can fail to fit while
     ``fused=False`` works).
 
-    Resident per grid step: full q/do rows (storage dtype), the full
-    f32 dq accumulator, lse/delta lanes, the k/v/dk/dv blocks and the
-    (block_q, block_k) f32 score/prob temporaries. The 6x margin
-    covers Mosaic's double buffering and spill slack (measured 16.75MB
-    actual vs ~4.4MB resident at S=8k/dh=64/bf16 — a 3.8x ratio).
+    Resident per grid step, as VMEM holds it — every operand of the
+    call twice (Pallas double-buffers a block even when its index
+    never moves), a minor dim under 128 lanes padded to 128, a (1, S)
+    row to 8 sublanes: the full q/do rows (storage dtype), the full
+    TRANSPOSED f32 dq accumulator (dh, S), the lse/delta lanes, the
+    k/v/dk/dv blocks; once, four (block_k, block_q) f32 score/prob
+    temporaries. The 1.5x margin is Mosaic's slack over that: what
+    the v5e compiler really needs is 0.54-0.93 of the footprint
+    (PR 29, found by lowering the grant until the compile fails:
+    15.6MB of 18MB at S=8k/dh=64/bf16/tile 512, 22.4 of 31 at tile
+    1024, 12.6 of 13.5 at tile 128, 28.0 of 31 at S=16k, 16.1 of 22
+    at dh=128; the (queries, keys) kernel before it needed 20.5MB at
+    tile 512, 16.8 at tile 128).
     Raises with the escape hatches when even that exceeds the device:
     ``fused=False`` (the two-kernel backward never holds dq resident)
     or a smaller ``pallas_tile``."""
-    resident = (s * dh * (2 * itemsize + 4)    # q + do + f32 dq
-                + 2 * 4 * s                    # lse + delta lanes
-                + 4 * block_k * dh * itemsize  # k/v/dk/dv blocks
-                + 4 * block_q * block_k * 4)   # score/prob temps
-    need = 6 * resident
+    lanes = max(dh, 128)
+    resident = (2 * (2 * s * lanes * itemsize       # q + do rows
+                     + dh * s * 4                   # f32 dq_t
+                     + 2 * 8 * s * 4                # lse + delta
+                     + 4 * block_k * lanes * itemsize)  # k/v/dk/dv
+                + 4 * block_q * block_k * 4)        # score/prob temps
+    need = resident * 3 // 2
     vmem = device_vmem if device_vmem is not None \
         else _device_vmem_bytes()
     limit = min(max(need, 16 << 20), vmem)
@@ -179,8 +218,36 @@ def _online_softmax_step(jnp, parts, carry, acc_dtype):
     return m_new, l_new, acc_new
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q,
+#: contract the LAST dim of both operands, ``a @ b.T`` without the
+#: transpose as an operation of its own (the MXU takes its latched
+#: operand either way round)
+_NT = (((1,), (1,)), ((), ()))
+
+
+def _after(jnp, k0, q0, block_k, block_q):
+    """(block_k, block_q) bool over (key, query) of the tile whose
+    first key is ``k0`` and first query ``q0``: True where the key
+    comes after the query — what the causal mask hides."""
+    from jax import lax
+    shape = (block_k, block_q)
+    return k0 + lax.broadcasted_iota(jnp.int32, shape, 0) \
+        > q0 + lax.broadcasted_iota(jnp.int32, shape, 1)
+
+
+def _fwd_kernel(q_ref, k_ref, vt_ref, o_ref, lse_ref, *, block_q,
                 block_k, n_kb, causal, scale, acc_dtype):
+    """K-LOOP forward, one q block a program, the K row and the
+    TRANSPOSED V row (dh, S) resident. The score tile is held
+    transposed, (keys on sublanes, queries on lanes), as the one-tile
+    kernels hold it: the running max, sum and rescale factor are
+    (1, block_q) lane vectors, ``max`` / ``sum`` run down the sublanes
+    (elementwise over vregs), ``st - m`` and ``acc_t * coef`` are
+    sublane broadcasts, and the carry is the (dh, block_q) context plus
+    two lane vectors — inside the register file, where the (queries,
+    keys) form carried three (block_q, .) columns of one used lane in
+    128 (PERF.md section 6, PR 29). One (dh, block_q) transpose ends
+    the program; lse leaves lane-dense, a (1, block_q) block of
+    (BH, 1, S)."""
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -188,27 +255,38 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q,
 
     qi = pl.program_id(1)
     qb = q_ref[0]                                   # (bq, dh)
-    bq, dh = qb.shape
-    rows = qi * block_q + lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
+    dh = qb.shape[1]
 
     def make_body(masked):
         def body(j, carry):
-            kb = k_ref[0, pl.ds(j * block_k, block_k), :]
-            vb = v_ref[0, pl.ds(j * block_k, block_k), :]
-            s = jnp.dot(qb, kb.T,
-                        preferred_element_type=jnp.float32) * scale
+            m, l, acc_t = carry
+            k0 = pl.multiple_of(j * block_k, block_k)
+            kb = k_ref[0, pl.ds(k0, block_k), :]    # (bk, dh)
+            vt = vt_ref[0, :, pl.ds(k0, block_k)]   # (dh, bk)
+            st = lax.dot_general(
+                kb, qb, _NT,
+                preferred_element_type=jnp.float32) * scale
             if masked:
-                cols = j * block_k + lax.broadcasted_iota(
-                    jnp.int32, (block_q, block_k), 1)
-                s = jnp.where(cols > rows, jnp.float32(-1e9), s)
-            return _online_softmax_step(jnp, [(s, vb)], carry,
-                                        acc_dtype)
+                st = jnp.where(
+                    _after(jnp, k0, qi * block_q, block_k, block_q),
+                    jnp.float32(-1e9), st)
+            m_new = jnp.maximum(m, st.max(axis=0, keepdims=True))
+            coef = jnp.exp(m - m_new)               # (1, bq)
+            pt = jnp.exp(st - m_new)
+            l_new = l * coef + pt.sum(axis=0, keepdims=True)
+            # pt in the storage dtype (bf16 on TPU) for the product —
+            # exp stays f32, the MXU gets matched input dtypes and
+            # accumulates in f32 whatever ``acc_dtype`` carries
+            pv_t = jnp.dot(vt, pt.astype(vt.dtype),
+                           preferred_element_type=jnp.float32)
+            acc_new = acc_t * coef.astype(acc_dtype) \
+                + pv_t.astype(acc_dtype)
+            return m_new, l_new, acc_new
         return body
 
-    m0 = jnp.full((block_q, 1), -jnp.inf, jnp.float32)
-    l0 = jnp.zeros((block_q, 1), jnp.float32)
-    acc0 = jnp.zeros((block_q, dh), acc_dtype)
+    m0 = jnp.full((1, block_q), -jnp.inf, jnp.float32)
+    l0 = jnp.zeros((1, block_q), jnp.float32)
+    acc0 = jnp.zeros((dh, block_q), acc_dtype)
     if causal:
         # K blocks past this Q block's last row are all-masked — skip
         # them entirely; only the diagonal remnant needs the mask
@@ -217,9 +295,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q,
         spans = [(0, clear, False), (clear, hi, True)]
     else:
         spans = [(0, n_kb, False)]
-    m, l, acc = _split_loop(spans, make_body, (m0, l0, acc0))
-    o_ref[0] = (acc.astype(jnp.float32) / l).astype(o_ref.dtype)
-    lse_ref[0] = m + jnp.log(l)                     # (bq, 1)
+    m, l, acc_t = _split_loop(spans, make_body, (m0, l0, acc0))
+    o_ref[0] = (acc_t.astype(jnp.float32) / l).T.astype(o_ref.dtype)
+    lse_ref[0] = m + jnp.log(l)                     # (1, bq)
 
 
 def _kv_lane_pack(dh):
@@ -426,19 +504,28 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _dkvq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                 dk_ref, dv_ref, dq_ref, *, block_q, block_k, n_qb,
+                 dk_ref, dv_ref, dqt_ref, *, block_q, block_k, n_qb,
                  causal, scale):
     """FUSED backward: one pass over the (q-block, k-block) pairs
     computes dk, dv AND dq — where the two-kernel form ran 7 block
     matmuls and 2 exp passes per pair (s and dp recomputed in each
     kernel), this runs 5 and 1.
 
-    The trick is TPU Pallas' SEQUENTIAL grid: dq rides as a full
-    (1, S, dh) f32 output ref whose block index is constant in the
-    ki grid dim, so the buffer is revisited across k-blocks and
-    accumulated in place (zeroed at ki == 0, flushed to HBM when the
-    bh index advances) — the accumulation pattern a parallel-grid GPU
-    kernel would need atomics for."""
+    The trick is TPU Pallas' SEQUENTIAL grid: dq rides as a full-row
+    f32 output ref whose block index is constant in the ki grid dim,
+    so the buffer is revisited across k-blocks and accumulated in
+    place (zeroed at ki == 0, flushed to HBM when the bh index
+    advances) — the accumulation pattern a parallel-grid GPU kernel
+    would need atomics for.
+
+    The tile is held TRANSPOSED like the forward's, (keys, queries):
+    lse and delta are used as the (1, block_q) lane vectors they are
+    stored as, dv and dk are plain products of the transposed tile
+    (``pt @ do``, ``dst @ q``), and dq accumulates transposed too,
+    ``dq_t[:, q block] += k_blk.T @ dst`` with ``k_blk.T`` made once a
+    program: the resident accumulator is (dh, S), lane-dense. No
+    (block, block) transpose and no lane-to-column relayout is left
+    in the pair loop."""
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -448,38 +535,37 @@ def _dkvq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     kb = k_ref[0]                                   # (bk, dh)
     vb = v_ref[0]
     bk, dh = kb.shape
-    cols = ki * block_k + lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
+    kb_t = kb.T                                     # (dh, bk)
 
     @pl.when(ki == 0)
     def _init():
-        dq_ref[0] = jnp.zeros_like(dq_ref[0])
+        dqt_ref[0] = jnp.zeros_like(dqt_ref[0])
 
     def make_body(masked):
         def body(j, carry):
             dk, dv = carry
-            qb = q_ref[0, pl.ds(j * block_q, block_q), :]
-            dob = do_ref[0, pl.ds(j * block_q, block_q), :]
-            lse = lse_ref[0, 0, pl.ds(j * block_q, block_q)][:, None]
-            delta = delta_ref[0, 0,
-                              pl.ds(j * block_q, block_q)][:, None]
-            s = jnp.dot(qb, kb.T,
-                        preferred_element_type=jnp.float32) * scale
+            q0 = pl.multiple_of(j * block_q, block_q)
+            rows = pl.ds(q0, block_q)
+            qb = q_ref[0, rows, :]                  # (bq, dh)
+            dob = do_ref[0, rows, :]
+            st = lax.dot_general(
+                kb, qb, _NT,
+                preferred_element_type=jnp.float32) * scale
             if masked:
-                rows = j * block_q + lax.broadcasted_iota(
-                    jnp.int32, (block_q, block_k), 0)
-                s = jnp.where(cols > rows, jnp.float32(-1e9), s)
-            p = jnp.exp(s - lse)
-            dv = dv + jnp.dot(p.astype(dob.dtype).T, dob,
+                st = jnp.where(
+                    _after(jnp, ki * block_k, q0, block_k, block_q),
+                    jnp.float32(-1e9), st)
+            pt = jnp.exp(st - lse_ref[0, :, rows])  # lse: (1, bq)
+            dv = dv + jnp.dot(pt.astype(dob.dtype), dob,
                               preferred_element_type=jnp.float32)
-            dp = jnp.dot(dob, vb.T,
-                         preferred_element_type=jnp.float32)
-            ds = (p * (dp - delta) * scale).astype(qb.dtype)
-            dk = dk + jnp.dot(ds.T, qb,
+            dpt = lax.dot_general(
+                vb, dob, _NT, preferred_element_type=jnp.float32)
+            dst = (pt * (dpt - delta_ref[0, :, rows])
+                   * scale).astype(qb.dtype)
+            dk = dk + jnp.dot(dst, qb,
                               preferred_element_type=jnp.float32)
-            sl = pl.ds(j * block_q, block_q)
-            dq_ref[0, sl, :] = dq_ref[0, sl, :] + jnp.dot(
-                ds, kb, preferred_element_type=jnp.float32)
+            dqt_ref[0, :, rows] = dqt_ref[0, :, rows] + jnp.dot(
+                kb_t, dst, preferred_element_type=jnp.float32)
             return dk, dv
         return body
 
@@ -501,8 +587,8 @@ def _dkvq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 #: the short-sequence kernels (``_tile_fwd_kernel`` /
 #: ``_tile_bwd_kernel``) take a sequence whose one tile is the whole
-#: (S, S) square, up to this S (the largest tile the general kernels
-#: use; 1024 blows scoped VMEM) ...
+#: (S, S) square, up to this S (a 1024 square, several rows a
+#: program, blows scoped VMEM) ...
 TILE_MAX_S = 512
 #: ... and this many (batch, head) rows a program, where the number
 #: of rows allows
@@ -639,10 +725,13 @@ def _specs(block_rows, s, dh):
 def flash_attention_fwd(q, k, v, causal=True, block_q=128,
                         block_k=128, interpret=None, pipeline=False,
                         acc_dtype=None):
-    """q/k/v: (B, H, S, dh) → (out, lse); exact. Blocks must divide
-    S. ``interpret``: False = the real Mosaic kernel, True = the
-    Pallas interpreter (how the CPU tests run the same code); None
-    asks jax's default device (:func:`_on_tpu`).
+    """q/k/v: (B, H, S, dh) → (out (B, H, S, dh), lse (B, H, S)
+    f32); exact. Blocks must divide S. ``interpret``: False = the
+    real Mosaic kernel, True = the Pallas interpreter (how the CPU
+    tests run the same code); None asks jax's default device
+    (:func:`_on_tpu`). The K-loop kernel takes V as (BH, dh, S) — one
+    XLA transpose a call — and writes lse lane-dense as (BH, 1, S),
+    the layout the backward reads.
 
     ``pipeline=True`` keeps K/V in HBM and double-buffers each block
     into VMEM scratch (``_fwd_kernel_pipe``): the next block's DMA
@@ -703,21 +792,32 @@ def flash_attention_fwd(q, k, v, causal=True, block_q=128,
             acc_dtype=acc_dtype, kv_dtype=k.dtype, pack=pack)
         kv_spec = pl.BlockSpec(memory_space=pl.ANY)
         kv_shape = (b * h, s // pack, pack * dh)
+        v_spec, vf = kv_spec, v.reshape(kv_shape)
+        lse_spec, lse_shape = vec, (b * h, s, 1)
     else:
         kernel = functools.partial(
             _fwd_kernel, block_q=block_q, block_k=block_k,
             n_kb=s // block_k, causal=causal, scale=scale,
             acc_dtype=acc_dtype)
         kv_spec = full
+        # V as (BH, dh, S): one O(S*dh) XLA transpose a call, so that
+        # the kernel's P.V product needs none a pair and V's resident
+        # row is lane-dense; lse leaves as (BH, 1, S) blocks, the
+        # layout the backward reads
+        v_spec = pl.BlockSpec((1, dh, s), lambda bh, i: (bh, 0, 0))
+        vf = v.reshape(kv_shape).swapaxes(1, 2)
+        lse_spec = pl.BlockSpec((1, 1, block_q),
+                                lambda bh, i: (bh, 0, i))
+        lse_shape = (b * h, 1, s)
     out, lse = pl.pallas_call(
         kernel,
         grid=(b * h, s // block_q),
-        in_specs=[blocked, kv_spec, kv_spec],
-        out_specs=[blocked, vec],
+        in_specs=[blocked, kv_spec, v_spec],
+        out_specs=[blocked, lse_spec],
         out_shape=[jax.ShapeDtypeStruct((b * h, s, dh), q.dtype),
-                   jax.ShapeDtypeStruct((b * h, s, 1), jnp.float32)],
+                   jax.ShapeDtypeStruct(lse_shape, jnp.float32)],
         interpret=interpret,
-    )(qf, k.reshape(kv_shape), v.reshape(kv_shape))
+    )(qf, k.reshape(kv_shape), vf)
     return (out.reshape(b, h, s, dh), lse.reshape(b, h, s))
 
 
@@ -731,10 +831,13 @@ def flash_attention_bwd(q, k, v, out, lse, dout, causal=True,
     from HBM every call.
 
     ``fused=True`` (default) runs the single-pass dk/dv/dq kernel
-    (``_dkvq_kernel`` — dq accumulated in a revisited output ref
-    across the sequential k-block grid): 5 block matmuls + 1 exp per
-    pair instead of the two-kernel form's 7 + 2, measured +38% (10.5 -> 7.65 ms) on the
-    whole backward at the 110M S=8k shapes. ``fused=False`` keeps the
+    (``_dkvq_kernel`` — dq accumulated transposed, (BH, dh, S) f32,
+    in a revisited output ref across the sequential k-block grid,
+    transposed back and converted in one XLA pass here): 5 block
+    matmuls + 1 exp per pair instead of the two-kernel form's 7 + 2,
+    measured +38% on the whole backward at the 110M S=8k shapes by an
+    earlier builder; 13.08 ms a layer call of B=4 at tile 512, 12.73
+    at 1024 (PR 29). ``fused=False`` keeps the
     classic dq-kernel + dkv-kernel pair (the reference formulation,
     retained for A/B and as the fallback if a Pallas/Mosaic change
     ever breaks output-ref revisiting). A sequence that is one tile
@@ -790,32 +893,36 @@ def flash_attention_bwd(q, k, v, out, lse, dout, causal=True,
                                  block_k=block_k,
                                  n_qb=s // block_q,
                                  causal=causal, scale=scale)
-        # dq: full-row f32 accumulator, block index CONSTANT in ki so
-        # the sequential grid revisits (and keeps) it in VMEM
-        dq_full_f32 = pl.BlockSpec((1, s, dh), lambda bh, i: (bh, 0, 0))
-        # the resident q/do/dq rows push past the default 16MB scoped-
-        # vmem budget at S=8k inside a larger program (measured
-        # 16.75MB) — grant the kernel what its footprint needs,
-        # clamped to the device generation's actual VMEM
+        # dq: full-row f32 accumulator, TRANSPOSED (dh, S) so that it
+        # is lane-dense, block index CONSTANT in ki so the sequential
+        # grid revisits (and keeps) it in VMEM
+        dqt_full_f32 = pl.BlockSpec((1, dh, s),
+                                    lambda bh, i: (bh, 0, 0))
+        # the resident q/do/dq rows reach the default 16MB scoped-
+        # vmem budget at S=8k (15.6MB at tile 512, 22.4 at 1024) —
+        # grant the kernel what its footprint needs, clamped to the
+        # device generation's actual VMEM
         params = {}
         if not interpret:
             from jax.experimental.pallas import tpu as pltpu
             params["compiler_params"] = pltpu.CompilerParams(
                 vmem_limit_bytes=_fused_bwd_vmem_limit(
                     s, dh, block_q, block_k, q.dtype.itemsize))
-        dk, dv, dq = pl.pallas_call(
+        dk, dv, dq_t = pl.pallas_call(
             dkvq,
             grid=(b * h, s // block_k),
             in_specs=[qfull, kblocked, kblocked, qfull, qfull_vec,
                       qfull_vec],
-            out_specs=[kblocked, kblocked, dq_full_f32],
+            out_specs=[kblocked, kblocked, dqt_full_f32],
             out_shape=[jax.ShapeDtypeStruct(flat, q.dtype),
                        jax.ShapeDtypeStruct(flat, q.dtype),
-                       jax.ShapeDtypeStruct(flat, jnp.float32)],
+                       jax.ShapeDtypeStruct((b * h, dh, s),
+                                            jnp.float32)],
             interpret=interpret,
             **params,
         )(qf, kf, vf, dof, lse_lanes, delta_lanes)
-        return (dq.astype(q.dtype).reshape(shape),
+        # transpose and convert in one XLA pass
+        return (dq_t.swapaxes(1, 2).astype(q.dtype).reshape(shape),
                 dk.reshape(shape), dv.reshape(shape))
 
     dq = pl.pallas_call(
