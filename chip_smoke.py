@@ -14,7 +14,7 @@ layers, FFN 3072, vocab 16384; random weights from ``--seed 1``):
 2. ``train_long`` — the same entry point at S=8192, batch 4 (Pallas
    forward + fused backward); its compiled step must hold Mosaic
    kernels (``tpu_custom_call`` in the optimized HLO);
-3. ``kernels`` — every shipped Pallas variant compiled for real
+3. ``kernels`` — every shipped Pallas kernel compiled for real
    (``interpret=False``) at the step-2 shapes against the dense float32
    reference, then a check of whether ``block_until_ready`` blocks;
 4. ``serve`` — ``python velescli.py serve --backend jit`` on the
@@ -121,7 +121,6 @@ LEGS = (
 #: the tier-1 tests' own, absolute (tests/test_pallas_attention.py,
 #: tests/test_pallas_grads.py)
 TOL_BF16 = 2e-2         # bf16 tensors (what the chip computes in)
-TOL_BF16_ACC = 1.5e-2   # the forward output under a bf16 accumulator
 TOL_F32 = 2e-4          # float32 tensors (the CPU rehearsal)
 TOL_LSE = 1e-4          # the row statistics, which never narrow
 #: the tests hold those bounds on O(1) outputs; at S=8192 a gradient
@@ -587,7 +586,7 @@ def child_train(size, device, workdir, leg):
 
 
 def pallas_variants(size, interpret, dtype):
-    """{name: (jitted function, argument specs)} — every Pallas variant
+    """{name: (jitted function, argument specs)} — every Pallas kernel
     the repo ships, at the train_long shapes of ``size``, and the
     short-sequence kernels (one tile a row, what the auto rule runs
     at S=512) at train_short's S. ``fwd`` and ``bwd_fused`` are the
@@ -611,23 +610,17 @@ def pallas_variants(size, interpret, dtype):
         row = jax.ShapeDtypeStruct((b, h, s), jnp.float32)
         return (t, t, t), (t, t, t, t, row, t)  # bwd: q k v out lse dout
 
-    def attn(fn, tile=cfg["tile"], **kwargs):
+    def attn(fn, tile=cfg["tile"]):
         return jax.jit(functools.partial(
             fn, causal=True, block_q=tile, block_k=tile,
-            interpret=interpret, **kwargs))
+            interpret=interpret))
 
     fwd, bwd = shapes(b, s)
     s_short = cfg["train_short"]["seq_len"]
     fwd_short, bwd_short = shapes(cfg["kernels_short_batch"], s_short)
     variants = {
         "fwd": (attn(PA.flash_attention_fwd), fwd),
-        "fwd_pipeline": (attn(PA.flash_attention_fwd, pipeline=True),
-                         fwd),
-        "fwd_acc_bf16": (attn(PA.flash_attention_fwd,
-                              acc_dtype=jnp.bfloat16), fwd),
-        "bwd_fused": (attn(PA.flash_attention_bwd, fused=True), bwd),
-        "bwd_two_kernel": (attn(PA.flash_attention_bwd, fused=False),
-                           bwd),
+        "bwd_fused": (attn(PA.flash_attention_bwd), bwd),
         "fwd_short": (attn(PA.flash_attention_fwd, tile=s_short),
                       fwd_short),
         "bwd_short": (attn(PA.flash_attention_bwd, tile=s_short),
@@ -642,7 +635,7 @@ def pallas_variants(size, interpret, dtype):
 
 
 def child_kernels(size, device):
-    """Compile and run every shipped Pallas variant for real at the
+    """Compile and run every shipped Pallas kernel for real at the
     train_long shapes, compare each with the dense float32 reference,
     then time how ``block_until_ready`` and a read-back wait."""
     sys.path.insert(0, HERE)
@@ -685,30 +678,23 @@ def child_kernels(size, device):
         check(net <= atol,
               "kernel %s: error %.3e > %.3e" % (name, net, atol))
 
-    def check_attention(fwd_names, bwd_names):
-        """Run the forward variants ``fwd_names`` (the last one the
-        plain forward) and the backward variants ``bwd_names`` on one
-        random q, k, v, dout of their shape and hold every result to
-        the dense float32 reference."""
-        q, k, v, dout = (rand(variants[fwd_names[-1]][1][0])
+    def check_attention(fwd_name, bwd_name):
+        """Run the forward ``fwd_name`` and, on its out and lse, the
+        backward ``bwd_name`` on one random q, k, v, dout of their
+        shape and hold every result to the dense float32 reference."""
+        q, k, v, dout = (rand(variants[fwd_name][1][0])
                          for _ in range(4))
         b, h, s, dh = q.shape
         got, bounds = {}, {}    # "variant.tensor" -> result, (atol, rtol)
-        for name in fwd_names:
-            out, lse = variants[name][0](q, k, v)
-            got[name + ".out"], got[name + ".lse"] = out, lse
-            # a bf16 accumulator is in the bf16 regime whatever the
-            # inputs
-            bounds[name + ".out"] = (
-                TOL_BF16_ACC if name == "fwd_acc_bf16" else tol, rtol)
-            bounds[name + ".lse"] = (TOL_LSE, 0.0)
-        # the backward forms take the plain forward's out and lse
-        for name in bwd_names:
-            for gname, g in zip(
-                    ("dq", "dk", "dv"),
-                    variants[name][0](q, k, v, out, lse, dout)):
-                got["%s.%s" % (name, gname)] = g
-                bounds["%s.%s" % (name, gname)] = (tol, rtol)
+        out, lse = variants[fwd_name][0](q, k, v)
+        got[fwd_name + ".out"], got[fwd_name + ".lse"] = out, lse
+        bounds[fwd_name + ".out"] = (tol, rtol)
+        bounds[fwd_name + ".lse"] = (TOL_LSE, 0.0)
+        for gname, g in zip(
+                ("dq", "dk", "dv"),
+                variants[bwd_name][0](q, k, v, out, lse, dout)):
+            got["%s.%s" % (bwd_name, gname)] = g
+            bounds["%s.%s" % (bwd_name, gname)] = (tol, rtol)
         for name, g in got.items():
             check(g.shape == (q.shape[:3] if name.endswith(".lse")
                               else q.shape),
@@ -755,9 +741,8 @@ def child_kernels(size, device):
             settle(name, float(worst[name][0]), float(worst[name][1]),
                    bounds[name][0])
 
-    check_attention(("fwd_acc_bf16", "fwd_pipeline", "fwd"),
-                    ("bwd_fused", "bwd_two_kernel"))
-    check_attention(("fwd_short",), ("bwd_short",))
+    check_attention("fwd", "bwd_fused")
+    check_attention("fwd_short", "bwd_short")
 
     for n, kk, act in cfg["bias_grad"]:
         name = "bias_grad_%dx%d_%s" % (n, kk, act)

@@ -57,6 +57,26 @@ def _model_health_isolation():
         yield
 
 
+@pytest.fixture(autouse=True, scope="module")
+def _mnist_config_isolation():
+    """Workflow builders (``make_wf`` in tests/test_service.py and its
+    copies) set ``root.mnist`` for their small runs and do not restore
+    it; which file a worker runs next is a matter of timing, so the
+    leak must end with the FILE that made it (test_mnist_functional
+    trains on whatever sizes it finds: 500 samples read 0.46 where
+    6000 read 0.09)."""
+    from veles.config import root
+    # the sample's module-level defaults must be in root BEFORE the
+    # snapshot, or a never-touched key restores as an explicit None
+    from veles.znicz_tpu.models import mnist  # noqa: F401
+    saved_loader = {k: root.mnist.loader.get(k)
+                    for k in ("minibatch_size", "n_train", "n_valid")}
+    saved_epochs = root.mnist.decision.get("max_epochs")
+    yield
+    root.mnist.loader.update(saved_loader)
+    root.mnist.decision.max_epochs = saved_epochs
+
+
 @pytest.fixture(autouse=True)
 def _tenant_table_isolation():
     """The per-tenant QoS table (veles/serving/tenants.py) is

@@ -172,14 +172,26 @@ def test_mid_job_kill_requeues_and_completes():
 
 def test_slave_reconnects_through_connection_kill():
     """Auto-reconnect: sever the slave's connection mid-run (via the
-    proxy) — run_forever re-hellos on a fresh lease and finishes."""
+    proxy) — run_forever re-hellos on a fresh lease and finishes. The
+    kill is an EVENT of the run, not a moment on the clock: the
+    slave's third job request dies with every live connection, from
+    inside the proxy's own pump."""
     master_wf = make_wf("ReconMaster", max_epochs=None)
     master_wf.decision.max_epochs = 2
     server = MasterServer(master_wf, "127.0.0.1:0", max_epochs=2,
                           slave_timeout=5.0)
     server.start_background()
+    severed = []
 
-    with ChaosProxy(("127.0.0.1", server.bound_address[1])) as proxy:
+    def plan(evt):
+        if evt.direction == C2S and evt.kind == "job" \
+                and evt.nth == 3 and not severed:
+            severed.append(proxy.kill_all())
+            return DROP
+        return None
+
+    with ChaosProxy(("127.0.0.1", server.bound_address[1]),
+                    plan=plan) as proxy:
         slave_wf = make_wf("ReconSlave")
         slave_wf.is_slave = True
         client = SlaveClient(slave_wf, proxy.address, name="recon",
@@ -189,15 +201,14 @@ def test_slave_reconnects_through_connection_kill():
         t = threading.Thread(
             target=lambda: done.append(client.run_forever()))
         t.start()
-        deadline = time.time() + 30
-        while time.time() < deadline and client.jobs_done < 2:
-            time.sleep(0.01)
-        assert client.jobs_done >= 2, "slave never got going"
-        proxy.kill_all()
         t.join(timeout=120)
         assert done, "slave did not survive the kill"
+    assert severed == [1], "the kill never happened"
     assert server.done.is_set()
     assert client.reconnects >= 1
+    # the master saw the connection END (the proxy hangs up on both
+    # peers), long before the run's remaining jobs were served: no
+    # waiting for its silent-peer sweep
     assert server.status()["faults"]["drops"] >= 1
 
 

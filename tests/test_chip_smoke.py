@@ -55,14 +55,14 @@ def test_every_pallas_variant_lowers_for_tpu(monkeypatch):
     """Each variant of the smoke's table, ``interpret=False``, at the
     full train_long shapes in bf16, lowers to a Mosaic custom call for
     the TPU platform. Lowering is not compiling (VMEM limits and tile
-    alignment are the chip run's job), but it is where
-    ``acc_dtype=bf16`` was caught asking the MXU for a bf16
-    accumulator — and it needs no chip."""
+    alignment are the chip run's job), but it is where a kernel that
+    asks the MXU for a bf16 accumulator is caught — and it needs no
+    chip."""
     # the fused backward sizes its VMEM grant from the device it
     # compiles for; there is none here, so name the v5e's 128 MiB
     monkeypatch.setattr(PA, "_device_vmem_bytes", lambda: 128 << 20)
     variants = chip_smoke.pallas_variants("full", False, jnp.bfloat16)
-    assert len(variants) == 9
+    assert len(variants) == 6
     for name, (fn, specs) in variants.items():
         text = fn.trace(*specs).lower(
             lowering_platforms=("tpu",)).as_text()
@@ -99,8 +99,8 @@ def test_every_pallas_variant_compiles_for_v5e(tmp_path):
     """The step past lowering, still without a chip: libtpu is
     installed, so a compile-only v5e topology runs the real TPU
     compiler — Mosaic's VMEM limits and (8,128) tiling rules included
-    (this is where the pipelined forward's 64-lane DMA window was
-    refused) — over the same table at the same shapes."""
+    (this is where a 64-lane DMA window is refused) — over the same
+    table at the same shapes."""
     proc = subprocess.run(
         [sys.executable, "-c", _COMPILE_FOR_V5E], cwd=REPO,
         env=dict(os.environ, JAX_PLATFORMS="cpu",
@@ -113,7 +113,7 @@ def test_every_pallas_variant_compiles_for_v5e(tmp_path):
         pytest.skip("libtpu gives no compile-only v5e topology here: "
                     + proc.stdout.strip()[:200])
     assert proc.returncode == 0, proc.stderr[-3000:]
-    assert proc.stdout.count("COMPILED ") == 9, proc.stdout
+    assert proc.stdout.count("COMPILED ") == 6, proc.stdout
 
 
 def test_smoke_refuses_a_machine_without_a_tpu():

@@ -66,34 +66,6 @@ def test_pallas_bwd_matches_scan_flash(case):
              numpy.abs(numpy.asarray(g) - numpy.asarray(r)).max())
 
 
-@pytest.mark.parametrize("case", CASES, ids=lambda c: str(c))
-def test_pallas_bwd_fused_matches_two_kernel(case):
-    """The single-pass dk/dv/dq kernel (dq accumulated in a revisited
-    output ref across the sequential k-block grid — round 5, measured
-    +38% on the backward at S=8k) must agree leaf-for-leaf with the
-    retained two-kernel formulation."""
-    q, k, v = _qkv(case["s"])
-    prng.seed_all(911)
-    dout = prng.get("pa3").normal(0, 1.0, q.shape).astype(
-        numpy.float32)
-    out, lse = PA.flash_attention_fwd(
-        q, k, v, causal=case["causal"], block_q=case["block"],
-        block_k=case["block"], interpret=True)
-    two = PA.flash_attention_bwd(
-        q, k, v, out, lse, dout, causal=case["causal"],
-        block_q=case["block"], block_k=case["block"], interpret=True,
-        fused=False)
-    one = PA.flash_attention_bwd(
-        q, k, v, out, lse, dout, causal=case["causal"],
-        block_q=case["block"], block_k=case["block"], interpret=True,
-        fused=True)
-    for name, a, b in zip(("dq", "dk", "dv"), two, one):
-        assert numpy.allclose(numpy.asarray(a), numpy.asarray(b),
-                              atol=2e-5), \
-            (name,
-             numpy.abs(numpy.asarray(a) - numpy.asarray(b)).max())
-
-
 @pytest.mark.parametrize("bq,bk", [(32, 16), (16, 32)],
                          ids=["bq>bk", "bq<bk"])
 @pytest.mark.parametrize("causal", [True, False],
@@ -101,8 +73,8 @@ def test_pallas_bwd_fused_matches_two_kernel(case):
 def test_pallas_unequal_blocks(bq, bk, causal):
     """UNEQUAL block_q/block_k exercise the hand-derived diagonal
     split boundaries (round 5: the floor/ceil clear points differ
-    from the trivial qi/ki±1 values only here) — fwd vs the scan
-    flash, and BOTH backward forms vs the scan backward."""
+    from the trivial qi/ki±1 values only here) — forward and fused
+    backward vs the scan flash."""
     s = 64
     q, k, v = _qkv(s)
     prng.seed_all(912)
@@ -119,153 +91,14 @@ def test_pallas_unequal_blocks(bq, bk, causal):
                           atol=2e-5)
     refs = flash.blocked_attention_bwd(
         q, k, v, out_ref, lse_ref, dout, causal=causal, block=16)
-    for fused in (False, True):
-        got = PA.flash_attention_bwd(
-            q, k, v, out, lse, dout, causal=causal, block_q=bq,
-            block_k=bk, interpret=True, fused=fused)
-        for name, r, g in zip(("dq", "dk", "dv"), refs, got):
-            assert numpy.allclose(numpy.asarray(g), numpy.asarray(r),
-                                  atol=2e-4), \
-                (fused, name,
-                 numpy.abs(numpy.asarray(g) - numpy.asarray(r)).max())
-
-
-@pytest.mark.parametrize("case", CASES, ids=lambda c: str(c))
-def test_pallas_fwd_pipelined_matches_resident(case):
-    """The DMA-pipelined forward (K/V in HBM, double-buffered block
-    scratch) is a pure data-movement change: out and lse must match
-    the resident-rows kernel to float tolerance."""
-    q, k, v = _qkv(case["s"])
-    out_ref, lse_ref = PA.flash_attention_fwd(
-        q, k, v, causal=case["causal"], block_q=case["block"],
-        block_k=case["block"], interpret=True)
-    out, lse = PA.flash_attention_fwd(
-        q, k, v, causal=case["causal"], block_q=case["block"],
-        block_k=case["block"], interpret=True, pipeline=True)
-    assert numpy.allclose(numpy.asarray(out), numpy.asarray(out_ref),
-                          atol=2e-5), \
-        numpy.abs(numpy.asarray(out) - numpy.asarray(out_ref)).max()
-    assert numpy.allclose(numpy.asarray(lse), numpy.asarray(lse_ref),
-                          atol=2e-5)
-
-
-@pytest.mark.parametrize("bq,bk", [(32, 16), (16, 32)],
-                         ids=["bq>bk", "bq<bk"])
-def test_pallas_fwd_pipelined_unequal_blocks(bq, bk):
-    """Unequal tiles stress the pipelined loop's causal bound (hi =
-    cdiv over block_k while the DMA window is block_k-sized)."""
-    q, k, v = _qkv(64)
-    out_ref, lse_ref = flash.blocked_attention_fwd(
-        q, k, v, causal=True, block=16)
-    out, lse = PA.flash_attention_fwd(
-        q, k, v, causal=True, block_q=bq, block_k=bk,
-        interpret=True, pipeline=True)
-    assert numpy.allclose(numpy.asarray(out), numpy.asarray(out_ref),
-                          atol=2e-5)
-    assert numpy.allclose(numpy.asarray(lse), numpy.asarray(lse_ref),
-                          atol=2e-5)
-
-
-def test_pallas_fwd_bf16_accumulate_numerics_gate():
-    """THE gate for the bf16-accumulation experiment: against the f32-
-    accumulated reference the output error must stay within the bf16
-    input-rounding regime (~2^-8 relative on O(1) softmax-weighted
-    averages), and the lse — whose statistics deliberately stay f32 —
-    must remain exact. If a kernel change ever narrows the softmax
-    chain too, this is the test that fires."""
-    import jax.numpy as jnp
-    q, k, v = _qkv(128, b=2, h=2, dh=16)
-    for causal in (True, False):
-        ref, lse_ref = PA.flash_attention_fwd(
-            q, k, v, causal=causal, block_q=32, block_k=32,
-            interpret=True)
-        out, lse = PA.flash_attention_fwd(
-            q, k, v, causal=causal, block_q=32, block_k=32,
-            interpret=True, acc_dtype=jnp.bfloat16)
-        err = numpy.abs(numpy.asarray(out) - numpy.asarray(ref)).max()
-        assert err < 1.5e-2, err          # bf16 accumulation regime
-        assert err > 0.0                  # the variant really ran
-        assert numpy.allclose(numpy.asarray(lse),
-                              numpy.asarray(lse_ref), atol=2e-5)
-
-
-def test_attention_unit_pipelined_path():
-    """attn_pipeline=True through the unit: forward matches the dense
-    numpy oracle and the backward (which reads the cached out/lse —
-    layout unchanged by the pipelined forward) still agrees."""
-    wf, feed, fwd, gd, x, err, comp = build(
-        MultiHeadAttention, input_shape=(2, 32, 16), gd_kwargs={},
-        heads=2, attn_impl="pallas", attn_block_size=16,
-        attn_pipeline=True)
-    golden = numpy.array(fwd.output.mem)
-    params0 = comp.gather_params()
-    state0 = comp.gather_state()
-    y = xla_forward(comp, feed, fwd, params0, x)
-    assert numpy.allclose(numpy.asarray(y), golden, atol=3e-5)
-    gd.numpy_run()
-    ei_np = numpy.array(gd.err_input.mem)
-    ei_x, _ = xla_backward(comp, feed, fwd, gd, params0, state0,
-                           x, err)
-    assert numpy.allclose(ei_np, numpy.asarray(ei_x), atol=3e-4)
-
-
-def test_attention_unit_bf16_acc_path():
-    """attn_acc='bf16' through the unit, forward AND backward: the
-    experimental arm's gradients must stay within the bf16-acc
-    numerics regime of the dense numpy oracle — a forward-only gate
-    would let a backward-side regression ship on exactly the A/B run
-    the knob exists for (the backward consumes the bf16-accumulated
-    out/lse via delta = rowsum(dout*out))."""
-    wf, feed, fwd, gd, x, err, comp = build(
-        MultiHeadAttention, input_shape=(2, 32, 16), gd_kwargs={},
-        heads=2, attn_impl="pallas", attn_block_size=16,
-        attn_acc="bf16")
-    golden = numpy.array(fwd.output.mem)
-    params0 = comp.gather_params()
-    state0 = comp.gather_state()
-    y = xla_forward(comp, feed, fwd, params0, x)
-    assert numpy.allclose(numpy.asarray(y), golden, atol=2e-2)
-    gd.numpy_run()
-    ei_np = numpy.array(gd.err_input.mem)
-    ei_x, params1 = xla_backward(comp, feed, fwd, gd, params0, state0,
-                                 x, err)
-    assert numpy.allclose(ei_np, numpy.asarray(ei_x), atol=2e-2), \
-        numpy.abs(ei_np - numpy.asarray(ei_x)).max()
-    for pname in fwd.PARAMS:
-        w1_np = getattr(fwd, pname).map_read().mem
-        w1_x = numpy.asarray(params1[fwd.name][pname])
-        assert numpy.allclose(w1_np, w1_x, atol=3e-2), pname
-
-
-def test_attention_unit_rejects_bad_attn_acc():
-    from veles.workflow import Workflow
-    wf = Workflow(None, name="wf-acc")
-    with pytest.raises(ValueError):
-        MultiHeadAttention(wf, heads=2, attn_acc="fp64")
-
-
-def test_attention_unit_rejects_inert_fwd_experiments():
-    """attn_pipeline/attn_acc='bf16' on a dispatch that resolves to
-    any non-pallas mode (dense/scan/ring) must raise loudly (like
-    transformer_lm's stacked guard), never run the other kernel with
-    a silently inert knob — the worst failure mode for an A/B."""
-    from veles.workflow import Workflow
-    wf = Workflow(None, name="wf-inert")
-    for kwargs in ({"attn_pipeline": True}, {"attn_acc": "bf16"}):
-        dense = MultiHeadAttention(wf, heads=2, **kwargs)
-        with pytest.raises(ValueError, match="pallas"):
-            dense._traced_mode(None, 32)
-        scan = MultiHeadAttention(wf, heads=2, attn_impl="scan",
-                                  attn_block_size=16, **kwargs)
-        with pytest.raises(ValueError, match="pallas"):
-            scan._traced_mode(None, 32)
-        ring = MultiHeadAttention(wf, heads=2, **kwargs)
-        ring.seq_mesh = object()
-        with pytest.raises(ValueError, match="pallas"):
-            ring._traced_mode(None, 32)
-        # attn_acc='f32' is the explicit default, not an experiment
-        MultiHeadAttention(wf, heads=2,
-                           attn_acc="f32")._traced_mode(None, 32)
+    got = PA.flash_attention_bwd(
+        q, k, v, out, lse, dout, causal=causal, block_q=bq,
+        block_k=bk, interpret=True)
+    for name, r, g in zip(("dq", "dk", "dv"), refs, got):
+        assert numpy.allclose(numpy.asarray(g), numpy.asarray(r),
+                              atol=2e-4), \
+            (name,
+             numpy.abs(numpy.asarray(g) - numpy.asarray(r)).max())
 
 
 def test_attention_unit_pallas_path():
@@ -399,34 +232,6 @@ def _dense_core(q, k, v, dout, causal):
                                          scale)
 
 
-@pytest.mark.parametrize("b,h,s,dh,causal", [
-    (1, 4, 512, 64, True),      # the benchmark's shape, 4 rows a program
-    (1, 3, 512, 64, True),      # a row count only 1 divides
-    (2, 2, 128, 8, False),
-], ids=str)
-def test_tile_kernels_match_dense_core(b, h, s, dh, causal):
-    """S=512, head 64, tile 512 — what ``_pallas_block`` picks for the
-    S=512 cells — forward and fused backward against the dense float32
-    core, at this file's bounds."""
-    prng.seed_all(913)
-    gen = prng.get("pa5")
-    q, k, v, dout = (gen.normal(0, 1.0, (b, h, s, dh)).astype(
-        numpy.float32) for _ in range(4))
-    out_ref, grads_ref = _dense_core(q, k, v, dout, causal)
-    out, lse = PA.flash_attention_fwd(
-        q, k, v, causal=causal, block_q=512, block_k=512,
-        interpret=True)
-    assert lse.shape == (b, h, s)
-    assert numpy.allclose(numpy.asarray(out), out_ref, atol=2e-5), \
-        numpy.abs(numpy.asarray(out) - out_ref).max()
-    got = PA.flash_attention_bwd(
-        q, k, v, out, lse, dout, causal=causal, block_q=512,
-        block_k=512, interpret=True)
-    for name, r, g in zip(("dq", "dk", "dv"), grads_ref, got):
-        assert numpy.allclose(numpy.asarray(g), r, atol=2e-4), \
-            (name, numpy.abs(numpy.asarray(g) - r).max())
-
-
 def _dense_lse(q, k, causal):
     """log-sum-exp of the scaled (masked) scores, dense float64."""
     scale = 1.0 / numpy.sqrt(q.shape[-1])
@@ -441,50 +246,102 @@ def _dense_lse(q, k, causal):
         axis=-1, keepdims=True)))[..., 0]
 
 
-@pytest.mark.parametrize("b,h,s,bq,bk,causal,hoist_delta", [
-    (1, 3, 512, 128, 128, True, False),     # BH odd, 4 tiles a row
-    (1, 2, 512, 128, 128, False, True),
-    (1, 2, 1024, 256, 256, True, True),
-    (1, 1, 1024, 256, 256, False, False),
-    (1, 2, 512, 256, 128, True, False),     # block_q > block_k
-    (1, 2, 512, 128, 256, True, True),      # block_q < block_k
-    (1, 1, 512, 256, 128, False, True),
-    (1, 1, 512, 128, 256, False, False),
-], ids=str)
-def test_kloop_kernels_match_dense_core(b, h, s, bq, bk, causal,
-                                        hoist_delta):
-    """The K-loop kernels (several tiles a row: what every S above
-    512 runs, S=8192 at tile 512 on the chip) at head size 64 against
-    the dense float32 core: out, the lane-dense lse, the fused
-    backward — with ``delta`` hoisted by the caller, as the ring
-    does, and not — and the fused backward against ``fused=False``."""
-    prng.seed_all(915)
-    gen = prng.get("pa7")
-    q, k, v, dout = (gen.normal(0, 1.0, (b, h, s, 64)).astype(
-        numpy.float32) for _ in range(4))
-    out_ref, grads_ref = _dense_core(q, k, v, dout, causal)
+#: |kernel - dense float32 core| by operand type. float32: this file's
+#: bounds. bfloat16 — what every cell feeds the kernels, and what only
+#: ``chip_smoke.py`` checked until PR 30: the reference is the dense
+#: float32 core of the float32 values of the SAME bf16 operands, and
+#: each bound is at most 3x the interpreter's own worst reading over
+#: the eleven bf16 cases below (out 7.3e-3, lse 5.6e-7, dq 1.44e-2, dk
+#: 1.35e-2, dv 1.28e-2; the v5e's kernels read 7.3e-3, 6.7e-6, 5.8e-3,
+#: 1.0e-2, 2.0e-2 at S=8192: PERF.md section 6, PR 29).
+_BOUNDS = {
+    "float32": dict(out=2e-5, lse=2e-5, dq=2e-4, dk=2e-4, dv=2e-4),
+    "bfloat16": dict(out=1.5e-2, lse=1.5e-6, dq=3e-2, dk=3e-2, dv=3e-2),
+}
+
+
+def _check_kernels(stream, seed, shape, dtype, block_q, block_k, causal,
+                   hoist_delta=False):
+    """Forward and backward of :mod:`pallas_attention` at ``shape`` =
+    (b, h, s, dh) on random operands of ``dtype`` against the dense
+    float32 core of the same values, at ``_BOUNDS[dtype]``."""
+    import jax.numpy as jnp
+    prng.seed_all(seed)
+    gen = prng.get(stream)
+    ops = [jnp.asarray(gen.normal(0, 1.0, shape).astype(numpy.float32),
+                       dtype) for _ in range(4)]
+    q32, k32, v32, dout32 = (numpy.asarray(t.astype(jnp.float32))
+                             for t in ops)
+    q, k, v, dout = ops
+    out_ref, grads_ref = _dense_core(q32, k32, v32, dout32, causal)
+    want = dict(zip(("dq", "dk", "dv"), grads_ref), out=out_ref,
+                lse=_dense_lse(q32, k32, causal))
     out, lse = PA.flash_attention_fwd(
-        q, k, v, causal=causal, block_q=bq, block_k=bk,
+        q, k, v, causal=causal, block_q=block_q, block_k=block_k,
         interpret=True)
-    assert lse.shape == (b, h, s) and lse.dtype == numpy.float32
-    assert out.shape == (b, h, s, 64)
-    assert numpy.allclose(numpy.asarray(lse), _dense_lse(q, k, causal),
-                          atol=2e-5)
-    assert numpy.allclose(numpy.asarray(out), out_ref, atol=2e-5), \
-        numpy.abs(numpy.asarray(out) - out_ref).max()
-    delta = (dout * out_ref).sum(axis=-1) if hoist_delta else None
-    got = PA.flash_attention_bwd(
-        q, k, v, out, lse, dout, causal=causal, block_q=bq,
-        block_k=bk, interpret=True, delta=delta)
-    two = PA.flash_attention_bwd(
-        q, k, v, out, lse, dout, causal=causal, block_q=bq,
-        block_k=bk, interpret=True, delta=delta, fused=False)
-    for name, r, g, t in zip(("dq", "dk", "dv"), grads_ref, got, two):
-        assert g.shape == (b, h, s, 64)
-        assert numpy.allclose(numpy.asarray(g), r, atol=2e-4), \
-            (name, numpy.abs(numpy.asarray(g) - r).max())
-        assert numpy.allclose(numpy.asarray(g), numpy.asarray(t),
-                              atol=2e-5), name
+    assert lse.shape == shape[:3] and lse.dtype == numpy.float32
+    delta = (dout32 * out_ref).sum(axis=-1) if hoist_delta else None
+    got = dict(zip(("dq", "dk", "dv"), PA.flash_attention_bwd(
+        q, k, v, out, lse, dout, causal=causal, block_q=block_q,
+        block_k=block_k, interpret=True, delta=delta)), out=out)
+    for name, g in got.items():
+        assert g.shape == shape and g.dtype == q.dtype, name
+    got["lse"] = lse
+    errs = {name: float(numpy.abs(numpy.asarray(
+        g.astype(jnp.float32)) - want[name]).max())
+        for name, g in got.items()}
+    assert all(errs[name] <= bound
+               for name, bound in _BOUNDS[dtype].items()), errs
+
+
+_TILE_SHAPES = [
+    (1, 4, 512, 64, True),      # the benchmark's shape, 4 rows a program
+    (1, 3, 512, 64, True),      # a row count only 1 divides
+    (2, 2, 128, 8, False),
+]
+
+
+@pytest.mark.parametrize(
+    "b,h,s,dh,causal,dtype",
+    [c + ("float32",) for c in _TILE_SHAPES]
+    + [c + ("bfloat16",) for c in _TILE_SHAPES]
+    + [(1, 2, 256, 128, True, "float32")],      # the other head size
+    ids=str)
+def test_tile_kernels_match_dense_core(b, h, s, dh, causal, dtype):
+    """S=512, head 64, tile 512 — what ``_pallas_block`` picks for the
+    S=512 cells — forward and fused backward against the dense float32
+    core, in float32 at this file's bounds and in the cells' bf16."""
+    _check_kernels("pa5", 913, (b, h, s, dh), dtype, 512, 512, causal)
+
+
+_KLOOP_SHAPES = [
+    (1, 3, 512, 64, 128, 128, True, False),     # BH odd, 4 tiles a row
+    (1, 2, 512, 64, 128, 128, False, True),
+    (1, 2, 1024, 64, 256, 256, True, True),
+    (1, 1, 1024, 64, 256, 256, False, False),
+    (1, 2, 512, 64, 256, 128, True, False),     # block_q > block_k
+    (1, 2, 512, 64, 128, 256, True, True),      # block_q < block_k
+    (1, 1, 512, 64, 256, 128, False, True),
+    (1, 1, 512, 64, 128, 256, False, False),
+]
+
+
+@pytest.mark.parametrize(
+    "b,h,s,dh,bq,bk,causal,hoist_delta,dtype",
+    [c + ("float32",) for c in _KLOOP_SHAPES]
+    + [c + ("bfloat16",) for c in _KLOOP_SHAPES]
+    + [(1, 1, 512, 128, 256, 128, True, False, "float32")],
+    ids=str)
+def test_kloop_kernels_match_dense_core(b, h, s, dh, bq, bk, causal,
+                                        hoist_delta, dtype):
+    """The K-loop kernels (several tiles a row: what every S above
+    512 runs, S=8192 at tile 512 on the chip) at head size 64 — and
+    once at 128, the other size they compile for — against the dense
+    float32 core: out, the lane-dense lse, the fused backward — with
+    ``delta`` hoisted by the caller, as the ring does, and not — in
+    float32 and in the cells' bf16."""
+    _check_kernels("pa7", 915, (b, h, s, dh), dtype, bq, bk, causal,
+                   hoist_delta)
 
 
 #: what the v5e compiler needs for the fused backward, MB of scoped
@@ -509,9 +366,28 @@ def test_fused_bwd_vmem_limit_covers_the_transposed_resident_set(
     claiming three times that."""
     grant = PA._fused_bwd_vmem_limit(*shape, 2, device_vmem=128 << 20)
     assert need_mb * 2 ** 20 < grant < 3 * need_mb * 2 ** 20
-    # a device that cannot hold it is refused, the escape hatch named
-    with pytest.raises(ValueError, match="fused=False"):
-        PA._fused_bwd_vmem_limit(*shape, 2, device_vmem=8 << 20)
+
+
+@pytest.mark.parametrize("s,device_mb,want_mb", [
+    (512, 128, 16),         # a small shape keeps the 16 MB default
+    (16384, 128, 39.75),    # 1.5x the resident set (20.25 at S=8192)
+    (512, 8, 8),            # a device that has less than the default
+    (8192, 16, None),       # a v2/v3-sized VMEM cannot hold the rows
+], ids=["floor", "monotone_in_s", "clamped_to_device", "refused"])
+def test_fused_bwd_vmem_limit_tracks_footprint(s, device_mb, want_mb):
+    """The grant is the 16 MB default at least, the footprint's 1.5x
+    above it, never more than the device has, and a device that
+    cannot hold the footprint is refused loudly (dh 64, tile 128,
+    bf16)."""
+    def grant(s):
+        return PA._fused_bwd_vmem_limit(s, 64, 128, 128, 2,
+                                        device_vmem=device_mb << 20)
+    if want_mb is None:
+        with pytest.raises(ValueError, match="smaller pallas_tile"):
+            grant(s)
+        return
+    assert grant(s) == want_mb * 2 ** 20
+    assert grant(s // 2) <= grant(s) <= device_mb << 20
 
 
 def test_tile_kernels_equal_the_general_kernels(monkeypatch):

@@ -688,33 +688,6 @@ def test_http_snapshot_store_lists_absolute_url_hrefs(caplog):
         httpd.server_close()
 
 
-def test_fused_bwd_vmem_limit_tracks_footprint():
-    """The pallas fused-backward VMEM grant derives from the resident
-    footprint, clamps to the device generation, and names
-    ``fused=False`` as the escape hatch when nothing fits."""
-    from veles.znicz_tpu.parallel.pallas_attention import (
-        _fused_bwd_vmem_limit)
-    # small shapes keep the default 16MB floor
-    small = _fused_bwd_vmem_limit(512, 64, 128, 128, 2,
-                                  device_vmem=128 << 20)
-    assert small == 16 << 20
-    # the measured S=8k case: grant covers the observed 12.7MB need
-    # (the v5e compiler's, PR 29: the transposed kernel's resident
-    # set; 16.75MB before it) without claiming the whole chip
-    grant = _fused_bwd_vmem_limit(8192, 64, 128, 128, 2,
-                                  device_vmem=128 << 20)
-    assert (13 << 20) < grant < (32 << 20)
-    # monotone in S, never past the device capacity
-    bigger = _fused_bwd_vmem_limit(16384, 64, 128, 128, 2,
-                                   device_vmem=128 << 20)
-    assert grant < bigger <= 128 << 20
-    # a v2/v3-sized VMEM refuses the fused path LOUDLY, pointing at
-    # the two-kernel fallback
-    with pytest.raises(ValueError, match="fused=False"):
-        _fused_bwd_vmem_limit(8192, 64, 128, 128, 2,
-                              device_vmem=16 << 20)
-
-
 def test_bench_serving_row_runs():
     """bench.py's serving_throughput_rps: in-process, no sockets, no
     device required."""

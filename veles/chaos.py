@@ -146,6 +146,23 @@ class ChaosEvent:
                    self.kind, self.nth))
 
 
+def _hang_up(sock):
+    """End ``sock``'s connection NOW, for both peers. ``close()`` alone
+    does not: while a pump thread is blocked in ``recv`` on the socket
+    the kernel keeps the connection open, no FIN leaves, and the far
+    side learns of the "kill" only through its own silent-peer timeout
+    (the master's ``slave_timeout`` sweep) — ``shutdown`` wakes the
+    blocked thread and tells the peer at once."""
+    try:
+        sock.shutdown(socket.SHUT_RDWR)
+    except OSError:
+        pass                  # never connected, or the peer went first
+    try:
+        sock.close()
+    except OSError:
+        pass
+
+
 class _Pump(threading.Thread):
     """One direction of one proxied connection."""
 
@@ -291,12 +308,8 @@ class ChaosProxy(Logger):
     def _sever(self, conn_id):
         with self._lock:
             pair = self._conns.pop(conn_id, None)
-        if pair:
-            for sock in pair:
-                try:
-                    sock.close()
-                except OSError:
-                    pass
+        for sock in pair or ():
+            _hang_up(sock)
 
     # -- chaos ---------------------------------------------------------
 
@@ -503,12 +516,8 @@ class BrownoutProxy(Logger):
     def _sever(self, conn_id):
         with self._lock:
             pair = self._conns.pop(conn_id, None)
-        if pair:
-            for sock in pair:
-                try:
-                    sock.close()
-                except OSError:
-                    pass
+        for sock in pair or ():
+            _hang_up(sock)
 
     def _count_pipe(self, direction, n, swallowed=False):
         with self._lock:

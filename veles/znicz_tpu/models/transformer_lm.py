@@ -62,8 +62,7 @@ root.lm.update({
     # model's config states, none a tuning knob.
     "model": {"dim": 64, "heads": 4, "layers": 2, "ffn_hidden": 128,
               "attn_block": None, "attn_impl": None,
-              "pallas_tile": None, "attn_pipeline": False,
-              "attn_acc": None, "moe_experts": 0,
+              "pallas_tile": None, "moe_experts": 0,
               "moe_capacity_factor": 2.0, "moe_aux_weight": 0.01,
               "stacked": False, "remat": False,
               "block": "post_ln", "kv_heads": None, "head_dim": None,
@@ -275,9 +274,7 @@ def attention_kernel_keys(m):
     for every block's attention unit."""
     return {"attn_block_size": m.get("attn_block"),
             "attn_impl": m.get("attn_impl"),
-            "pallas_tile": m.get("pallas_tile"),
-            "attn_pipeline": m.get("attn_pipeline", False),
-            "attn_acc": m.get("attn_acc")}
+            "pallas_tile": m.get("pallas_tile")}
 
 
 def build_layers():
@@ -306,16 +303,13 @@ def build_layers():
                 "stacked=True builds dense-FFN blocks; it cannot "
                 "honour moe_experts=%r (use the per-layer model for "
                 "MoE)" % m.moe_experts)
-        if m.get("attn_block") or m.get("attn_impl") \
-                or m.get("attn_pipeline") \
-                or m.get("attn_acc") not in (None, "f32"):
+        if m.get("attn_block") or m.get("attn_impl"):
             raise ValueError(
                 "stacked=True uses dense attention inside the block "
-                "scan; attn_block=%r / attn_impl=%r / attn_pipeline=%r "
-                "/ attn_acc=%r are not supported there (use the "
-                "per-layer model for flash/pallas attention)"
-                % (m.get("attn_block"), m.get("attn_impl"),
-                   m.get("attn_pipeline"), m.get("attn_acc")))
+                "scan; attn_block=%r / attn_impl=%r are not supported "
+                "there (use the per-layer model for flash/pallas "
+                "attention)"
+                % (m.get("attn_block"), m.get("attn_impl")))
         layers += [
             {"type": "transformer_stack",
              "->": {"layers": m.layers, "heads": m.heads,

@@ -236,10 +236,8 @@ class GradientDescentBase(AcceleratedUnit, IDistributable):
         #: (ops/pallas_grads.py), the convert_reduce fix
         #: (docs/repro_convert_reduce.py). None = auto: the kernel
         #: takes over on a real TPU once $VELES_FUSED_BIAS_GRAD=1 —
-        #: opt-in until a device window validates the kernel
-        #: end-to-end, the same default-off posture as the
-        #: attn_pipeline experiment; True/False force either path
-        #: (mirrors the flash kernels' fused=False stance)
+        #: opt-in until a paired chip run times it inside a step
+        #: (ROADMAP D4); True/False force either path
         self.fused_bias_grad = kwargs.get("fused_bias_grad")
         # lr schedules (SURVEY.md §2.4 "LR scheduling"): pure policies
         # evaluated inside the compiled step on the traced iteration

@@ -383,21 +383,6 @@ class MultiHeadAttention(Forward):
         #: dims where the auto tile's scoped-VMEM footprint is too
         #: large. Must divide the (per-shard) sequence length.
         self.pallas_tile = kwargs.get("pallas_tile")
-        #: DMA-pipelined Pallas forward (pallas_attention._fwd_kernel
-        #: _pipe): K/V stay in HBM, blocks double-buffer into VMEM
-        #: scratch with the next load overlapping the current matmuls
-        #: — resident VMEM stops scaling with S. Exact (pinned by
-        #: tests); off by default until measured end-to-end on TPU.
-        self.attn_pipeline = bool(kwargs.get("attn_pipeline", False))
-        #: forward-accumulator dtype experiment: "bf16" narrows the
-        #: running PV accumulation chain (softmax statistics and lse
-        #: stay f32); None/"f32" keeps exact f32 accumulation. Gated
-        #: by the numerics bound in tests/test_pallas_attention.py.
-        self.attn_acc = kwargs.get("attn_acc")
-        if self.attn_acc not in (None, "f32", "bf16"):
-            raise ValueError(
-                "attn_acc must be None, 'f32' or 'bf16', got %r"
-                % (self.attn_acc,))
 
     def output_shape_for(self, ishape):
         return tuple(ishape)
@@ -510,27 +495,12 @@ class MultiHeadAttention(Forward):
         (they must agree — the cache layout follows the mode):
         "ring" | "pallas" | "scan" (blocked) | "dense"."""
         if self.seq_mesh is not None:
-            mode = "ring"
-        elif self.attn_impl == "pallas":
-            mode = "pallas"
-        elif not self.attn_block_size:
-            mode = "dense"
-        elif self._auto_pallas(ctx, s):
-            mode = "pallas"
-        else:
-            mode = "scan"
-        if mode != "pallas" and (self.attn_pipeline
-                                 or self.attn_acc == "bf16"):
-            # same loud stance as transformer_lm's stacked guard: a
-            # silently inert knob invalidates exactly the A/B the
-            # experiment knobs exist for
-            raise ValueError(
-                "attn_pipeline=%r / attn_acc=%r are only honoured on "
-                "the single-shard pallas forward, but this dispatch "
-                "resolves to %r (S=%d) — force attn_impl='pallas' or "
-                "clear the knob" % (self.attn_pipeline, self.attn_acc,
-                                    mode, s))
-        return mode
+            return "ring"
+        if self.attn_impl == "pallas":
+            return "pallas"
+        if not self.attn_block_size:
+            return "dense"
+        return "pallas" if self._auto_pallas(ctx, s) else "scan"
 
     @staticmethod
     def _pallas_interpret(ctx):
@@ -550,12 +520,8 @@ class MultiHeadAttention(Forward):
         names = ("q", "k", "v", "out_heads", "lse", "merged")
         if mode == "ring":
             y, cache = self._fwd_ring(jnp, x, p, ctx, ctx.dot)
-        elif mode == "pallas":
-            y, cache = self._fwd_pallas(jnp, x, p, ctx)
-        elif mode == "scan":
-            y, cache = self._fwd_blocked(
-                jnp, x, p, ctx.dot,
-                cd=ctx._compiler.device.compute_dtype)
+        elif mode in ("pallas", "scan"):
+            y, cache = self._fwd_flash(jnp, x, p, ctx, mode)
         else:
             y, cache = self._fwd_core(
                 jnp, x, p["weights"], p.get("bias"), p["weights_out"],
@@ -582,19 +548,22 @@ class MultiHeadAttention(Forward):
             y = y + x
         return y
 
-    def _fwd_blocked(self, xp, x, p, dot, cd=None):
-        """Single-chip flash-style forward: O(S·block) score memory.
-        q/k/v live in the compute dtype ``cd`` (bf16 on TPU): every
-        consumer is a matmul, the probs/ds tiles inside the scan
-        inherit it (halving their HBM traffic), and the backward
-        caches cost half the memory."""
-        q, k, v = self._project_qkv(x, p, dot)
-        if cd is not None:
-            q, k, v = q.astype(cd), k.astype(cd), v.astype(cd)
+    def _fwd_flash(self, xp, x, p, ctx, mode):
+        """Forward of the single-chip flash modes ("scan": the
+        ``lax.scan`` formulation, O(S·block) score memory; "pallas":
+        the kernels), by ``core_fwd``. q/k/v live in the compute dtype
+        (bf16 on TPU): every consumer is a matmul, the kernels' rows
+        take half the VMEM (K/V ride whole rows — the difference
+        between S=8k fitting and a scoped-vmem OOM), the scan's
+        probs/ds tiles inherit it (halving their HBM traffic), and
+        the backward caches cost half the memory."""
+        cd = ctx._compiler.device.compute_dtype
+        q, k, v = (t.astype(cd)
+                   for t in self._project_qkv(x, p, ctx.dot))
         with _core_scope():
-            out_heads, lse = self.core_fwd(None, "scan", q, k, v, dot)
+            out_heads, lse = self.core_fwd(ctx, mode, q, k, v)
         merged = self._merge(out_heads)
-        y = self._finish(x, merged, p, dot)
+        y = self._finish(x, merged, p, ctx.dot)
         return y, (q, k, v, out_heads, lse, merged)
 
     def _pallas_block(self, s=None):
@@ -645,7 +614,7 @@ class MultiHeadAttention(Forward):
             kernel, ctx._compiler.device.mesh, self.kernel_batch_axis,
             self.kernel_head_axis, in_kinds, out_kinds)
 
-    def core_fwd(self, ctx, mode, q, k, v, dot=None):
+    def core_fwd(self, ctx, mode, q, k, v):
         """The attention proper of the flash modes, "pallas" and
         "scan", on (B, H, S, dh) heads in the compute type ->
         (out_heads, lse). The one place that turns a mode into its
@@ -653,21 +622,17 @@ class MultiHeadAttention(Forward):
         (``ops/gqa_attention.py``) calls this and :meth:`core_bwd`,
         like the paths below, inside ``_core_scope()``."""
         if mode == "pallas":
-            import jax.numpy as jnp
             from veles.znicz_tpu.parallel import pallas_attention as PA
             blk = self._pallas_block()
             kernel = self._pallas_on_mesh(ctx, PA.jitted(
                 PA.flash_attention_fwd, causal=self.causal,
                 block_q=blk, block_k=blk,
-                interpret=self._pallas_interpret(ctx),
-                pipeline=self.attn_pipeline,
-                acc_dtype=jnp.bfloat16 if self.attn_acc == "bf16"
-                else None), "ttt", "tr")
+                interpret=self._pallas_interpret(ctx)), "ttt", "tr")
             return kernel(q, k, v)
         from veles.znicz_tpu.parallel import flash
         return flash.blocked_attention_fwd(
             q, k, v, causal=self.causal, block=self.attn_block_size,
-            dot=dot or ctx.dot)
+            dot=ctx.dot)
 
     def core_bwd(self, ctx, mode, q, k, v, out_heads, lse, dctx):
         """-> (dq, dk, dv) of :meth:`core_fwd`; ``dctx`` by head."""
@@ -686,20 +651,6 @@ class MultiHeadAttention(Forward):
             q, k, v, out_heads, lse, dctx.astype(cd),
             causal=self.causal, block=self.attn_block_size,
             dot=ctx.dot)
-
-    def _fwd_pallas(self, xp, x, p, ctx):
-        """Flash forward on the hand-written Pallas TPU kernel.
-        q/k/v in the compute dtype (bf16 on TPU): half the kernel's
-        VMEM (K/V ride whole rows — the difference between S=8k
-        fitting and a scoped-vmem OOM) and matched MXU input dtypes."""
-        cd = ctx._compiler.device.compute_dtype
-        q, k, v = (t.astype(cd)
-                   for t in self._project_qkv(x, p, ctx.dot))
-        with _core_scope():
-            out_heads, lse = self.core_fwd(ctx, "pallas", q, k, v)
-        merged = self._merge(out_heads)
-        y = self._finish(x, merged, p, ctx.dot)
-        return y, (q, k, v, out_heads, lse, merged)
 
     def _ring_inner(self, ctx):
         """(inner, block) for the ring path — which kernel each ring

@@ -43,8 +43,7 @@ reference ``dz.sum(axis=0)`` math at the existing gd tolerances.
 Consumed via ``GradientDescentBase.bias_grad_xla`` behind the
 ``fused_bias_grad`` escape hatch (None = auto: on TPU when
 $VELES_FUSED_BIAS_GRAD=1 — opt-in until a paired chip run decides it;
-True/False force), mirroring the flash kernels' ``fused=False``
-stance.
+True/False force).
 """
 
 import functools
