@@ -91,6 +91,20 @@ for name, (fn, specs) in chip_smoke.pallas_variants(
     fn.lower(*(jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=on_chip)
                for s in specs)).compile()
     print("COMPILED %s" % name, flush=True)
+# the expert layer's row stages (ops/expert_ffn.py) at the LFM2 cell's
+# gating shapes: a loop over chunks into a buffer that is allocated,
+# not filled
+from veles.znicz_tpu.ops import expert_ffn
+from veles.znicz_tpu.ops.swiglu import swiglu
+gate = expert_ffn.prefix_stage(
+    lambda real, h13: swiglu(h13, keep=real).astype(jnp.bfloat16))
+text = jax.jit(lambda h13, rows: gate(rows, h13)[0]).lower(
+    jax.ShapeDtypeStruct((65536, 3072), jnp.bfloat16, sharding=on_chip),
+    jax.ShapeDtypeStruct((), jnp.int32, sharding=on_chip)
+).compile().as_text()
+assert " while(" in text and text.count("tpu_custom_call") == 1, text
+assert "bf16[65536,1536]{1,0:T(8,128)(2,1)} broadcast(" not in text, text
+print("COMPILED expert row stage", flush=True)
 """
 
 
@@ -113,7 +127,7 @@ def test_every_pallas_variant_compiles_for_v5e(tmp_path):
         pytest.skip("libtpu gives no compile-only v5e topology here: "
                     + proc.stdout.strip()[:200])
     assert proc.returncode == 0, proc.stderr[-3000:]
-    assert proc.stdout.count("COMPILED ") == 6, proc.stdout
+    assert proc.stdout.count("COMPILED ") == 7, proc.stdout
 
 
 def test_smoke_refuses_a_machine_without_a_tpu():

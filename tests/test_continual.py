@@ -564,8 +564,11 @@ def test_blackhole_ingest_fires_staleness_slo_and_resolves():
         ok, reasons = mon.ready_state()
         assert ok is False
         assert any("staleness" in r for r in reasons)
-        assert telemetry.get_registry().counter_total(
-            "veles_stream_fetch_failures_total") >= 1.0
+        # the alert can fire (0.3 s of staleness) before the first
+        # wedged fetch has timed out (0.3 s from ITS start) and counted
+        wait_until(lambda: telemetry.get_registry().counter_total(
+            "veles_stream_fetch_failures_total") >= 1.0, timeout=10.0,
+            interval=0.05, what="a wedged fetch to time out")
         # restore: the wedged round completes, ingest flows again and
         # good samples age the violation out of both windows
         proxy.restore()

@@ -92,11 +92,22 @@ def reference_block(kind, model):
     return block
 
 
-def check_unit(cls, kwargs, model=MODEL, tol=2e-5):
+def rigged(fwd, params, chosen):
+    """``params`` with a selection bias that makes every token select
+    the experts ``chosen`` (k of them; None: as they are)."""
+    if not chosen:
+        return params
+    bias = numpy.full(fwd.experts, -10.0, numpy.float32)
+    bias[list(chosen)] = 10.0
+    return {fwd.name: dict(params[fwd.name], expert_bias=bias)}
+
+
+def check_unit(cls, kwargs, model=MODEL, tol=2e-5, chosen=None):
     import jax
     import jax.numpy as jnp
     feed, fwd, gd, x, err, comp = build(cls, **kwargs)
-    params0, state0 = comp.gather_params(), comp.gather_state()
+    params0 = rigged(fwd, comp.gather_params(), chosen)
+    state0 = comp.gather_state()
     y = numpy.asarray(xla_forward(comp, feed, fwd, params0, x))
     dx, params1 = xla_backward(comp, feed, fwd, gd, params0, state0,
                                x, err)
@@ -209,10 +220,7 @@ def test_no_pair_is_dropped_when_every_token_takes_one_expert():
     import jax
     feed, fwd, _, x, _, comp = build(ExpertFFN, experts_held=(0, 2),
                                      **EXPERT)
-    params = comp.gather_params()
-    bias = numpy.full(8, -10.0, numpy.float32)
-    bias[:2] = 10.0
-    params[fwd.name]["expert_bias"] = bias
+    params = rigged(fwd, comp.gather_params(), (0, 1))
     y, outs = step_aux(fwd, comp, feed, params, x)
     name = fwd.name
     assert int(outs["moe_pairs_" + name]) == B * S * 2
@@ -224,14 +232,16 @@ def test_no_pair_is_dropped_when_every_token_takes_one_expert():
     assert numpy.abs(numpy.asarray(y) - numpy.asarray(want)).max() < 2e-5
 
 
-def test_rows_the_grouped_product_skips_may_hold_anything(monkeypatch):
-    """The TPU's grouped kernel stops at the last group and leaves the
-    rows past it undefined (the CPU's writes zeros there). With those
-    rows poisoned, output and every gradient are still the
-    reference's: no 0 * NaN reaches a token or a weight."""
+@pytest.fixture
+def poisoned_rows(monkeypatch):
+    """What a TPU leaves undefined reads as NaN: the rows past the last
+    group of a grouped product's result (the CPU's writes zeros there)
+    and the rows of a buffer that no chunk of ``over_prefix`` wrote
+    (on a CPU ``jax.lax.empty`` is a zero fill, which would hide a
+    missing mask)."""
     import jax
     import jax.numpy as jnp
-    from veles.znicz_tpu.ops import vjp_units
+    from veles.znicz_tpu.ops import expert_ffn, vjp_units
     plain = jax.lax.ragged_dot
 
     def poisoned(x, w, sizes, **kwargs):
@@ -240,12 +250,66 @@ def test_rows_the_grouped_product_skips_may_hold_anything(monkeypatch):
         return jnp.where(real, out, jnp.nan)
 
     monkeypatch.setattr(jax.lax, "ragged_dot", poisoned)
+    monkeypatch.setattr(
+        expert_ffn, "unwritten",
+        lambda shape, dtype, after: jnp.full(shape, jnp.nan, dtype))
     vjp_units._products.cache_clear()
-    try:
-        check_unit(ExpertFFN, dict(EXPERT, experts_held=(2, 6)),
-                   dict(MODEL, experts_held=[2, 6]))
-    finally:
-        vjp_units._products.cache_clear()
+    yield
+    vjp_units._products.cache_clear()
+
+
+@pytest.fixture
+def chunk_of_16(monkeypatch):
+    """The row stages at ``CHUNK`` 48: the (B x S x k = 256)-row
+    buffer of these tests goes in chunks of 16 rows, the largest
+    divisor it shares with that."""
+    from veles.znicz_tpu.ops import expert_ffn
+    monkeypatch.setattr(expert_ffn, "CHUNK", 48)
+    return 16
+
+
+def test_rows_the_grouped_product_skips_may_hold_anything(
+        poisoned_rows, chunk_of_16):
+    """The TPU's grouped kernel stops at the last group, and the row
+    stages at the last chunk that holds a real pair; both leave what
+    lies past undefined. With those rows poisoned, output and every
+    gradient are still the reference's: no 0 * NaN reaches a token or
+    a weight."""
+    check_unit(ExpertFFN, dict(EXPERT, experts_held=(2, 6)),
+               dict(MODEL, experts_held=[2, 6]))
+
+
+#: name: (experts held, the experts every token is made to select or
+#: None for the router's own choice, real pairs or None)
+PREFIX_CASES = {
+    "no_held_pair_selected": ((0, 2), (6, 7), 0),
+    "rows_inside_a_chunk": ((2, 6), None, None),
+    "one_expert_of_two_held": ((0, 2), (0, 7), B * S),
+    "every_row_real": ((0, 2), (0, 1), B * S * 2),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PREFIX_CASES))
+def test_row_stages_follow_the_real_pairs(case, poisoned_rows,
+                                          chunk_of_16):
+    """The row stages at ``rows`` = 0, off a chunk's edge and = T x k,
+    the rows they leave alone poisoned: output, input gradient and
+    every parameter's gradient against the reference, and the loop's
+    trips, read from the counter's source, ``ceil(rows / chunk)``."""
+    held, chosen, pairs = PREFIX_CASES[case]
+    check_unit(ExpertFFN, dict(EXPERT, experts_held=held),
+               dict(MODEL, experts_held=list(held)), chosen=chosen)
+    feed, fwd, _, x, _, comp = build(ExpertFFN, experts_held=held,
+                                     **EXPERT)
+    _, outs = step_aux(fwd, comp, feed,
+                       rigged(fwd, comp.gather_params(), chosen), x)
+    rows = int(outs["moe_pairs_" + fwd.name])
+    if pairs is None:       # the router's own choice: off a chunk's edge
+        assert rows % chunk_of_16
+    else:
+        assert rows == pairs
+    assert int(outs["moe_touched_" + fwd.name]) \
+        == -(-rows // chunk_of_16) * chunk_of_16
 
 
 # -- the whole LM ------------------------------------------------------------
@@ -343,11 +407,19 @@ def test_counters_ride_the_metric_fetch(tiny_lm):
         # all 8 experts held: every one of the T x k pairs is computed
         assert registry.counter_total("veles_moe_pairs_total",
                                       layer=layer) == 2 * S * 2
+        # ... so the row stages ran over the whole buffer, one chunk
+        assert registry.counter_total("veles_moe_rows_touched_total",
+                                      layer=layer) == 2 * S * 2
     assert registry.counter_total("veles_moe_dropped_pairs_total") == 0
     text = registry.render_prometheus()
-    gauges = [float(line.split()[-1]) for line in text.splitlines()
-              if line.startswith("veles_moe_load_max_over_mean{")]
-    assert len(gauges) == 4 and all(1.0 <= g <= 8.0 for g in gauges)
+
+    def gauges(name):
+        return [float(line.split()[-1]) for line in text.splitlines()
+                if line.startswith(name + "{")]
+
+    loads = gauges("veles_moe_load_max_over_mean")
+    assert len(loads) == 4 and all(1.0 <= g <= 8.0 for g in loads)
+    assert gauges("veles_moe_buffer_rows") == [2 * S * 2] * 4
 
 
 def test_step_program_names_the_new_scopes(tiny_lm):

@@ -17,25 +17,40 @@ nothing here stands in for the other chips or their exchange.
 
 No token is dropped and there is no capacity: the token-expert pairs
 are sorted, held experts first and by expert, into a buffer of all
-``T x k`` rows (the worst case: every pair on a held expert), and the
-three products run over the rows of the held pairs as grouped products
+``T x k`` rows (the worst case: every pair on a held expert). The
+``rows`` pairs that are real are a prefix of it, and the work follows
+them, not the buffer: the three products run as grouped products
 (``vjp_units.Products.grouped_dot``: ``jax.lax.ragged_dot``, which the
 TPU compiler lowers to its own grouped-matmul kernel that stops at the
-last group). ``[W1 | W3]`` is one array, so the up-projections are one
-grouped product. Gradients reach the router through ``p`` alone.
+last group), and every row stage around them — the gather into the
+buffer, the gating, the weighting, and each one's pullback — runs over
+the chunks of ``CHUNK`` rows that hold a real pair
+(:func:`over_prefix`: a loop of ``ceil(rows / chunk)`` trips).
+Past the last chunk a buffer is not even written; the one reader of
+such rows, the token side of ``combine``, takes a pair past ``rows``
+as zero whatever its row holds. The buffers keep their worst-case
+shapes, so a layer that holds every expert runs every chunk and
+nothing can drop. ``[W1 | W3]`` is one array, so the up-projections
+are one grouped product. Gradients reach the router through ``p``
+alone.
 
 Scopes inside the unit's own: ``veles.route`` (router, top-k, sort,
 dispatch, gating, weighting, combine) and ``veles.experts`` (the grouped
 products). Counters, advanced when the step's metrics are replayed on
 the host (they ride the metric fetch the step makes anyway):
 ``veles_moe_pairs_total{layer}``, ``veles_moe_steps_total{layer}``,
-``veles_moe_dropped_pairs_total`` (:func:`misplaced_pairs`: held pairs
-whose row of the buffer lies outside their expert's group; 0 while
-sort, group sizes and buffer agree) and the gauge
-``veles_moe_load_max_over_mean{layer}``.
+``veles_moe_rows_touched_total{layer}`` (rows the row stages
+processed: trips x chunk, read from the loop itself; over
+``veles_moe_buffer_rows{layer}``, the gauge of ``T x k``, it says how
+far the buffer is from the work, over the pairs what a chunk's
+rounding costs), ``veles_moe_dropped_pairs_total``
+(:func:`misplaced_pairs`: held pairs whose row of the buffer lies
+outside their expert's group; 0 while sort, group sizes and buffer
+agree) and the gauge ``veles_moe_load_max_over_mean{layer}``.
 """
 
 import functools
+import math
 
 from veles import telemetry
 from veles.znicz_tpu.nn_units import forward_unit, gradient_for
@@ -44,39 +59,136 @@ from veles.znicz_tpu.ops.vjp_units import (
     GDVjp, Products, VjpForward, rms_norm)
 
 
-@functools.lru_cache(maxsize=None)
-def pair_moves(k):
-    """(dispatch, combine) for ``k`` experts a token: the two moves
-    between the (T, d) tokens and the (T x k, d) buffer of token-expert
-    pairs sorted by ``order`` (``inv`` its inverse). They are each
-    other's transpose, and each is written as a gather: left to jax,
-    the transpose of a gather is a scatter-add, which the TPU runs row
-    by row and, in bf16, sums in bf16."""
+#: rows a trip of :func:`over_prefix` handles (the largest divisor of
+#: the buffer's rows it shares with them). Timed on a v5e on the
+#: gating stage alone, 65,536 x 3072 -> 1536 in bf16 (PR 31): at a
+#: quarter of the rows real 0.297 / 0.295 / 0.313 / 0.367 ms for
+#: 1,024 / 2,048 / 4,096 / 8,192 (one pass over all rows 0.901), at
+#: all rows real 1.073 / 1.005 / 0.972 / 0.955.
+CHUNK = 2048
+
+
+def unwritten(shape, dtype, after):
+    """An array nothing has written, allocated once ``after`` exists.
+    On the TPU an allocation, not a fill: a kernel that does nothing
+    and takes ``after`` by reference. ``jax.lax.empty`` allocates too,
+    but has no operand, and XLA's scheduler then allocates at the top
+    of the program: every such buffer of every layer was live through
+    the whole step (+1.6 GB in `lfm2_24b_a2b_s8k_train`, PR 31)."""
+    import jax
+    from jax.experimental import pallas as pl
+
+    def allocation(after):
+        return pl.pallas_call(
+            lambda after_ref, out_ref: None,
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            out_shape=jax.ShapeDtypeStruct(shape, dtype))(after)
+
+    return jax.lax.platform_dependent(
+        after, tpu=allocation,
+        default=lambda after: jax.lax.empty(shape, dtype))
+
+
+def over_prefix(fn, rows, reads, buffers):
+    """The row chunks of ``buffers`` that intersect ``[0, rows)``, each
+    replaced by ``fn(real, *chunks of reads, *chunks of buffers)``:
+    ``ceil(rows / chunk)`` trips of slice, work, update in place.
+    ``real`` (chunk, 1) says which rows of the chunk lie inside the
+    prefix: ``fn`` masks the tail of the last one. The other chunks
+    are left as they are: those of an :func:`unwritten` buffer hold no
+    defined value. -> (buffers, rows touched = trips x chunk)."""
+    import jax
+    import jax.numpy as jnp
+    chunk = math.gcd(buffers[0].shape[0], CHUNK)
+
+    def trip(carry):
+        i, buffers = carry
+        real = (i * chunk + jnp.arange(chunk) < rows)[:, None]
+        done = fn(real, *(
+            jax.lax.dynamic_slice_in_dim(a, i * chunk, chunk)
+            for a in reads + buffers))
+        return i + 1, tuple(
+            jax.lax.dynamic_update_slice_in_dim(b, c, i * chunk, 0)
+            for b, c in zip(buffers, done))
+
+    trips, buffers = jax.lax.while_loop(
+        lambda carry: carry[0] * chunk < rows, trip,
+        (jnp.zeros((), rows.dtype), buffers))
+    return buffers, trips * chunk
+
+
+def prefix_stage(fn):
+    """``stage(rows, *operands) -> (result, rows touched)``: the
+    row-wise ``fn(real, *chunks) -> chunk`` by :func:`over_prefix`,
+    into an :func:`unwritten` buffer. Its pullback is the same loop
+    over the chunks' pullbacks: of the cotangent, too, only the prefix
+    is read, and each operand's cotangent is written over the operand
+    (the residuals are the operands, and die there)."""
     import jax
     import jax.numpy as jnp
 
     @jax.custom_vjp
-    def dispatch(x, order, inv):
-        """Row r of the buffer: the token of pair ``order[r]``."""
-        return x[order // k]
+    def stage(rows, *operands):
+        like = jax.eval_shape(
+            fn, jax.ShapeDtypeStruct((operands[0].shape[0], 1), bool),
+            *operands)
+        (out,), touched = over_prefix(
+            lambda real, *chunks: (fn(real, *chunks[:-1]),), rows,
+            operands, (unwritten(like.shape, like.dtype, operands[0]),))
+        return out, touched
+
+    def pull(real, g, *chunks):
+        return jax.vjp(lambda *c: fn(real, *c), *chunks)[1](
+            jnp.where(real, g, 0))
+
+    stage.defvjp(
+        lambda rows, *operands: (stage(rows, *operands),
+                                 (rows, operands)),
+        lambda saved, g: (None,) + over_prefix(
+            pull, saved[0], (g[0],), saved[1])[0])
+    return stage
+
+
+@functools.lru_cache(maxsize=None)
+def pair_moves(k):
+    """(dispatch, combine) for ``k`` experts a token: the two moves
+    between the (T, d) tokens and the (T x k, d) buffer of token-expert
+    pairs sorted by ``order`` (``inv`` its inverse), of which the first
+    ``rows`` are real. They are each other's transpose, and each is
+    written as a gather: left to jax, the transpose of a gather is a
+    scatter-add, which the TPU runs row by row and, in bf16, sums in
+    bf16."""
+    import jax
+    import jax.numpy as jnp
 
     @jax.custom_vjp
-    def combine(rows, order, inv):
-        """Token t: the sum of its k pairs' rows, added in float32 —
+    def dispatch(x, order, inv, rows):
+        """Row r < ``rows`` of the buffer: the token of pair
+        ``order[r]``, gathered by :func:`over_prefix`."""
+        return over_prefix(
+            lambda real, pairs, _: (jnp.where(real, x[pairs // k], 0),),
+            rows, (order,),
+            (unwritten((order.size,) + x.shape[1:], x.dtype, x),))[0][0]
+
+    @jax.custom_vjp
+    def combine(buffer, order, inv, rows):
+        """Token t: the sum of its k pairs' rows, added in float32;
+        a pair past ``rows`` adds nothing, whatever its row holds —
         k gathers of T rows each: one gather of all T x k rows wants a
         copy into (T, k, d) tiles before the sum (3.8 against 2.8 ms
         on a v5e at 65,536 rows of 2048, PR 28)."""
-        where = inv.reshape(rows.shape[0] // k, k).T
-        return sum(rows[where[j]].astype(jnp.float32)
-                   for j in range(k)).astype(rows.dtype)
+        where = inv.reshape(buffer.shape[0] // k, k).T
+        return sum(jnp.where((where[j] < rows)[:, None],
+                             buffer[where[j]], 0).astype(jnp.float32)
+                   for j in range(k)).astype(buffer.dtype)
 
     dispatch.defvjp(
-        lambda x, order, inv: (dispatch(x, order, inv), (order, inv)),
-        lambda saved, g: (combine(g, *saved), None, None))
+        lambda x, *sorting: (dispatch(x, *sorting), sorting),
+        lambda sorting, g: (combine(g, *sorting), None, None, None))
     combine.defvjp(
-        lambda rows, order, inv: (combine(rows, order, inv),
-                                  (order, inv)),
-        lambda saved, g: (dispatch(g, *saved), None, None))
+        lambda buffer, *sorting: (combine(buffer, *sorting), sorting),
+        lambda sorting, g: (dispatch(g, *sorting), None, None, None))
     return dispatch, combine
 
 
@@ -170,9 +282,25 @@ class ExpertFFN(VjpForward):
                 local[:, None] == jnp.arange(hi - lo)[None, :], axis=0,
                 dtype=jnp.int32)
             rows = sizes.sum()
-            real = (jnp.arange(order.size) < rows)[:, None]
             inv = jnp.argsort(order)
             dispatch, combine = pair_moves(k)
+
+        # Every row stage runs over the chunks of the buffers that hold
+        # real pairs (``over_prefix``), as the grouped products stop at
+        # the last group; past them a buffer holds no defined value,
+        # and ``combine`` reads none of it.
+        @prefix_stage
+        def gate(real, h13):
+            # masked BEFORE the activation: a row the product skipped
+            # may hold anything, and 0 * NaN would reach the weights
+            return swiglu(h13, keep=real).astype(mm.cd)
+
+        @prefix_stage
+        def weigh(real, out, weight):
+            # masked BEFORE the weighting, for the same reason: the
+            # weight's gradient is a sum over the row
+            return (jnp.where(real, out, 0).astype(f32)
+                    * weight[:, None]).astype(mm.act)
 
         # Checkpointed: the backward keeps the normalised tokens and
         # ``h13`` and makes the pair buffer and the activation again (a
@@ -181,29 +309,28 @@ class ExpertFFN(VjpForward):
         @jax.checkpoint
         def up(tokens, w13):
             with jax.named_scope("veles.route"):
-                xs = jnp.where(real, dispatch(tokens, order, inv), 0)
+                xs = dispatch(tokens, order, inv, rows)
             with jax.named_scope("veles.experts"):
                 return mm.grouped_dot(xs, w13, sizes)
 
         @jax.checkpoint
         def down(h13, w2):
             with jax.named_scope("veles.route"):
-                # masked BEFORE the activation: a row the product
-                # skipped may hold anything, and 0 * NaN would reach
-                # the weights
-                act = swiglu(h13, keep=real).astype(mm.cd)
+                act, _ = gate(rows, h13)
             with jax.named_scope("veles.experts"):
                 return mm.grouped_dot(act, w2, sizes)
 
         out = down(up(n.astype(mm.cd), p["weights13"]), p["weights2"])
         with jax.named_scope("veles.route"):
-            # masked BEFORE the weighting, for the same reason: the
-            # weight's gradient is a sum over the row
-            out = jnp.where(real, out, 0).astype(f32) \
-                * weight.reshape(-1)[order][:, None]
-            y = combine(out.astype(mm.act), order, inv).astype(f32)
+            # a weight past the real pairs is masked for its gradient's
+            # sake: the weighting's pullback writes the prefix alone
+            out, touched = weigh(rows, out, jnp.where(
+                jnp.arange(order.size) < rows,
+                weight.reshape(-1)[order], 0))
+            y = combine(out, order, inv, rows).astype(f32)
             aux = {"pairs": rows, "max_load": sizes.max(),
-                   "dropped": misplaced_pairs(local, held, inv, sizes)}
+                   "dropped": misplaced_pairs(local, held, inv, sizes),
+                   "touched": touched}
         return x.astype(f32) + y.reshape(x.shape), aux
 
     # -- counters ----------------------------------------------------------
@@ -214,7 +341,7 @@ class ExpertFFN(VjpForward):
 
     def metric_sinks(self):
         return [("moe_%s_%s" % (key, self.name), "step_" + key)
-                for key in ("pairs", "max_load", "dropped")]
+                for key in ("pairs", "max_load", "dropped", "touched")]
 
     @property
     def step_pairs(self):
@@ -241,6 +368,20 @@ class ExpertFFN(VjpForward):
             "busiest held expert over the mean of the held experts",
             ("layer",)).labels(self.name).set(
                 busiest / mean if mean else 0.0)
+
+    step_touched = property(lambda self: None)
+
+    @step_touched.setter
+    def step_touched(self, touched):
+        telemetry.counter(
+            "veles_moe_rows_touched_total", "Rows of the pair buffer an "
+            "expert layer's row stages processed (chunks run x chunk)",
+            ("layer",)).labels(self.name).inc(touched)
+        telemetry.gauge(
+            "veles_moe_buffer_rows", "Rows of an expert layer's pair "
+            "buffer: tokens a step x experts a token, the worst case",
+            ("layer",)).labels(self.name).set(
+                self.input.size // self.input.shape[-1] * self.top_k)
 
     step_dropped = property(lambda self: None)
 

@@ -102,6 +102,10 @@ def _products(cd):
         dx = rd(g, w.swapaxes(1, 2), sizes, like.dtype)
         dw = jax.lax.ragged_dot_general(
             x, g, sizes, by_group, preferred_element_type=w.dtype)
+        # dx leaves with dw: what consumes dx then runs after the
+        # product has read x, and may overwrite what x was made from
+        # in place (without the order XLA copies that buffer first)
+        dx, dw = jax.lax.optimization_barrier((dx, dw))
         return dx, dw, None
 
     grouped_dot.defvjp(grouped_fwd, grouped_bwd)
