@@ -147,6 +147,17 @@ class FlowContext:
     params/state pytrees and the PRNG key / train flag. Units read
     inputs (resolved through link_attrs wiring by the unit itself) and
     ``set`` their outputs.
+
+    A unit that a loop (``veles/znicz_tpu/loop.py``) runs several times
+    in one step runs each time in a VISIT: a child context (:meth:`visit`) that reads
+    the step's values, parameters and state but keeps what the unit
+    sets — its output, its pullbacks, its residuals, its exports — to
+    itself, so one visit never overwrites another's and all of it dies
+    with the loop iteration that made it; what a later visit needs the
+    loop carries out by hand. A visit does not update parameters: the
+    gradients a gradient unit hands to its solver there are added to
+    the loop's float32 sums, ``grads`` (:meth:`defer`), and the solver
+    runs once a step on the sum over the visits.
     """
 
     def __init__(self, compiler, params, state, hyper, key, train,
@@ -160,6 +171,13 @@ class FlowContext:
         self.axis_name = axis_name  # set when traced under shard_map
         self.values = {}            # (producer_unit_name, attr) -> tensor
         self.outputs = {}           # exported outputs (metrics etc.)
+        #: the backward of what runs here follows in this context (a
+        #: unit on ``jax.vjp`` leaves its pullback); False in a visit
+        #: whose backward runs from a recomputation
+        self.pullbacks = True
+        #: a visit's deferred gradients, {gd unit name: {param: grad}};
+        #: None outside a visit (the solver runs where the unit does)
+        self.grads = None
         #: model-health plane (veles/model_health.py): when set, GD
         #: units export their per-layer stat vector as one extra fused
         #: output — a compile-time variant, keyed into the program
@@ -170,6 +188,34 @@ class FlowContext:
             getattr(compiler, "collect_stats", False)) and train
         self.stats_stride = int(
             getattr(compiler, "stats_stride", 1) or 1)
+
+    def visit(self, pullbacks=True, grads=None):
+        """A child context for one visit of a loop;
+        ``grads``: the loop's float32 sums so far, {gd unit name:
+        {parameter: sum}}, which this visit's gradients are added to."""
+        import collections
+        import copy
+        child = copy.copy(self)
+        child.params = dict(self.params)
+        child.values = collections.ChainMap({}, self.values)
+        child.outputs = {}
+        child.pullbacks = pullbacks
+        child.grads = {name: dict(sums)
+                       for name, sums in (grads or {}).items()}
+        return child
+
+    def defer(self, unit, **grads):
+        """In a visit: add ``grads`` to the loop's sums — each where
+        it is made, so that no visit's gradients wait in memory for
+        the others — and say True (the caller leaves its solver
+        alone)."""
+        if self.grads is None:
+            return False
+        sums = self.grads[unit.name]
+        for name, grad in grads.items():
+            if grad is not None:
+                sums[name] = sums[name] + grad.astype(sums[name].dtype)
+        return True
 
     # value routing ----------------------------------------------------
 
@@ -315,6 +361,11 @@ class StepCompiler:
         #: key, since they change the traced program
         self.collect_stats = False
         self.stats_stride = 1
+        #: the workflow's loop, where some units run several times a
+        #: step (``znicz_tpu.loop.Loop``; set by XLAStep before anything
+        #: is traced): ``loop.trace(compiler, ctx, units)`` then runs the
+        #: step's units in place of ``trace_step``'s own walk
+        self.loop = None
         self._compiled = {}
 
     # pytree assembly ---------------------------------------------------
@@ -351,18 +402,29 @@ class StepCompiler:
         compilation: build the context, bind the batch (caller-supplied
         closure), run every unit's ``xla_run`` under the scope
         ``veles.<role>.<Class>.<name>`` — the name a device trace shows
-        for the unit's operations (``tf_op``)."""
-        import jax
+        for the unit's operations (``tf_op``). Where the workflow has a
+        ``loop``, that runs the units (some several times each)."""
         ctx = FlowContext(self, dict(params), dict(state), hyper,
                           key, train)
         bind(ctx)
+        if self.loop is not None:
+            self.loop.trace(self, ctx, list(units))
+            return ctx
         for unit in units:
-            if not train and getattr(unit, "train_only", False):
-                continue
-            with jax.named_scope("veles.%s.%s.%s" % (
-                    unit.scope_role, type(unit).__name__, unit.name)):
-                unit.xla_run(ctx)
+            self.run_unit(ctx, unit)
         return ctx
+
+    @staticmethod
+    def unit_scope(unit):
+        import jax
+        return jax.named_scope("veles.%s.%s.%s" % (
+            unit.scope_role, type(unit).__name__, unit.name))
+
+    def run_unit(self, ctx, unit):
+        if not ctx.train and getattr(unit, "train_only", False):
+            return
+        with self.unit_scope(unit):
+            unit.xla_run(ctx)
 
     def build_step(self, batch_spec, train=True):
         """Return ``step(params, state, batch, hyper, key)``.

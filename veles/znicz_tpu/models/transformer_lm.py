@@ -16,7 +16,7 @@ import numpy
 from veles import prng
 from veles.config import root
 from veles.loader.fullbatch import FullBatchLoader
-from veles.znicz_tpu.ops.evaluator import EvaluatorLM
+from veles.znicz_tpu.ops.evaluator import EvaluatorLM, EvaluatorLoopLM
 from veles.znicz_tpu.standard_workflow import StandardWorkflow
 
 root.lm.update({
@@ -58,8 +58,20 @@ root.lm.update({
     # moe_hidden (sigmoid scores times moe_scaling, selection biases
     # drawn at moe_bias_stddev), of which this job holds experts_held =
     # [lo, hi) (None: all; the rest live on other chips and their part
-    # of the sum is left out). Every key is a shape or a constant the
-    # model's config states, none a tuning knob.
+    # of the sum is left out). pre_norm's "plain_attention" is
+    # "full_attention" without the q/k norm. norm: "pre" (one gain
+    # before each sub-layer) or "sandwich" (a second gain on the
+    # sub-layer's output, before the residual add). ut_steps > 1 runs
+    # the layers and the final norm ut_steps times in a row over the
+    # same weights (znicz_tpu.loop.Loop: the passes are a loop of
+    # the compiled step, a layer is recomputed in the backward from its
+    # saved input, the solver runs once a step on the gradients' sum);
+    # every pass's state exits through the one head, an exit gate
+    # (ops/exit_gate.py) weighs the exits per token, and the loss is
+    # the expected cross entropy less exit_entropy_weight times the
+    # exit distribution's entropy (EvaluatorLoopLM). Every key is a
+    # shape or a constant the model's config states, none a tuning
+    # knob.
     "model": {"dim": 64, "heads": 4, "layers": 2, "ffn_hidden": 128,
               "attn_block": None, "attn_impl": None,
               "pallas_tile": None, "moe_experts": 0,
@@ -69,7 +81,8 @@ root.lm.update({
               "dense_layers": 0, "moe_hidden": None, "moe_top_k": 1,
               "experts_held": None, "moe_scaling": 1.0,
               "moe_bias_stddev": 0.0, "conv_kernel": 3,
-              "rope_theta": 1e6, "norm_eps": 1e-5},
+              "rope_theta": 1e6, "norm_eps": 1e-5, "norm": "pre",
+              "ut_steps": 1, "exit_entropy_weight": 0.0},
     "train": {"learning_rate": 0.05, "gradient_moment": 0.9,
               "weights_decay": 0.0},
     "decision": {"max_epochs": 8, "fail_iterations": 50},
@@ -219,41 +232,75 @@ def layer_operators(m):
     return list(layers)
 
 
+#: pre_norm's attention operators: is there an RMS norm on q and k
+ATTENTION_OPERATORS = {"full_attention": True, "plain_attention": False}
+
+
+def loop_passes(m):
+    """``ut_steps``: how often the layers run in a row (1: once)."""
+    steps = m.get("ut_steps", 1)
+    if steps != 1 and m.get("block", "post_ln") != "pre_norm":
+        raise ValueError("ut_steps=%r needs block='pre_norm'" % (steps,))
+    return steps
+
+
 def pre_norm_body(m, t):
     """The layers of ``block="pre_norm"`` between embedding and head
-    (the keys' meaning is beside ``root.lm.model``)."""
+    (the keys' meaning is beside ``root.lm.model``). With ``ut_steps``
+    > 1 each layer dict says its place in the loop (``"loop"``: a
+    layer's operator starts a recomputed segment)."""
     operators = layer_operators(m)
-    if set(operators) - {"conv", "full_attention"}:
-        raise ValueError("block='pre_norm' has the operators 'conv' "
-                         "and 'full_attention', got %r" % (operators,))
+    if set(operators) - {"conv"} - set(ATTENTION_OPERATORS):
+        raise ValueError("block='pre_norm' has the operators 'conv', "
+                         "'full_attention' and 'plain_attention' (no "
+                         "q/k norm), got %r" % (operators,))
+    norm = m.get("norm", "pre")
+    if norm not in ("pre", "sandwich"):
+        raise ValueError("norm is 'pre' or 'sandwich', got %r" % (norm,))
+    steps = loop_passes(m)
+    looped = steps > 1
     busy = {k: v for k, v in root.lm.parallel.to_dict().items()
-            if k in ("seq", "model", "expert", "pipe") and v > 1}
+            if k in ("seq", "model", "expert", "pipe") + (
+                ("data",) if looped else ()) and v > 1}
     if busy or m.get("stacked"):
         raise ValueError(
             "block='pre_norm' trains on the per-unit path, one chip or "
-            "data-parallel; not with stacked=%r / parallel %r"
-            % (m.get("stacked"), busy))
+            "(at ut_steps=1) data-parallel; not with stacked=%r / "
+            "parallel %r" % (m.get("stacked"), busy))
+    dense = m.get("dense_layers", 0) >= len(operators)
+    if looped and not dense:
+        raise ValueError(
+            "ut_steps=%r loops layers with a SwiGLU feed-forward "
+            "(dense_layers = the layers' number): the expert layer's "
+            "counters are one visit's" % (steps,))
+    if norm == "sandwich" and ("conv" in operators or not dense):
+        raise ValueError("norm='sandwich' is for attention layers over "
+                         "a SwiGLU feed-forward")
     eps = m.get("norm_eps", 1e-5)
+    sandwich = {"sandwich": True} if norm == "sandwich" else {}
     body = []
     for index, kind in enumerate(operators):
         if kind == "conv":
             body.append({
                 "type": "short_conv",
                 "->": {"kernel": m.get("conv_kernel", 3), "eps": eps},
-                "<-": dict(t)})
+                "<-": dict(t), "loop": "segment"})
         else:
+            plain = {} if ATTENTION_OPERATORS[kind] \
+                else {"qk_norm": False}
             body.append({
                 "type": "gqa_attention",
                 "->": dict(attention_kernel_keys(m), heads=m.heads,
                            kv_heads=m.get("kv_heads"),
                            head_dim=m.get("head_dim"),
                            rope_theta=m.get("rope_theta", 1e6),
-                           eps=eps),
-                "<-": dict(t)})
+                           eps=eps, **plain, **sandwich),
+                "<-": dict(t), "loop": "segment"})
         if index < m.get("dense_layers", 0):
             body.append({"type": "swiglu_ffn",
-                         "->": {"hidden": m.ffn_hidden, "eps": eps},
-                         "<-": dict(t)})
+                         "->": dict(hidden=m.ffn_hidden, eps=eps,
+                                    **sandwich),
+                         "<-": dict(t), "loop": "body"})
         else:
             body.append({
                 "type": "expert_ffn",
@@ -265,7 +312,10 @@ def pre_norm_body(m, t):
                        "bias_stddev": m.get("moe_bias_stddev", 0.0),
                        "eps": eps},
                 "<-": dict(t)})
-    body.append({"type": "rms_norm", "->": {"eps": eps}, "<-": dict(t)})
+    body.append({"type": "rms_norm", "->": {"eps": eps}, "<-": dict(t),
+                 "loop": "body"})
+    if looped:
+        body.append({"type": "exit_gate", "<-": dict(t), "loop": "body"})
     return body
 
 
@@ -294,7 +344,7 @@ def build_layers():
     head = {"type": "token_dense",
             "->": dict({"output_features": vocab},
                        **({"include_bias": False} if pre_norm else {})),
-            "<-": dict(t)}
+            "<-": dict(t), "loop": "exit"}
     if pre_norm:
         return layers + pre_norm_body(m, t) + [head]
     if m.get("stacked"):
@@ -348,8 +398,35 @@ def build_layers():
     return layers
 
 
+def looped_stack(wf):
+    """The workflow's ``znicz_tpu.loop.Loop`` (None at ut_steps 1),
+    from the places ``pre_norm_body`` gave the layers."""
+    steps = loop_passes(root.lm.model)
+    if steps <= 1:
+        return None
+    from veles.znicz_tpu.loop import Loop
+    segments, exits = [], []
+    for spec, unit in zip(wf.layers_config, wf.forwards):
+        place = spec.get("loop")
+        if place == "segment":
+            segments.append([unit])
+        elif place == "body":
+            segments[-1].append(unit)
+        elif place == "exit":
+            exits.append(unit)
+    return Loop(steps, segments, exits)
+
+
 def lm_evaluator_factory(wf, last):
-    ev = EvaluatorLM(wf, name="evaluator")
+    m = root.lm.model
+    steps = loop_passes(m)
+    if steps > 1:
+        ev = EvaluatorLoopLM(
+            wf, name="evaluator", steps=steps,
+            entropy_weight=m.get("exit_entropy_weight", 0.0))
+        ev.link_attrs(wf.forwards[-2], "gate")   # the exit gate's tap
+    else:
+        ev = EvaluatorLM(wf, name="evaluator")
     ev.link_attrs(last, ("input", "output"))
     ev.link_attrs(wf.loader, ("labels", "minibatch_labels"),
                   ("batch_size", "minibatch_size"))
@@ -361,6 +438,12 @@ class TransformerLMWorkflow(StandardWorkflow):
     ``root.lm.parallel`` picks ring attention (seq), Megatron TP
     (model) and/or batch DP (data) — no code required in user
     configs."""
+
+    def create_workflow(self):
+        super().create_workflow()
+        #: units that run several times a step (``ut_steps`` > 1)
+        self.loop = looped_stack(self)
+        return self
 
     def initialize(self, device=None, **kwargs):
         out = super().initialize(device=device, **kwargs)

@@ -542,8 +542,23 @@ class GradientDescentBase(AcceleratedUnit, IDistributable):
             vec = compute()
         ctx.export(model_health.STAT_KEY_PREFIX + self.name, vec)
 
-    @_update_scope
+    def apply_grads(self, ctx, grads):
+        """The solver on ``grads`` = {parameter name: gradient}:
+        ``weights`` (and ``bias``), then the ``EXTRA_PARAMS``."""
+        self.update_weights_xla(ctx, grads["weights"], grads.get("bias"))
+        extra = {n: grads[n] for n, _ in self.EXTRA_PARAMS if n in grads}
+        if extra:
+            self.update_extra_xla(ctx, extra)
+
     def update_weights_xla(self, ctx, grad_w, grad_b):
+        """The solver on ``weights`` and ``bias``; in a visit of a loop
+        the gradients go to the loop's sums instead
+        (``FlowContext.defer``) and the solver runs once, later."""
+        if not ctx.defer(self, weights=grad_w, bias=grad_b):
+            self._update_weights(ctx, grad_w, grad_b)
+
+    @_update_scope
+    def _update_weights(self, ctx, grad_w, grad_b):
         import jax.numpy as jnp
         f = self.forward
         h = ctx.hyper[self.name]
@@ -662,10 +677,15 @@ class GradientDescentBase(AcceleratedUnit, IDistributable):
             if q is not None:
                 sq.mem[...] = q
 
-    @_update_scope
     def update_extra_xla(self, ctx, grads):
         """Traced twin of :meth:`update_extra_numpy`; call after
-        ``update_weights_xla`` in the same ``xla_run``."""
+        ``update_weights_xla`` in the same ``xla_run`` (deferred with
+        it in a visit of a loop)."""
+        if not ctx.defer(self, **grads):
+            self._update_extra(ctx, grads)
+
+    @_update_scope
+    def _update_extra(self, ctx, grads):
         import jax.numpy as jnp
         f = self.forward
         h = ctx.hyper[self.name]

@@ -15,6 +15,7 @@ from veles.znicz_tpu.ops.gd import (  # noqa: F401
 )
 from veles.znicz_tpu.ops.evaluator import (  # noqa: F401
     EvaluatorBase, EvaluatorSoftmax, EvaluatorMSE, EvaluatorLM,
+    EvaluatorLoopLM,
 )
 from veles.znicz_tpu.ops.conv import (  # noqa: F401
     Conv, ConvTanh, ConvRELU, ConvStrictRELU, ConvSigmoid,
@@ -64,6 +65,9 @@ from veles.znicz_tpu.ops.short_conv import (  # noqa: F401
 from veles.znicz_tpu.ops.swiglu import SwiGLUFFN, GDSwiGLUFFN  # noqa: F401
 from veles.znicz_tpu.ops.gqa_attention import (  # noqa: F401
     GQAttention, GDGQAttention,
+)
+from veles.znicz_tpu.ops.exit_gate import (  # noqa: F401
+    ExitGate, GDExitGate,
 )
 from veles.znicz_tpu.ops.expert_ffn import (  # noqa: F401
     ExpertFFN, GDExpertFFN,

@@ -271,3 +271,135 @@ class EvaluatorLM(EvaluatorBase):
         ctx.set(self, "err_output", err.astype(ctx.act_dtype))
         ctx.export("loss", loss)
         ctx.export("n_err", wrong.astype(jnp.int32))
+
+
+class EvaluatorLoopLM(EvaluatorLM):
+    """The loss of a looped layer stack (``znicz_tpu.loop.Loop``):
+    every pass ``t`` of ``steps`` exits through the one head, and a
+    learned gate ``lambda_t = sigmoid(gate_t)`` says, per token, how
+    much of the prediction leaves there:
+
+        p_t  = lambda_t * prod_{j<t} (1 - lambda_j)   (t < steps)
+        p_T  = prod_{j<T} (1 - lambda_j)              (sums to 1)
+        loss = mean over tokens of [sum_t p_t CE_t - beta * H(p)]
+
+    with ``CE_t`` the token's cross entropy at exit ``t`` and ``H`` the
+    entropy of ``(p_1 .. p_T)``. The loop drives it in three calls:
+    ``loop_begin`` makes ``p`` from the passes' gates, ``xla_run`` is
+    ONE exit's visit (the logits of one exit live at a time: its
+    ``err_output`` is ``p_t (softmax - onehot) / tokens``), and
+    ``loop_end`` has every exit's per-token ``CE_t``, so it closes the
+    loss and gives the gates' cotangent — through the weights ``p_t``
+    AND the entropy term."""
+
+    def __init__(self, workflow, steps=2, entropy_weight=0.0, **kwargs):
+        super().__init__(workflow, **kwargs)
+        self.steps = int(steps)
+        self.entropy_weight = float(entropy_weight)
+        self.gate = None            # linked: the gate unit's tap
+
+    @staticmethod
+    def exit_log_mass(gate):
+        """log p, (T, ...) from the gates (T, ...), in log space: no
+        product of sigmoids underflows to a 0 whose log is taken."""
+        import jax
+        import jax.numpy as jnp
+        stay = jax.nn.log_sigmoid(-gate)            # log(1 - lambda)
+        before = jnp.cumsum(stay, axis=0) - stay    # sum over j < t
+        leave = jnp.concatenate(
+            [jax.nn.log_sigmoid(gate[:-1]), jnp.zeros_like(gate[:1])])
+        return leave + before
+
+    def _token_mask(self, ctx, shape):
+        import jax.numpy as jnp
+        valid = ctx.get(self, "batch_size")
+        rows = (jnp.arange(shape[0]) < valid).astype(jnp.float32)
+        return rows[:, None] / (valid.astype(jnp.float32) * shape[1])
+
+    def loop_begin(self, ctx):
+        """-> what each exit's visit is given, stacked by pass."""
+        import jax.numpy as jnp
+        gate = ctx.get(self, "gate").astype(jnp.float32)
+        return {"exit_mass": jnp.exp(self.exit_log_mass(gate))}
+
+    def xla_run(self, ctx):
+        import jax.numpy as jnp
+        logits = ctx.get(self, "input").astype(jnp.float32)
+        labels = ctx.get(self, "labels").astype(jnp.int32)
+        logp, probs, onehot = self._softmax_ce_core(jnp, logits, labels)
+        mask = self._token_mask(ctx, labels.shape)
+        if ctx.train:
+            weight = ctx.get(self, "exit_mass") * mask
+            ctx.set(self, "err_output",
+                    ((probs - onehot) * weight[..., None])
+                    .astype(ctx.act_dtype))
+        ctx.export("exit_ce", -(logp * onehot).sum(axis=-1))
+        wrong = (jnp.argmax(logits, axis=-1) != labels) & (mask > 0)
+        ctx.export("exit_wrong", wrong.sum().astype(jnp.int32))
+
+    def loop_end(self, ctx, exits):
+        """``exits``: the visits' exports stacked by pass. Exports the
+        step's metrics; in a training step -> the taps' cotangents,
+        ``{"gate": (T, B, S)}``."""
+        import jax
+        import jax.numpy as jnp
+        gate = ctx.get(self, "gate").astype(jnp.float32)
+        ce = exits["exit_ce"]
+        mask = self._token_mask(ctx, ce.shape[1:])
+
+        def total(gate):
+            logp = self.exit_log_mass(gate)
+            p = jnp.exp(logp)
+            per_token = (p * ce).sum(0) \
+                + self.entropy_weight * (p * logp).sum(0)
+            return (per_token * mask).sum(), p
+
+        (loss, p), dgate = jax.value_and_grad(total, has_aux=True)(gate)
+        ctx.export("loss", loss)
+        ctx.export("n_err", exits["exit_wrong"][-1])
+        if not ctx.train:
+            return {}
+        mass = (p * mask).sum((1, 2))
+        for t in range(self.steps):
+            ctx.export("loop_ce_%d" % (t + 1), (ce[t] * mask).sum())
+            ctx.export("loop_mass_%d" % (t + 1), mass[t])
+        ctx.export("loop_expected",
+                   (mass * jnp.arange(1, self.steps + 1)).sum())
+        return {"gate": dgate}
+
+    # -- counters: the last training step's exits, by pass ---------------
+
+    def metric_sinks(self):
+        return super().metric_sinks() + [
+            ("loop_%s_%d" % (what, t), "step_%s_%d" % (what, t))
+            for t in range(1, self.steps + 1) for what in ("ce", "mass")
+        ] + [("loop_expected", "step_expected")]
+
+    def metrics_published(self, fresh):
+        """``XLAStep``'s hook, once a step's sinks are filled: a
+        training step's (only that exports ``loop_*``) moves the
+        counters and sets the gauges."""
+        if "step_expected" not in fresh:
+            return
+        from veles import telemetry
+        telemetry.counter(
+            "veles_loop_steps_total", "Training steps a looped layer "
+            "stack ran").inc()
+        telemetry.counter(
+            "veles_loop_passes_total", "Passes of a looped layer stack "
+            "over its one set of weights, training steps"
+        ).inc(self.steps)
+        telemetry.gauge(
+            "veles_loop_expected_exit_pass", "Last step: the pass at "
+            "which a token's prediction leaves, in expectation"
+        ).set(self.step_expected)
+        loss = telemetry.gauge(
+            "veles_loop_exit_loss", "Last step: mean cross entropy of a "
+            "looped stack's exit, by pass", ("pass",))
+        mass = telemetry.gauge(
+            "veles_loop_exit_mass", "Last step: mean share of a token's "
+            "prediction that leaves at a pass (the exit distribution)",
+            ("pass",))
+        for t in range(1, self.steps + 1):
+            loss.labels(str(t)).set(getattr(self, "step_ce_%d" % t))
+            mass.labels(str(t)).set(getattr(self, "step_mass_%d" % t))

@@ -9,6 +9,12 @@ heads), rotary positions on the whole head (half-split rotation):
     q, k    = rope(rms(q; g_q)), rope(rms(k; g_k))
     y       = x + merge(softmax(q k^T / sqrt(dh) + causal) v) W_o
 
+That is the pre-norm placement, one gain before the sub-layer. With
+``sandwich`` the sub-layer's float32 output takes a second gain before
+the residual add, ``y = x + rms(merge(...) W_o; g_out)``; with
+``qk_norm=False`` q and k go to the rotation as projected (no ``g_q``,
+``g_k``).
+
 The projections, norms and rotation are ``jax.vjp`` of their trace
 (``ops/vjp_units.py``). The attention proper is the repo's own — the
 Pallas kernels on a TPU from S=256 up, ``parallel/flash.py`` elsewhere
@@ -64,8 +70,14 @@ class GQAttention(VjpForward, MultiHeadAttention):
     PARAMS = ("weights", "weights_out", "norm", "q_norm", "k_norm")
 
     def __init__(self, workflow, heads=4, kv_heads=None, head_dim=None,
-                 rope_theta=1e6, eps=1e-5, **kwargs):
+                 rope_theta=1e6, eps=1e-5, qk_norm=True, sandwich=False,
+                 **kwargs):
         kwargs.setdefault("residual", True)
+        self.qk_norm = bool(qk_norm)
+        self.sandwich = bool(sandwich)
+        self.PARAMS = ("weights", "weights_out", "norm") \
+            + (("q_norm", "k_norm") if self.qk_norm else ()) \
+            + (("norm_out",) if self.sandwich else ())
         super().__init__(workflow, heads=heads, causal=True, **kwargs)
         self.kv_heads = int(kv_heads or heads)
         if self.heads % self.kv_heads:
@@ -79,11 +91,15 @@ class GQAttention(VjpForward, MultiHeadAttention):
         d = ishape[-1]
         dh = self.head_dim = int(self.head_dim or d // self.heads)
         wide = (self.heads + 2 * self.kv_heads) * dh
-        return {"weights": ((d, wide), (d, wide)),
-                "weights_out": ((self.heads * dh, d),
-                                (self.heads * dh, d)),
-                "norm": ((d,), "ones"),
-                "q_norm": ((dh,), "ones"), "k_norm": ((dh,), "ones")}
+        specs = {"weights": ((d, wide), (d, wide)),
+                 "weights_out": ((self.heads * dh, d),
+                                 (self.heads * dh, d)),
+                 "norm": ((d,), "ones")}
+        if self.qk_norm:
+            specs.update(q_norm=((dh,), "ones"), k_norm=((dh,), "ones"))
+        if self.sandwich:
+            specs["norm_out"] = ((d,), "ones")
+        return specs
 
     def initialize(self, device=None, **kwargs):
         if self.seq_mesh is not None:
@@ -110,8 +126,14 @@ class GQAttention(VjpForward, MultiHeadAttention):
             return t.reshape(b, s, n, dh).transpose(0, 2, 1, 3)
 
         cos, sin = rope_tables(s, dh, self.rope_theta)
-        q = rope(rms_norm(heads(q, h), p["q_norm"], self.eps), cos, sin)
-        k = rope(rms_norm(heads(k, kv), p["k_norm"], self.eps), cos, sin)
+
+        def turned(t, n, gain):
+            t = heads(t, n)
+            if self.qk_norm:
+                t = rms_norm(t, p[gain], self.eps)
+            return rope(t, cos, sin)
+
+        q, k = turned(q, h, "q_norm"), turned(k, kv, "k_norm")
         k, v = (repeat_heads(t, h // kv) for t in (k, heads(v, kv)))
         return tuple(t.astype(mm.cd) for t in (q, k, v))
 
@@ -136,14 +158,17 @@ class GQAttention(VjpForward, MultiHeadAttention):
 
     def finish(self, ctx, p, merged):
         import jax.numpy as jnp
-        return Products(ctx).dot(merged, p["weights_out"], jnp.float32)
+        out = Products(ctx).dot(merged, p["weights_out"], jnp.float32)
+        if self.sandwich:
+            out = rms_norm(out, p["norm_out"], self.eps)
+        return out
 
     def xla_run(self, ctx):
         import jax.numpy as jnp
         x = ctx.get(self, "input")
         p, _ = self.split_params(ctx)
-        pre = {k: p[k] for k in ("weights", "norm", "q_norm", "k_norm")}
-        post = {"weights_out": p["weights_out"]}
+        post = {k: p[k] for k in ("weights_out", "norm_out") if k in p}
+        pre = {k: v for k, v in p.items() if k not in post}
         mode = self._traced_mode(ctx, x.shape[1])
 
         def project(pre, x):
@@ -165,7 +190,8 @@ class GQAttention(VjpForward, MultiHeadAttention):
 @gradient_for(GQAttention)
 class GDGQAttention(GDVjp):
     EXTRA_PARAMS = (("weights_out", False), ("norm", True),
-                    ("q_norm", True), ("k_norm", True))
+                    ("q_norm", True), ("k_norm", True),
+                    ("norm_out", True))
 
     def xla_run(self, ctx):
         import jax.numpy as jnp

@@ -3,7 +3,8 @@
     y = x + (silu(n W1) * (n W3)) W2,   n = rms(x; g)
 
 ``weights`` holds ``[W1 | W3]`` (d x 2f), so the two up-projections
-are one product.
+are one product. With ``sandwich`` the sub-layer's float32 output takes
+a gain of its own before the residual add: ``y = x + rms(...; g_out)``.
 """
 
 from veles.znicz_tpu.nn_units import forward_unit, gradient_for
@@ -29,7 +30,11 @@ def swiglu(h13, keep=None):
 class SwiGLUFFN(VjpForward):
     PARAMS = ("weights", "weights2", "norm")
 
-    def __init__(self, workflow, hidden=None, eps=1e-5, **kwargs):
+    def __init__(self, workflow, hidden=None, eps=1e-5, sandwich=False,
+                 **kwargs):
+        self.sandwich = bool(sandwich)
+        if self.sandwich:
+            self.PARAMS = type(self).PARAMS + ("norm_out",)
         super().__init__(workflow, **kwargs)
         if not hidden:
             raise ValueError("swiglu_ffn needs hidden")
@@ -38,9 +43,12 @@ class SwiGLUFFN(VjpForward):
 
     def param_specs(self, ishape):
         d, f = ishape[-1], self.hidden
-        return {"weights": ((d, 2 * f), (d, f)),
-                "weights2": ((f, d), (f, d)),
-                "norm": ((d,), "ones")}
+        specs = {"weights": ((d, 2 * f), (d, f)),
+                 "weights2": ((f, d), (f, d)),
+                 "norm": ((d,), "ones")}
+        if self.sandwich:
+            specs["norm_out"] = ((d,), "ones")
+        return specs
 
     def apply(self, ctx, p, x):
         import jax
@@ -53,9 +61,12 @@ class SwiGLUFFN(VjpForward):
 
         h13 = mm.dot(rms_norm(x, p["norm"], self.eps), p["weights"])
         out = down(h13, p["weights2"])
+        if self.sandwich:
+            out = rms_norm(out, p["norm_out"], self.eps)
         return x.astype(jnp.float32) + out
 
 
 @gradient_for(SwiGLUFFN)
 class GDSwiGLUFFN(GDVjp):
-    EXTRA_PARAMS = (("weights2", False), ("norm", True))
+    EXTRA_PARAMS = (("weights2", False), ("norm", True),
+                    ("norm_out", True))

@@ -195,8 +195,9 @@ class VjpForward(Forward):
 
     def traced(self, ctx, fn, *args, has_aux=False):
         """``fn(*args)``; in a training step through ``jax.vjp``, the
-        pullback left for the gradient unit under ``fn``'s name."""
-        if not ctx.train:
+        pullback left for the gradient unit under ``fn``'s name (not
+        in a visit whose backward runs from a later recomputation)."""
+        if not (ctx.train and ctx.pullbacks):
             return fn(*args)
         import jax
         out = jax.vjp(fn, *args, has_aux=has_aux)
@@ -240,12 +241,6 @@ class GDVjp(GradientDescentBase):
 
     def pull(self, ctx, name, cotangent):
         return ctx.get(self.forward, "vjp_" + name)(cotangent)
-
-    def apply_grads(self, ctx, grads):
-        self.update_weights_xla(ctx, grads["weights"], None)
-        extra = {n: grads[n] for n, _ in self.EXTRA_PARAMS}
-        if extra:
-            self.update_extra_xla(ctx, extra)
 
     def xla_run(self, ctx):
         f = self.forward

@@ -102,6 +102,7 @@ class XLAStep(Unit):  # zlint: disable=checkpoint-state (params/state/step_index
         super().initialize(**kwargs)
         self.device = device or getattr(self.workflow, "device", None)
         self.compiler = StepCompiler(self.train_units, self.device)
+        self.compiler.loop = getattr(self.workflow, "loop", None)
         self.compiler.collect_stats = bool(self.collect_model_stats)
         self.compiler.stats_stride = max(1, int(self.stats_interval))
         self.params = self._place_tree(self.compiler.gather_params())
@@ -686,7 +687,9 @@ class XLAStep(Unit):  # zlint: disable=checkpoint-state (params/state/step_index
         """Hand step metrics to the host side. Every unit may declare
         ``metric_sinks() -> [(output_key, attr_name), ...]`` — the
         evaluator base declares n_err/loss; custom trainers (Kohonen,
-        RBM) publish their own. Stat outputs (the model-health plane's
+        RBM) publish their own. A unit with ``metrics_published(attrs)``
+        is told, once its sinks are filled, which attributes this step
+        set (scalars only). Stat outputs (the model-health plane's
         per-layer vectors) are split off first and published at the
         stats cadence."""
         from veles import model_health
@@ -697,6 +700,7 @@ class XLAStep(Unit):  # zlint: disable=checkpoint-state (params/state/step_index
             sinks = getattr(unit, "metric_sinks", None)
             if sinks is None:
                 continue
+            fresh = set()
             for key, attr in sinks():
                 if key not in outputs:
                     continue
@@ -714,6 +718,10 @@ class XLAStep(Unit):  # zlint: disable=checkpoint-state (params/state/step_index
                 value = float(value) if hasattr(value, "dtype") \
                     and value.dtype.kind == "f" else int(value)
                 setattr(unit, attr, value)
+                fresh.add(attr)
+            told = getattr(unit, "metrics_published", None)
+            if told is not None and fresh:
+                told(fresh)
 
     # -- host sync -----------------------------------------------------
 
