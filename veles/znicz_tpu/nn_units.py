@@ -325,7 +325,9 @@ class GradientDescentBase(AcceleratedUnit, IDistributable):
                 if not sq or sq.shape != src.shape:
                     sq.reset(numpy.zeros_like(src.mem))
 
-    # hyper-parameters (traced scalars; changing them never retraces) --
+    # hyper-parameters: traced scalars, so changing them never retraces.
+    # Built on the host at every dispatch; XLAStep._device_hyper keeps
+    # them on the device and uploads again only when one changed ------
 
     def hyperparams(self):
         out = {

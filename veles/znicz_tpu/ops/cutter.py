@@ -97,9 +97,10 @@ class ZeroFiller(Unit):
 
     On the XLA backend the compiled step keeps parameters
     device-resident and never re-reads host Arrays, so the mask is
-    registered on the target Forward unit (``zero_mask``), shipped as a
-    traced hyperparameter each dispatch (host-side mask edits stay
-    live), and applied by ``GradientDescentBase.update_weights_xla``
+    registered on the target Forward unit (``zero_mask``), handed over
+    as a traced hyperparameter (compared at each dispatch, shipped when
+    it changed: host-side mask edits stay live), and applied by
+    ``GradientDescentBase.update_weights_xla``
     inside the trace; ``run`` then only acts on the numpy backend, so
     each backend applies the mask exactly once per step."""
 

@@ -43,6 +43,20 @@ def _record_dispatch(kind, warm, start, dt, **args):
             warm=bool(warm), **args)
 
 
+def _record_host_leaves(kind, args):
+    """How many leaves of a dispatch's arguments still live on the
+    host: each is a host-to-device transfer inside the jit call, and
+    under a mesh one per device."""
+    import jax
+    telemetry.gauge(
+        "veles_xla_dispatch_host_leaves",
+        "Host (non-jax.Array) leaves among the last dispatch's "
+        "arguments: one transfer each, per device",
+        ("kind",)).labels(kind).set(sum(
+            not isinstance(leaf, jax.Array)
+            for leaf in jax.tree_util.tree_leaves(args)))
+
+
 class XLAStep(Unit):  # zlint: disable=checkpoint-state (params/state/step_index are checkpointed by NNWorkflow.checkpoint_state; the rest is per-dispatch bookkeeping reset by restore_state/initialize)
     """Runs the fused step; publishes evaluator metrics to the host."""
 
@@ -232,6 +246,40 @@ class XLAStep(Unit):  # zlint: disable=checkpoint-state (params/state/step_index
         return {gd.name: gd.hyperparams() for gd in self.gds
                 if hasattr(gd, "hyperparams")}
 
+    def _replicated(self):
+        """Placement of what every device reads whole: replicated over
+        the step's mesh, or None (the default device) without one."""
+        if self.batch_sharding is None:
+            return None
+        from veles.znicz_tpu import parallel
+        return parallel.replicated(self.batch_sharding.mesh)
+
+    def _device_hyper(self, kind):
+        """The hyperparameter tree a dispatch hands its program: the
+        units' host values as DEVICE arrays, kept between dispatches
+        and uploaded again only when a host value changed (an lr cut
+        of a rollback, a mask edit). A numpy leaf of a jit call's
+        arguments is one transfer a dispatch and one per device; the
+        schedules run inside the step from a counter in the state, so
+        between such edits these values never move. The programs see
+        the same avals either way: nothing retraces."""
+        import jax
+        host = self._gather_hyper()
+        leaves, treedef = jax.tree_util.tree_flatten(host)
+        # bytes, not the arrays: a mask leaf may alias the unit's
+        # memory, and an edit in place must still read as a change
+        sig = (treedef, [(leaf.shape, leaf.dtype.str, leaf.tobytes())
+                         for leaf in leaves])
+        if sig != self._hyper_sig:
+            self._hyper_device = _device_tree(host, self._replicated())
+            self._hyper_sig = sig
+            telemetry.counter(
+                "veles_xla_hyper_uploads_total",
+                "Uploads of the hyperparameter tree (a dispatch whose "
+                "host values equal the last upload's makes none)",
+                ("kind",)).labels(kind).inc()
+        return self._hyper_device
+
     def run(self):
         if not self.scan_mode and self._keep_epoch_entry:
             # stream/per-step: the first serve of an epoch sees the
@@ -325,8 +373,9 @@ class XLAStep(Unit):  # zlint: disable=checkpoint-state (params/state/step_index
         will run. Shared by ``_dispatch_epoch`` and the HLO
         introspection path (``lowered_epoch_hlo``) so what gets
         inspected can never drift from what gets executed.
-        Side-effect free: ``peek_epoch_orders`` is cached/idempotent
-        and ``jax.jit(...).lower`` neither executes nor donates."""
+        Repeatable: ``peek_epoch_orders`` is cached/idempotent, the
+        hyperparameters' upload happens once per value, and
+        ``jax.jit(...).lower`` neither executes nor donates."""
         import jax
         loader = self.loader
         if n_epochs is None:
@@ -362,8 +411,7 @@ class XLAStep(Unit):  # zlint: disable=checkpoint-state (params/state/step_index
                 idx_stack = self._pad_batch_dim(idx_stack, 2)
                 idx_stack = jax.device_put(idx_stack, NamedSharding(
                     mesh, PartitionSpec(None, None, axis)))
-                vl = jax.device_put(vl, NamedSharding(
-                    mesh, PartitionSpec()))
+                vl = jax.device_put(vl, self._replicated())
             idxs[seg_key] = idx_stack
             valids[seg_key] = vl
         fn = self.compiler.compile_epoch_scan(
@@ -373,7 +421,7 @@ class XLAStep(Unit):  # zlint: disable=checkpoint-state (params/state/step_index
             self.step_index
             + serves_per_epoch * numpy.arange(n_epochs, dtype=numpy.int64))
         args = (self.params, self.state, full, idxs, valids,
-                self._gather_hyper(), self.base_key, offsets)
+                self._device_hyper("epoch"), self.base_key, offsets)
         return fn, args, n_epochs, serves_per_epoch, classes
 
     def lowered_epoch_hlo(self, optimized=True, n_epochs=1):
@@ -414,6 +462,7 @@ class XLAStep(Unit):  # zlint: disable=checkpoint-state (params/state/step_index
         # live arguments, and donation invalidates them afterwards
         cost = perf.ledger.cost(
             ("epoch", id(fn), n_epochs, serves_per_epoch), fn, args)
+        _record_host_leaves("epoch", args)
         t0 = time.perf_counter()
         self.params, self.state, outs = fn(*args)
         host_outs = _fetch_tree(outs)
@@ -512,7 +561,7 @@ class XLAStep(Unit):  # zlint: disable=checkpoint-state (params/state/step_index
             self._stage_pool = concurrent.futures.ThreadPoolExecutor(
                 max_workers=2, thread_name_prefix="%s-stage" % self.name)
         plan = loader.epoch_plan()
-        hyper = self._gather_hyper()
+        hyper = self._device_hyper("stream")
         w_size = self._window_minibatches()
         spans = []         # (cls, valids_slice, idx_rows)
         for cls, idx_mat, valids in plan:
@@ -551,14 +600,14 @@ class XLAStep(Unit):  # zlint: disable=checkpoint-state (params/state/step_index
                 stage(i + stage_depth)
             key0 = jax.random.fold_in(self.base_key, self.step_index)
             self.step_index += len(valids_w)
+            args = (self.params, self.state, stacked, valids_w, hyper,
+                    key0)
             w_cost = perf.ledger.cost(
-                ("window", id(fn), len(valids_w)), fn,
-                (self.params, self.state, stacked, valids_w, hyper,
-                 key0))
+                ("window", id(fn), len(valids_w)), fn, args)
             epoch_flops += w_cost.flops
             epoch_bytes += w_cost.bytes
-            self.params, self.state, outs = fn(
-                self.params, self.state, stacked, valids_w, hyper, key0)
+            _record_host_leaves("stream", args)
+            self.params, self.state, outs = fn(*args)
             pending.append((cls, outs))
         self._finish_put()
         _drain_pending(pending, outs_per_cls, keep=0)
@@ -611,13 +660,12 @@ class XLAStep(Unit):  # zlint: disable=checkpoint-state (params/state/step_index
         batch = self._gather_batch()
         key = jax.random.fold_in(self.base_key, self.step_index)
         self.step_index += 1
-        hyper = self._gather_hyper()
-        cost = perf.ledger.cost(
-            ("step", id(fn)), fn,
-            (self.params, self.state, batch, hyper, key))
+        args = (self.params, self.state, batch,
+                self._device_hyper("step"), key)
+        cost = perf.ledger.cost(("step", id(fn)), fn, args)
+        _record_host_leaves("step", args)
         t0 = time.perf_counter()
-        params, state, outputs = fn(
-            self.params, self.state, batch, hyper, key)
+        params, state, outputs = fn(*args)
         if train:
             self.params, self.state = params, state
         self._publish_metrics(outputs)
@@ -783,6 +831,9 @@ class XLAStep(Unit):  # zlint: disable=checkpoint-state (params/state/step_index
         """device_put a {unit: {key: array}} tree honouring the
         per-leaf TP sharding map, default param_sharding otherwise."""
         import jax
+        # wherever params/state are placed anew the device or the mesh
+        # may be another one: the kept hyperparameters go with them
+        self._hyper_sig = self._hyper_device = None
         if not self.param_sharding_map:
             return _device_tree(tree, self.param_sharding)
         return {
