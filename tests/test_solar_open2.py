@@ -1,0 +1,495 @@
+"""The pre-norm block at Solar-Open2's layer pattern — gated delta-rule
+linear attention, gated NoPE grouped-query attention, an expert layer
+with a shared expert — against its plain float32 reference,
+``benchmark/reference/solar_open2.py``: each new piece alone, the whole
+LM through ``StandardWorkflow`` (loss, logits, every parameter after
+one step), the chunked recurrence against the token-by-token one where
+``exp(-cumsum(a))`` overflows, the expert layer's shares adding up to
+the uncut layer with the shared expert counted once, the counters and
+scopes, and the accepted units' programs left as the parent traced
+them."""
+
+import hashlib
+import os
+import re
+import sys
+
+import numpy
+import pytest
+
+import veles.prng as prng
+from veles import telemetry
+from veles.accelerated_units import FlowContext
+from veles.config import root
+from veles.znicz_tpu.ops import delta_attention as delta
+from veles.znicz_tpu.ops.delta_attention import DeltaAttention
+from veles.znicz_tpu.ops.expert_ffn import ExpertFFN
+from veles.znicz_tpu.ops.gqa_attention import GQAttention
+
+from tests.test_conv_stack import xla_backward, xla_forward
+from tests.test_lfm2_moe import B, D, S, build
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+from benchmark.reference import solar_open2 as ref   # noqa: E402
+
+#: the tiny preset: d 64; 4 query / 2 K/V heads of 16; 4 delta-rule
+#: heads of 16, gates of rank 8, 4 taps; 8 experts top-2 of width 32
+#: and a shared one of 48; one period; S 64 as four chunks of 16
+MODEL = {"dim": 64, "heads": 4, "kv_heads": 2, "head_dim": 16,
+         "layers": ["gated_nope_attention"] + ["delta_attention"] * 3,
+         "dense_layers": 0, "delta_heads": 4, "delta_head_dim": 16,
+         "delta_conv_kernel": 4, "delta_gate_rank": 8,
+         "moe_hidden": 32, "moe_shared_hidden": 48, "moe_experts": 8,
+         "moe_top_k": 2, "experts_held": [0, 8], "routed_scaling": 1.0,
+         "norm_eps": 1e-5, "vocab": 32, "gradient_moment": 0.9}
+DELTA = dict(heads=4, head_dim=16, kernel=4, gate_rank=8)
+GATED = dict(heads=4, kv_heads=2, rope=False, gate=True, qk_norm=False)
+EXPERT = dict(experts=8, top_k=2, hidden=32, shared_hidden=48)
+
+
+@pytest.fixture(autouse=True)
+def chunks_of_16(monkeypatch):
+    """S = 64 is four chunks: the state crosses chunk boundaries; and
+    4 heads are two groups of 2 run one after the other."""
+    monkeypatch.setattr(delta, "CHUNK", 16)
+    monkeypatch.setattr(delta, "HEADS_AT_ONCE", 2)
+
+
+def reference_block(kind, model):
+    """x (S, d), params -> the unit's output by the reference."""
+    import jax
+
+    def block(p, x):
+        with jax.default_matmul_precision("highest"):
+            if kind == "expert_ffn":
+                return ref.expert_layer(p, x, model)
+            return ref.operator(p, x, kind, model, 16)
+
+    return block
+
+
+def check_unit(cls, kwargs, kind, model=MODEL, tol=2e-5, prepare=None):
+    """Output, input gradient and every parameter's gradient of one
+    unit against ``jax.grad`` of the reference's function."""
+    import jax
+    import jax.numpy as jnp
+    feed, fwd, gd, x, err, comp = build(cls, **kwargs)
+    params0 = comp.gather_params()
+    if prepare:
+        prepare(params0[fwd.name])
+    state0 = comp.gather_state()
+    y = numpy.asarray(xla_forward(comp, feed, fwd, params0, x))
+    dx, params1 = xla_backward(comp, feed, fwd, gd, params0, state0,
+                               x, err)
+    block = reference_block(kind, model)
+    p = {k: jnp.asarray(v) for k, v in params0[fwd.name].items()}
+
+    def total(p, x):
+        out = jax.vmap(lambda row: block(p, row))(x)
+        return (out * err).sum(), out
+
+    (_, want), (gp, gx) = jax.value_and_grad(
+        total, argnums=(0, 1), has_aux=True)(p, jnp.asarray(x))
+    assert numpy.abs(y - numpy.asarray(want)).max() < tol
+    assert numpy.abs(numpy.asarray(dx) - numpy.asarray(gx)).max() \
+        < 10 * tol
+    for name, g in gp.items():
+        moved = numpy.asarray(params0[fwd.name][name]) \
+            - numpy.asarray(params1[fwd.name][name])
+        if name == "expert_bias":       # a buffer: never moves
+            assert not moved.any()
+            continue
+        assert numpy.abs(numpy.asarray(g)).max() > 0, name
+        scale = max(1.0, float(numpy.abs(numpy.asarray(g)).max()))
+        assert numpy.abs(moved - numpy.asarray(g)).max() \
+            < 10 * tol * scale, name
+    return fwd, y
+
+
+def strong_decay(p):
+    """``A = 16`` and ``dt = 1`` on every channel: a chunk's whole
+    decay is exp(-16 x 16 x 1.3), far past float32's range."""
+    p["a_log"][...] = numpy.log(16.0)
+    p["dt_bias"][...] = numpy.log(numpy.expm1(1.0))
+
+
+def steps_near_2(p):
+    """``b = 2 sigmoid(.)`` pushed to 1.9 and beyond: the negative
+    eigenvalues ``kda_allow_neg_eigval`` allows."""
+    p["weights_beta"][...] *= 0.1
+    p["weights_beta"][...] += 3.0 / numpy.sqrt(D) \
+        * numpy.sign(p["norm"])[:, None]
+
+
+UNIT_CASES = {
+    "delta_attention": (DeltaAttention, DELTA, "delta_attention", None),
+    "delta_strong_decay": (DeltaAttention, DELTA, "delta_attention",
+                           strong_decay),
+    "delta_one_chunk": (DeltaAttention, dict(DELTA, heads=2, head_dim=8),
+                        "delta_attention", None),
+    "gated_nope_dense_core": (GQAttention, GATED,
+                              "gated_nope_attention", None),
+    "gated_nope_scan_core": (GQAttention, dict(GATED, attn_block_size=16),
+                             "gated_nope_attention", None),
+    "gated_nope_pallas_core": (GQAttention, dict(
+        GATED, attn_block_size=16, attn_impl="pallas"),
+        "gated_nope_attention", None),
+    "experts_with_shared": (ExpertFFN, EXPERT, "expert_ffn", None),
+    "experts_share_with_shared": (ExpertFFN, dict(
+        EXPERT, experts_held=(2, 6)), "expert_ffn", None),
+    # 2 of 24 held: under an eighth, the backward runs the routed part
+    # again instead of keeping its buffers
+    "experts_thin_share": (ExpertFFN, dict(
+        EXPERT, experts=24, top_k=4, experts_held=(3, 5)), "expert_ffn",
+        None),
+}
+PATCHES = {"delta_one_chunk": {"delta_heads": 2, "delta_head_dim": 8},
+           "experts_share_with_shared": {"experts_held": [2, 6]},
+           "experts_thin_share": {"moe_experts": 24, "moe_top_k": 4,
+                                  "experts_held": [3, 5]}}
+
+
+@pytest.mark.parametrize("case", sorted(UNIT_CASES))
+def test_unit_against_reference(case, monkeypatch):
+    cls, kwargs, kind, prepare = UNIT_CASES[case]
+    if case == "delta_one_chunk":       # and one group of heads
+        monkeypatch.setattr(delta, "CHUNK", 64)
+        monkeypatch.setattr(delta, "HEADS_AT_ONCE", 16)
+    tol = 2e-3 if "pallas" in case else 2e-5    # interpreted kernels
+    check_unit(cls, kwargs, kind, dict(MODEL, **PATCHES.get(case, {})),
+               tol=tol, prepare=prepare)
+
+
+# -- the chunked recurrence against the token-by-token one -------------------
+
+RECURRENCE_CASES = {
+    # (A, dt, b): log-decay -A softplus(. + softplus^-1(dt)), step b
+    "mild": (1.0, 0.05, 1.0),
+    "strong_decay": (16.0, 1.0, 1.0),
+    "steps_near_2": (1.0, 0.01, 1.99),
+    "no_decay_steps_near_2": (0.01, 0.01, 1.99),
+    "strong_decay_steps_near_2": (16.0, 1.0, 1.99),
+}
+
+
+@pytest.mark.parametrize("case", sorted(RECURRENCE_CASES))
+def test_chunks_against_tokens(case):
+    """``delta_rule`` (chunks of 16, pairwise decays) against the
+    reference's scan over tokens: output, final state and the gradient
+    of every input. At ``strong_decay`` the product form
+    ``(exp(G) k)(exp(-G) k)^T`` holds exp(+300) inside one chunk."""
+    import jax
+    import jax.numpy as jnp
+    scale, dt, step = RECURRENCE_CASES[case]
+    gen = numpy.random.RandomState(3)
+    s, h, dk = 64, 3, 8
+
+    def unit(t):
+        return t / numpy.sqrt((t * t).sum(-1, keepdims=True))
+
+    q = unit(gen.randn(s, h, dk)) * dk ** -0.5
+    k, v = unit(gen.randn(s, h, dk)), gen.randn(s, h, dk)
+    a = -scale * numpy.logaddexp(
+        0, gen.randn(s, h, dk) * 0.5 + numpy.log(numpy.expm1(dt)))
+    b = numpy.full((s, h), step)
+    args = [jnp.asarray(t, jnp.float32) for t in (q, k, v, a, b)]
+    weights = jnp.asarray(gen.randn(s, h, dk), jnp.float32)
+    if scale == 16.0:
+        assert -float(a.reshape(4, 16, h, dk).sum(1).min()) > 88.0
+
+    def chunked(*args):
+        o, state = delta.delta_rule(*(t[None] for t in args))
+        return (o[0] * weights).sum() + state.sum(), (o[0], state[0])
+
+    def by_token(*args):
+        o, state = ref.delta_recurrence(*args)
+        return (o * weights).sum() + state.sum(), (o, state)
+
+    with jax.default_matmul_precision("highest"):
+        (_, got), g_got = jax.value_and_grad(
+            chunked, argnums=range(5), has_aux=True)(*args)
+        (_, want), g_want = jax.value_and_grad(
+            by_token, argnums=range(5), has_aux=True)(*args)
+    for mine, theirs in zip(got + g_got, want + g_want):
+        assert numpy.isfinite(numpy.asarray(mine)).all()
+        bound = 2e-5 * max(1.0, float(jnp.abs(theirs).max()))
+        assert float(jnp.abs(mine - theirs).max()) < bound
+
+
+# -- the share sums to the model ---------------------------------------------
+
+
+def test_five_shares_and_one_shared_expert_add_up_to_the_uncut_layer():
+    """40 experts as five shares ``experts_held [8i, 8i + 8)``: the
+    shares' routed parts, with the shared expert counted once, add up
+    to what the reference gives for the layer that holds all 40."""
+    import jax
+    wide = dict(EXPERT, experts=40, top_k=8)
+    model = dict(MODEL, moe_experts=40, moe_top_k=8, experts_held=[0, 40])
+    feed, fwd, _, x, _, comp = build(ExpertFFN, **wide)
+    params = comp.gather_params()[fwd.name]
+    whole = numpy.asarray(jax.vmap(lambda row: reference_block(
+        "expert_ffn", model)(params, row))(x)) - x
+
+    def part(lo, hi, shared):
+        feed, fwd, _, _, _, comp = build(
+            ExpertFFN, **dict(wide, experts_held=(lo, hi),
+                              shared_hidden=48 if shared else 0))
+        mine = dict(params, weights13=params["weights13"][lo:hi],
+                    weights2=params["weights2"][lo:hi])
+        if not shared:
+            del mine["shared13"], mine["shared2"]
+        y = xla_forward(comp, feed, fwd, {fwd.name: mine}, x,
+                        train=False)
+        return numpy.asarray(y) - x
+
+    routed = [part(lo, lo + 8, False) for lo in range(0, 40, 8)]
+    assert all(numpy.abs(p).max() > 1e-3 for p in routed)
+    with_shared = part(0, 8, True)
+    shared = with_shared - routed[0]
+    assert numpy.abs(shared).max() > 1e-3
+    assert numpy.abs(sum(routed) + shared - whole).max() < 3e-5
+    # every chip adding its own copy of the shared expert is NOT the
+    # layer
+    assert numpy.abs(sum(routed) + 5 * shared - whole).max() > 1e-3
+
+
+# -- the whole LM ------------------------------------------------------------
+
+
+@pytest.fixture
+def tiny_lm():
+    from veles.znicz_tpu.models import transformer_lm as T
+    saved = {k: getattr(root.lm, k).to_dict()
+             for k in ("loader", "model", "train", "decision")}
+    root.lm.loader.update({"minibatch_size": 1, "n_train": 1,
+                           "n_valid": 1, "seq_len": S, "vocab": 32,
+                           "max_period": 40})
+    root.lm.model.update(dict(
+        {k: v for k, v in MODEL.items()
+         if k not in ("vocab", "gradient_moment", "routed_scaling")},
+        block="pre_norm", attn_block=16, moe_scaling=1.0))
+    root.lm.train.update({"learning_rate": 0.5, "gradient_moment": 0.9})
+    root.lm.decision.update({"max_epochs": 1})
+    prng.seed_all(5)
+    try:
+        yield T
+    finally:
+        for k, v in saved.items():
+            getattr(root.lm, k).update(v)
+
+
+def exported(wf):
+    return [(type(u).MAPPING, u.export_params()) for u in wf.forwards]
+
+
+def test_lm_loss_logits_and_one_step_against_reference(tiny_lm):
+    """The program trained through StandardWorkflow / xla_step for one
+    step: first validation loss, train loss, the logits, and every
+    parameter after the step, against the reference's walk over the
+    sub-layers + momentum SGD; and that walk against ``jax.grad`` of
+    the reference's whole loss."""
+    import jax
+    wf = tiny_lm.create_workflow()
+    wf.initialize(device="cpu")
+    loader = wf.loader
+    data, labels = loader.original_data.mem, loader.original_labels.mem
+    valid = (data[:1].copy(), labels[:1].copy())
+    train = (data[1:2].copy(), labels[1:2].copy())
+    tree = ref.from_program(exported(wf), MODEL)
+    assert ref.count_parameters(MODEL) == sum(
+        a.size for a in jax.tree_util.tree_leaves(tree)) - 4 * 8
+    comp = wf.xla_step.compiler
+
+    def logits(p, tokens):
+        ctx = FlowContext(comp, dict(p), {}, {}, jax.random.PRNGKey(0),
+                          False)
+        ctx.set(wf.loader, "minibatch_data", tokens)
+        for unit in wf.forwards:
+            unit.xla_run(ctx)
+        return ctx.get(wf.forwards[-1], "output")
+
+    got = numpy.asarray(jax.jit(logits)(comp.gather_params(), valid[0]))
+
+    def whole(tree, tokens, labels):
+        with jax.default_matmul_precision("highest"):
+            return ref.sequence_loss(tree, tokens, labels, MODEL, 16)
+
+    value, want_grads = jax.value_and_grad(whole)(
+        tree, train[0][0], train[1][0])
+    walked, grads = ref.gradients(tree, train, MODEL)
+    assert abs(walked - float(value) / S) < 1e-5
+    for (path, mine), theirs in zip(
+            jax.tree_util.tree_leaves_with_path(grads),
+            jax.tree_util.tree_leaves(want_grads)):
+        theirs = numpy.asarray(theirs) / S
+        assert numpy.abs(mine - theirs).max() \
+            < 1e-5 * max(1.0, numpy.abs(theirs).max()), path
+
+    wf.run()
+    history = wf.decision.history
+    assert abs(history[0]["validation"]["loss"]
+               - ref.loss(tree, valid, MODEL)) < 1e-5
+    assert numpy.isfinite(got).all() and got.shape == (1, S, 32)
+    after, losses = ref.train(tree, [train], MODEL, 0.5, 0.9)
+    assert abs(history[0]["train"]["loss"] - losses[0]) < 1e-5
+    stepped = ref.from_program(exported(wf), MODEL)
+    flat = jax.tree_util.tree_leaves_with_path
+    moved = 0
+    for (path, new), (_, want), (_, old) in zip(
+            flat(stepped), flat(after), flat(tree)):
+        change = numpy.abs(want - old).max()
+        assert numpy.abs(new - want).max() < 1e-5 + 1e-3 * change, path
+        moved += change > 0
+    # everything but the four (zero) selection biases took a step
+    assert moved == len(flat(tree)) - 4
+
+
+def test_counters_and_gauges_ride_the_metric_fetch(tiny_lm):
+    wf = tiny_lm.create_workflow()
+    wf.initialize(device="cpu")
+    wf.run()
+    registry = telemetry.get_registry()
+    layers = [u.name for u in wf.forwards
+              if isinstance(u, DeltaAttention)]
+    assert len(layers) == 3
+    for layer in layers:
+        assert registry.counter_total("veles_delta_steps_total",
+                                      layer=layer) == 1
+        assert registry.counter_total("veles_delta_tokens_total",
+                                      layer=layer) == S
+    text = registry.render_prometheus()
+
+    def gauges(name):
+        return [float(line.split()[-1]) for line in text.splitlines()
+                if line.startswith(name + "{")]
+
+    assert all(0.0 < g < 1.0 for g in gauges("veles_delta_decay_mean"))
+    assert all(0.5 < g < 1.5 for g in gauges("veles_delta_beta_mean"))
+    rms = gauges("veles_delta_state_rms")
+    assert len(rms) == 3 and all(0.0 < g < 10.0 for g in rms)
+    # the expert counters keep their names: 8 of 8 held, every pair
+    for unit in wf.forwards:
+        if isinstance(unit, ExpertFFN):
+            assert registry.counter_total(
+                "veles_moe_pairs_total", layer=unit.name) == S * 2
+    assert registry.counter_total("veles_moe_dropped_pairs_total") == 0
+
+
+def test_step_program_names_the_new_scopes(tiny_lm):
+    """``veles.delta`` inside both delta-rule units and nowhere else,
+    ``veles.shared`` inside the expert units beside ``veles.experts``
+    and ``veles.route``, ``veles.core`` in the softmax attention
+    alone."""
+    wf = tiny_lm.create_workflow()
+    wf.initialize(device="cpu")
+    fn, args, _, _, _ = wf.xla_step._epoch_program(1)
+    text = fn.lower(*args).as_text(debug_info=True)
+    for unit in (r"veles\.fwd\.DeltaAttention\.DeltaAttention",
+                 r"veles\.bwd\.GDDeltaAttention\.GDDeltaAttention"):
+        assert re.search(unit + r'[^"]*?[/(]veles\.delta[/)]', text), unit
+        assert not re.search(unit + r'[^"/]*/[^"]*veles\.core', text)
+    for unit in (r"veles\.fwd\.ExpertFFN\.ExpertFFN",
+                 r"veles\.bwd\.GDExpertFFN\.GDExpertFFN"):
+        found = set(re.findall(
+            unit + r'[^"/]*/[^"]*?[/(]veles\.(experts|route|shared)[/)]',
+            text))
+        assert found == {"experts", "route", "shared"}, unit
+    assert "veles.fwd.GQAttention.GQAttention/veles.core" in text
+    assert not re.search(r"GQAttention[^\"]*veles\.delta", text)
+    # the shared expert lies outside the routed scopes
+    assert not re.search(r"veles\.(experts|route)[^\"]*veles\.shared",
+                         text)
+
+
+def test_numpy_device_refuses_the_new_units(tiny_lm):
+    wf = tiny_lm.create_workflow()
+    with pytest.raises(NotImplementedError, match="no numpy oracle"):
+        wf.initialize(device="numpy")
+        wf.run()
+
+
+@pytest.mark.parametrize("patch,message", [
+    ({"layers": ["delta_attention", "window"]},
+     "has the operators 'conv', 'delta_attention', 'full_attention', "
+     "'plain_attention', 'gated_nope_attention', got"),
+    ({"layers": ["delta_attention"], "dense_layers": 1, "ut_steps": 2,
+      "ffn_hidden": 32}, "no delta_attention"),
+    ({"layers": ["delta_attention"], "dense_layers": 1,
+      "norm": "sandwich", "ffn_hidden": 32}, "norm='sandwich'"),
+])
+def test_build_layers_refuses(tiny_lm, patch, message):
+    root.lm.model.update(patch)
+    with pytest.raises(ValueError, match=message):
+        tiny_lm.build_layers()
+
+
+def test_builder_hands_every_stated_constant_to_the_new_units(tiny_lm):
+    root.lm.model.update({"delta_conv_kernel": 3, "norm_eps": 1e-4})
+    by_type = {}
+    for layer in tiny_lm.build_layers():
+        by_type.setdefault(layer["type"], layer["->"])
+    assert by_type["delta_attention"] == {
+        "heads": 4, "head_dim": 16, "kernel": 3, "gate_rank": 8,
+        "eps": 1e-4}
+    gated = by_type["gqa_attention"]
+    assert (gated["rope"], gated["gate"], gated["qk_norm"]) \
+        == (False, True, False)
+    assert by_type["expert_ffn"]["shared_hidden"] == 48
+
+
+# -- the accepted units trace the programs the parent traced -----------------
+
+#: sha256 of the StableHLO text of one unit pair's forward + backward +
+#: update on the fixed inputs of ``build``, traced by the PARENT of PR 34
+#: (commit 0497976) in this container's jax: the same program gives the
+#: same bits on any backend. A PR that changes what these units compute
+#: by default changes these on purpose.
+PARENT_PROGRAMS = {
+    "gqa_attention": "7e75d6438b243c03560289867b6b2a064bcbdf762742c54f04084430e48e9b39",
+    "gqa_plain_sandwich": "2c2dffde543f1542746c4d5467470cd54aa406b5faba87d0ed59bfe68ba81bcd",
+    "expert_ffn": "66579489f03160d6833a59a68d41d1076aa2b01fc83cb4de6546a44880cb0e53",
+}
+ACCEPTED = {
+    "gqa_attention": (GQAttention, dict(heads=4, kv_heads=2),
+                      dict(gate=False, rope=True)),
+    "gqa_plain_sandwich": (GQAttention, dict(
+        heads=4, kv_heads=4, qk_norm=False, sandwich=True),
+        dict(gate=False, rope=True)),
+    "expert_ffn": (ExpertFFN, dict(experts=8, top_k=2, hidden=32,
+                                   bias_stddev=0.05),
+                   dict(shared_hidden=0)),
+}
+
+
+def program_text(cls, kwargs):
+    import jax
+    feed, fwd, gd, x, err, comp = build(cls, **kwargs)
+
+    def fn(p, s, xv, ev):
+        ctx = FlowContext(comp, dict(p), dict(s),
+                          {gd.name: gd.hyperparams()},
+                          jax.random.PRNGKey(7), True)
+        ctx.set(feed, "minibatch_data", xv)
+        fwd.xla_run(ctx)
+        ctx.set(gd, "err_output", ev)
+        gd.xla_run(ctx)
+        return (ctx.get(fwd, "output"),
+                ctx.values.get((gd.name, "err_input")), ctx.params)
+
+    return jax.jit(fn).lower(comp.gather_params(), comp.gather_state(),
+                             x, err).as_text()
+
+
+@pytest.mark.parametrize("case", sorted(ACCEPTED))
+def test_defaults_leave_the_accepted_units_as_the_parent_traced_them(
+        case):
+    """``gate=False, rope=True`` and ``shared_hidden=0``, said aloud or
+    left to the defaults, trace the program the parent traced: the
+    outputs are bit-equal on every input."""
+    cls, kwargs, said = ACCEPTED[case]
+    default = program_text(cls, kwargs)
+    assert program_text(cls, dict(kwargs, **said)) == default
+    assert hashlib.sha256(default.encode()).hexdigest() \
+        == PARENT_PROGRAMS[case]

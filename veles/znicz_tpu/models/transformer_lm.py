@@ -58,8 +58,14 @@ root.lm.update({
     # moe_hidden (sigmoid scores times moe_scaling, selection biases
     # drawn at moe_bias_stddev), of which this job holds experts_held =
     # [lo, hi) (None: all; the rest live on other chips and their part
-    # of the sum is left out). pre_norm's "plain_attention" is
-    # "full_attention" without the q/k norm. norm: "pre" (one gain
+    # of the sum is left out), beside a shared expert of width
+    # moe_shared_hidden that every token passes (0: none). pre_norm's
+    # "plain_attention" is "full_attention" without the q/k norm, its
+    # "gated_nope_attention" that without rotary positions and with an
+    # elementwise sigmoid output gate, and "delta_attention" a gated
+    # delta-rule linear-attention layer (ops/delta_attention.py) of
+    # delta_heads heads of delta_head_dim, delta_conv_kernel taps and
+    # gates of rank delta_gate_rank. norm: "pre" (one gain
     # before each sub-layer) or "sandwich" (a second gain on the
     # sub-layer's output, before the residual add). ut_steps > 1 runs
     # the layers and the final norm ut_steps times in a row over the
@@ -82,7 +88,10 @@ root.lm.update({
               "experts_held": None, "moe_scaling": 1.0,
               "moe_bias_stddev": 0.0, "conv_kernel": 3,
               "rope_theta": 1e6, "norm_eps": 1e-5, "norm": "pre",
-              "ut_steps": 1, "exit_entropy_weight": 0.0},
+              "ut_steps": 1, "exit_entropy_weight": 0.0,
+              "moe_shared_hidden": 0, "delta_heads": None,
+              "delta_head_dim": None, "delta_conv_kernel": 4,
+              "delta_gate_rank": None},
     "train": {"learning_rate": 0.05, "gradient_moment": 0.9,
               "weights_decay": 0.0},
     "decision": {"max_epochs": 8, "fail_iterations": 50},
@@ -232,8 +241,14 @@ def layer_operators(m):
     return list(layers)
 
 
-#: pre_norm's attention operators: is there an RMS norm on q and k
-ATTENTION_OPERATORS = {"full_attention": True, "plain_attention": False}
+#: pre_norm's attention operators: what each changes of ``GQAttention``
+ATTENTION_OPERATORS = {
+    "full_attention": {},
+    "plain_attention": {"qk_norm": False},
+    "gated_nope_attention": {"qk_norm": False, "rope": False,
+                             "gate": True}}
+#: every operator a pre_norm layer can have
+PRE_NORM_OPERATORS = ("conv", "delta_attention") + tuple(ATTENTION_OPERATORS)
 
 
 def loop_passes(m):
@@ -250,10 +265,10 @@ def pre_norm_body(m, t):
     > 1 each layer dict says its place in the loop (``"loop"``: a
     layer's operator starts a recomputed segment)."""
     operators = layer_operators(m)
-    if set(operators) - {"conv"} - set(ATTENTION_OPERATORS):
-        raise ValueError("block='pre_norm' has the operators 'conv', "
-                         "'full_attention' and 'plain_attention' (no "
-                         "q/k norm), got %r" % (operators,))
+    if set(operators) - set(PRE_NORM_OPERATORS):
+        raise ValueError("block='pre_norm' has the operators %s, got %r"
+                         % (", ".join(map(repr, PRE_NORM_OPERATORS)),
+                            operators))
     norm = m.get("norm", "pre")
     if norm not in ("pre", "sandwich"):
         raise ValueError("norm is 'pre' or 'sandwich', got %r" % (norm,))
@@ -268,12 +283,14 @@ def pre_norm_body(m, t):
             "(at ut_steps=1) data-parallel; not with stacked=%r / "
             "parallel %r" % (m.get("stacked"), busy))
     dense = m.get("dense_layers", 0) >= len(operators)
-    if looped and not dense:
+    if looped and not (dense and "delta_attention" not in operators):
         raise ValueError(
             "ut_steps=%r loops layers with a SwiGLU feed-forward "
-            "(dense_layers = the layers' number): the expert layer's "
-            "counters are one visit's" % (steps,))
-    if norm == "sandwich" and ("conv" in operators or not dense):
+            "(dense_layers = the layers' number) and no delta_attention: "
+            "the expert and delta-rule layers' counters are one visit's"
+            % (steps,))
+    if norm == "sandwich" and not (
+            dense and set(operators) <= set(ATTENTION_OPERATORS)):
         raise ValueError("norm='sandwich' is for attention layers over "
                          "a SwiGLU feed-forward")
     eps = m.get("norm_eps", 1e-5)
@@ -285,16 +302,25 @@ def pre_norm_body(m, t):
                 "type": "short_conv",
                 "->": {"kernel": m.get("conv_kernel", 3), "eps": eps},
                 "<-": dict(t), "loop": "segment"})
+        elif kind == "delta_attention":
+            body.append({
+                "type": "delta_attention",
+                "->": {"heads": m.get("delta_heads") or m.heads,
+                       "head_dim": m.get("delta_head_dim")
+                       or m.get("head_dim"),
+                       "kernel": m.get("delta_conv_kernel", 4),
+                       "gate_rank": m.get("delta_gate_rank"),
+                       "eps": eps},
+                "<-": dict(t), "loop": "segment"})
         else:
-            plain = {} if ATTENTION_OPERATORS[kind] \
-                else {"qk_norm": False}
             body.append({
                 "type": "gqa_attention",
                 "->": dict(attention_kernel_keys(m), heads=m.heads,
                            kv_heads=m.get("kv_heads"),
                            head_dim=m.get("head_dim"),
                            rope_theta=m.get("rope_theta", 1e6),
-                           eps=eps, **plain, **sandwich),
+                           eps=eps, **ATTENTION_OPERATORS[kind],
+                           **sandwich),
                 "<-": dict(t), "loop": "segment"})
         if index < m.get("dense_layers", 0):
             body.append({"type": "swiglu_ffn",
@@ -310,6 +336,7 @@ def pre_norm_body(m, t):
                        "experts_held": m.get("experts_held"),
                        "scaling": m.get("moe_scaling", 1.0),
                        "bias_stddev": m.get("moe_bias_stddev", 0.0),
+                       "shared_hidden": m.get("moe_shared_hidden", 0),
                        "eps": eps},
                 "<-": dict(t)})
     body.append({"type": "rms_norm", "->": {"eps": eps}, "<-": dict(t),

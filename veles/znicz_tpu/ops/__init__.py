@@ -66,6 +66,9 @@ from veles.znicz_tpu.ops.swiglu import SwiGLUFFN, GDSwiGLUFFN  # noqa: F401
 from veles.znicz_tpu.ops.gqa_attention import (  # noqa: F401
     GQAttention, GDGQAttention,
 )
+from veles.znicz_tpu.ops.delta_attention import (  # noqa: F401
+    DeltaAttention, GDDeltaAttention,
+)
 from veles.znicz_tpu.ops.exit_gate import (  # noqa: F401
     ExitGate, GDExitGate,
 )

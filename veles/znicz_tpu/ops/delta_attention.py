@@ -1,0 +1,308 @@
+"""Gated delta-rule linear attention (Kimi Delta Attention), the
+operator of a ``delta_attention`` layer, as a pre-norm residual block
+over (B, S, d): ``heads`` heads, each carrying a ``dk x dv`` state
+along the sequence (``dk = dv = head_dim``):
+
+    n      = rms(x; g)
+    q,k,v  = silu(conv(n W_q)), silu(conv(n W_k)), silu(conv(n W_v))
+                                            conv depthwise, causal
+    q, k   = q / |q|_2 * dk^-1/2,  k / |k|_2          per head and token
+    a_t    = -exp(A_log_h) * softplus((n_t W_f1) W_f2 + dt_bias)
+                                            a log-decay <= 0 a channel
+    b_t    = 2 * sigmoid(n_t W_b)           one a head, in (0, 2)
+    S_t    = (I - b_t k_t k_t^T) diag(exp(a_t)) S_{t-1} + b_t k_t v_t^T
+    o_t    = S_t^T q_t                      S float32, S_0 = 0
+    y      = x + (rms_head(o_t; g_o) * sigmoid((n_t W_g1) W_g2 + c_g)) W_o
+
+``weights`` holds ``[W_q | W_k | W_v]`` and ``conv`` their taps, so the
+three projections are one product and one pass of taps
+(``ShortConv``'s shifted-sum form). ``|t|_2`` is
+``sqrt(sum t^2 + 1e-6)``.
+
+The recurrence proper (:func:`delta_rule`) runs under
+``veles.delta``, in float32, ``CHUNK`` tokens at a time. With
+``G_t = a_1 + ... + a_t`` inside a chunk that starts from the state
+``S_0``, and ``E_ts = exp(G_t - G_s)`` (a vector over the ``dk``
+channels, taken for ``s <= t`` only, where it is at most 1):
+
+    A_ts = sum_c k_t k_s E_ts  (s < t),   B_ts = sum_c q_t k_s E_ts (s <= t)
+    (I + diag(b) A) [W_v | W_k] = diag(b) [V | exp(G) * K]
+    U    = W_v - W_k S_0
+    O    = (exp(G) * Q) S_0 + B U
+    S_C  = diag(exp(G_C)) S_0 + (exp(G_C - G) * K)^T U
+
+``A`` and ``B`` are made chunk by chunk from the PAIRWISE differences
+``G_t - G_s``: the product form ``(exp(G) k)(exp(-G) k)^T`` overflows
+float32 inside one chunk as soon as ``exp(A_log) = 16`` meets a
+softplus near 1. The triangular system (the delta rule inside a chunk)
+is solved for all chunks at once, it does not depend on the state; the
+three lines that do are a ``lax.scan`` over the chunks that carries
+``S``. The backward keeps ``q, k, v, a, b`` and runs all of this again
+(:func:`~veles.znicz_tpu.ops.vjp_units.recomputed`), and inside that
+each loop keeps its carries and a chunk's inputs alone
+(``jax.checkpoint`` on the bodies): ``S / CHUNK`` states of ``heads x
+dk x dv`` float32 a layer, alive in that layer's backward only.
+
+Counters, on the step's metric fetch: ``veles_delta_tokens_total
+{layer}``, ``veles_delta_steps_total{layer}``; gauges of the last
+training step ``veles_delta_decay_mean{layer}`` (mean ``exp(a)``: which
+numeric regime the chunks are in), ``veles_delta_beta_mean{layer}`` and
+``veles_delta_state_rms{layer}`` (the final state's root mean square: a
+recurrence that blows up under the negative eigenvalues ``b > 1``
+allows shows here before the loss does).
+"""
+
+import numpy
+
+from veles import telemetry
+from veles.znicz_tpu.nn_units import forward_unit, gradient_for
+from veles.znicz_tpu.ops.vjp_units import (
+    GDVjp, Products, VjpForward, recomputed, rms_norm)
+
+#: tokens a chunk of :func:`delta_rule` holds
+CHUNK = 64
+#: heads :func:`delta_rule` runs at a time. At 64 heads of 128 x 128
+#: and S = 4096 the chunks' terms, the saved states and their
+#: cotangents are 2.5 GB for all heads at once, in the middle of a
+#: backward that still holds every earlier layer's residuals
+#: (``benchmark/rehearse.py``, PR 34)
+HEADS_AT_ONCE = 8
+
+
+def chunk_scores(q, k, g):
+    """(A, B) of one chunk, each (..., C, C) and zero above the
+    diagonal: the decayed products of a token's k (A; the solve reads
+    it below the diagonal alone) and q (B) with its own and every
+    earlier token's k. ``q, k, g``: (..., C, dk)."""
+    import jax.numpy as jnp
+    c = g.shape[-2]
+    later = jnp.arange(c)[:, None] >= jnp.arange(c)[None, :]
+    # masked BEFORE the exponential: above the diagonal the difference
+    # is positive and as large as the chunk's whole decay
+    decay = jnp.exp(jnp.where(
+        later[:, :, None], g[..., :, None, :] - g[..., None, :, :],
+        -jnp.inf))
+    pairs = decay * k[..., None, :, :]
+    a = (pairs * k[..., :, None, :]).sum(-1)
+    b = (pairs * q[..., :, None, :]).sum(-1)
+    return a, b
+
+
+def delta_rule(q, k, v, a, beta):
+    """The recurrence above for ``q, k, a`` (B, S, H, dk), ``v``
+    (B, S, H, dv), ``beta`` (B, S, H), float32, from a zero state;
+    -> (o (B, S, H, dv), the final state (B, H, dk, dv)). The heads do
+    not meet: ``HEADS_AT_ONCE`` of them run at a time, one group after
+    the other (a ``lax.map`` whose backward keeps a group's inputs
+    alone), so the chunks' terms and the saved states of one group are
+    alive at a time, not the layer's."""
+    import jax
+    b, s, h, _ = q.shape
+    groups = h // HEADS_AT_ONCE if h % HEADS_AT_ONCE == 0 else 1
+    if groups == 1:
+        return chunked_delta_rule(q, k, v, a, beta)
+
+    def split(t):       # (B, S, H, w) -> (G, B, S, H / G, w)
+        return t.reshape(b, s, groups, h // groups, -1) \
+            .transpose(2, 0, 1, 3, 4)
+
+    o, state = jax.lax.map(
+        jax.checkpoint(
+            lambda x: chunked_delta_rule(*x[:4], x[4][..., 0]),
+            prevent_cse=False),
+        tuple(split(t) for t in (q, k, v, a, beta[..., None])))
+    return (o.transpose(1, 2, 0, 3, 4).reshape(b, s, h, -1),
+            state.transpose(1, 0, 2, 3, 4).reshape((b, h)
+                                                   + state.shape[3:]))
+
+
+def chunked_delta_rule(q, k, v, a, beta):
+    """:func:`delta_rule` for all the heads it is given, in chunks of
+    ``CHUNK`` tokens (one chunk where that does not divide S)."""
+    import jax
+    import jax.numpy as jnp
+    f32 = jnp.float32
+    exact = jax.lax.Precision.HIGHEST
+    b, s, h, dk = q.shape
+    dv = v.shape[-1]
+    c = CHUNK if s % CHUNK == 0 else s
+    n = s // c
+
+    def chunks(t):      # (B, S, H, w) -> (N, B, H, C, w)
+        return t.reshape(b, n, c, h, -1).transpose(1, 0, 3, 2, 4)
+
+    q, k, v, a = (chunks(t.astype(f32)) for t in (q, k, v, a))
+    beta = chunks(beta.astype(f32)[..., None])
+    g = jnp.cumsum(a, axis=-2)
+    scores, reads = jax.lax.map(
+        jax.checkpoint(lambda x: chunk_scores(*x), prevent_cse=False),
+        (q, k, g))
+    grown = jnp.exp(g)
+    w = jax.lax.linalg.triangular_solve(
+        beta * scores, beta * jnp.concatenate([v, grown * k], -1),
+        left_side=True, lower=True, unit_diagonal=True)
+    last = g[..., -1:, :]
+
+    @jax.checkpoint
+    def step(state, x):
+        w_v, w_k, q_in, read, k_out, keep = x
+        u = w_v - jnp.matmul(w_k, state, precision=exact)
+        o = jnp.matmul(q_in, state, precision=exact) \
+            + jnp.matmul(read, u, precision=exact)
+        state = keep * state + jnp.matmul(
+            k_out.swapaxes(-1, -2), u, precision=exact)
+        return state, o
+
+    state, o = jax.lax.scan(
+        step, jnp.zeros((b, h, dk, dv), f32),
+        (w[..., :dv], w[..., dv:], grown * q, reads,
+         jnp.exp(last - g) * k, jnp.exp(last).swapaxes(-1, -2)))
+    return o.transpose(1, 0, 3, 2, 4).reshape(b, s, h, dv), state
+
+
+@forward_unit("delta_attention")
+class DeltaAttention(VjpForward):
+    PARAMS = ("weights", "conv", "weights_decay_in", "weights_decay_out",
+              "weights_beta", "a_log", "dt_bias", "weights_gate_in",
+              "weights_gate_out", "gate_bias", "weights_out", "norm",
+              "norm_out")
+    HAS_AUX = True
+
+    def __init__(self, workflow, heads=4, head_dim=None, kernel=4,
+                 gate_rank=None, eps=1e-5, **kwargs):
+        super().__init__(workflow, **kwargs)
+        self.heads = int(heads)
+        self.head_dim = head_dim
+        self.kernel = int(kernel)
+        self.gate_rank = gate_rank
+        self.eps = float(eps)
+
+    @staticmethod
+    def fill_a_log(gen, mem):
+        """``A = exp(a_log)`` uniform in [1, 16)."""
+        gen.fill_uniform(mem, 1.0, 16.0)
+        numpy.log(mem, out=mem)
+
+    @staticmethod
+    def fill_dt_bias(gen, mem):
+        """``softplus(dt_bias) = dt``, log-uniform in [1e-3, 1e-1)."""
+        gen.fill_uniform(mem, numpy.log(1e-3), numpy.log(1e-1))
+        numpy.exp(mem, out=mem)
+        mem += numpy.log(-numpy.expm1(-mem))
+
+    def param_specs(self, ishape):
+        d = ishape[-1]
+        dh = self.head_dim = int(self.head_dim or d // self.heads)
+        wide = self.heads * dh
+        rank = self.gate_rank = int(self.gate_rank or dh)
+        return {"weights": ((d, 3 * wide), (d, wide)),
+                "conv": ((3 * wide, self.kernel), (self.kernel, 1)),
+                "weights_decay_in": ((d, rank), (d, rank)),
+                "weights_decay_out": ((rank, wide), (rank, wide)),
+                "weights_beta": ((d, self.heads), (d, self.heads)),
+                "a_log": ((self.heads,), self.fill_a_log),
+                "dt_bias": ((wide,), self.fill_dt_bias),
+                "weights_gate_in": ((d, rank), (d, rank)),
+                "weights_gate_out": ((rank, wide), (rank, wide)),
+                "gate_bias": ((wide,), "zeros"),
+                "weights_out": ((wide, d), (wide, d)),
+                "norm": ((d,), "ones"),
+                "norm_out": ((dh,), "ones")}
+
+    def apply(self, ctx, p, x):
+        import jax
+        import jax.numpy as jnp
+        mm = Products(ctx)
+        f32 = jnp.float32
+        b, s, _ = x.shape
+        h, dh, taps = self.heads, self.head_dim, self.kernel
+
+        # The backward keeps the normed input, the two gates' low ranks
+        # and the recurrence's output, and makes everything between
+        # them again, the three projections too.
+        @recomputed
+        def mix(n, low, beta, w, conv, w_decay, a_log, dt_bias):
+            padded = jnp.pad(mm.dot(n, w, f32),
+                             ((0, 0), (taps - 1, 0), (0, 0)))
+            qkv = jax.nn.silu(sum(conv[:, j] * padded[:, j:j + s]
+                                  for j in range(taps)))
+            q, k, v = (t.reshape(b, s, h, dh)
+                       for t in jnp.split(qkv, 3, axis=-1))
+
+            def unit(t):
+                return t * jax.lax.rsqrt(
+                    (t * t).sum(-1, keepdims=True) + 1e-6)
+
+            a = -jnp.exp(a_log)[:, None] * jax.nn.softplus(
+                (mm.dot(low, w_decay, f32) + dt_bias).reshape(b, s, h, dh))
+            beta = 2.0 * jax.nn.sigmoid(beta)
+            with jax.named_scope("veles.delta"):
+                o, state = delta_rule(unit(q) * dh ** -0.5, unit(k), v,
+                                      a, beta)
+            state = jax.lax.stop_gradient(state)
+            return o.astype(mm.act), {
+                "decay": jnp.exp(jax.lax.stop_gradient(a)).mean(),
+                "beta": jax.lax.stop_gradient(beta).mean(),
+                "state_rms": jnp.sqrt((state * state).mean())}
+
+        @recomputed
+        def gated(o, low, w_gate, gate_bias, gain):
+            gate = jax.nn.sigmoid(mm.dot(low, w_gate, f32) + gate_bias)
+            o = rms_norm(o.reshape(b, s, h, dh), gain, self.eps)
+            return (o.reshape(b, s, h * dh) * gate).astype(mm.cd)
+
+        n = rms_norm(x, p["norm"], self.eps)
+        o, aux = mix(
+            n.astype(mm.cd), mm.dot(n, p["weights_decay_in"]),
+            mm.dot(n, p["weights_beta"], f32), p["weights"], p["conv"],
+            p["weights_decay_out"], p["a_log"], p["dt_bias"])
+        out = mm.dot(
+            gated(o, mm.dot(n, p["weights_gate_in"]),
+                  p["weights_gate_out"], p["gate_bias"], p["norm_out"]),
+            p["weights_out"], f32)
+        return x.astype(f32) + out, aux
+
+    # -- counters ----------------------------------------------------------
+
+    #: the aux outputs of ``apply``, a gauge each
+    AUX = ("decay", "beta", "state_rms")
+
+    def export_aux(self, ctx, aux):
+        for key, value in aux.items():
+            ctx.export("delta_%s_%s" % (key, self.name), value)
+
+    def metric_sinks(self):
+        return [("delta_%s_%s" % (key, self.name), "step_" + key)
+                for key in self.AUX]
+
+    def set_gauge(self, name, what, value):
+        telemetry.gauge(name, "Last step of a delta-rule layer: " + what,
+                        ("layer",)).labels(self.name).set(value)
+
+    def metrics_published(self, fresh):
+        """``XLAStep``'s hook, once a training step's sinks are filled."""
+        if "step_decay" not in fresh:
+            return
+        telemetry.counter(
+            "veles_delta_tokens_total", "Tokens a delta-rule layer's "
+            "recurrence ran over, training steps", ("layer",)
+        ).labels(self.name).inc(self.input.size // self.input.shape[-1])
+        telemetry.counter(
+            "veles_delta_steps_total", "Training steps a delta-rule "
+            "layer ran", ("layer",)).labels(self.name).inc()
+        self.set_gauge("veles_delta_decay_mean", "mean exp(a), the decay "
+                       "a channel takes a token", self.step_decay)
+        self.set_gauge("veles_delta_beta_mean", "mean b, the delta "
+                       "rule's step in (0, 2)", self.step_beta)
+        self.set_gauge("veles_delta_state_rms", "root mean square of the "
+                       "final state", self.step_state_rms)
+
+
+@gradient_for(DeltaAttention)
+class GDDeltaAttention(GDVjp):
+    EXTRA_PARAMS = (
+        ("conv", True), ("weights_decay_in", False),
+        ("weights_decay_out", False), ("weights_beta", False),
+        ("a_log", True), ("dt_bias", True), ("weights_gate_in", False),
+        ("weights_gate_out", False), ("gate_bias", True),
+        ("weights_out", False), ("norm", True), ("norm_out", True))

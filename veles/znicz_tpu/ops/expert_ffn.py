@@ -15,6 +15,11 @@ experts would add is left out — on one chip's share of an
 expert-parallel deployment that partial sum is the layer's result, and
 nothing here stands in for the other chips or their exchange.
 
+With ``shared_hidden`` > 0 the layer also has ONE SHARED expert of
+that width, a SwiGLU every token passes whatever the router says,
+``y = x + swiglu(n; W13_s, W2_s) + sum ...``: it is held whole on
+every chip, outside the routed sum and its scaling.
+
 No token is dropped and there is no capacity: the token-expert pairs
 are sorted, held experts first and by expert, into a buffer of all
 ``T x k`` rows (the worst case: every pair on a held expert). The
@@ -35,9 +40,10 @@ are one grouped product. Gradients reach the router through ``p``
 alone.
 
 Scopes inside the unit's own: ``veles.route`` (router, top-k, sort,
-dispatch, gating, weighting, combine) and ``veles.experts`` (the grouped
-products). Counters, advanced when the step's metrics are replayed on
-the host (they ride the metric fetch the step makes anyway):
+dispatch, gating, weighting, combine), ``veles.experts`` (the grouped
+products) and ``veles.shared`` (the shared expert, outside both).
+Counters, advanced when the step's metrics are replayed on the host
+(they ride the metric fetch the step makes anyway):
 ``veles_moe_pairs_total{layer}``, ``veles_moe_steps_total{layer}``,
 ``veles_moe_rows_touched_total{layer}`` (rows the row stages
 processed: trips x chunk, read from the loop itself; over
@@ -56,7 +62,7 @@ from veles import telemetry
 from veles.znicz_tpu.nn_units import forward_unit, gradient_for
 from veles.znicz_tpu.ops.swiglu import swiglu
 from veles.znicz_tpu.ops.vjp_units import (
-    GDVjp, Products, VjpForward, rms_norm)
+    GDVjp, Products, VjpForward, recomputed, rms_norm)
 
 
 #: rows a trip of :func:`over_prefix` handles (the largest divisor of
@@ -215,12 +221,15 @@ class ExpertFFN(VjpForward):
 
     def __init__(self, workflow, experts=None, top_k=1, hidden=None,
                  experts_held=None, scaling=1.0, eps=1e-5,
-                 bias_stddev=0.0, **kwargs):
+                 bias_stddev=0.0, shared_hidden=0, **kwargs):
         """``scaling``: the model's routed scaling factor.
         ``bias_stddev``: the selection biases are drawn normal at this
         deviation when the unit initialises (0: they start at zero, as
         a fresh model's do); a snapshot or a test sets the buffer
         itself."""
+        self.shared_hidden = int(shared_hidden or 0)
+        if self.shared_hidden:
+            self.PARAMS = type(self).PARAMS + ("shared13", "shared2")
         super().__init__(workflow, **kwargs)
         if not (experts and hidden):
             raise ValueError("expert_ffn needs experts and hidden")
@@ -232,6 +241,11 @@ class ExpertFFN(VjpForward):
             raise ValueError("experts_held %r, top_k %d of %d experts"
                              % (experts_held, self.top_k, self.experts))
         self.held = (int(lo), int(hi))
+        #: under an eighth of the pair buffer's rows are expected to be
+        #: real (a token's top_k experts, the share of them held here):
+        #: keeping the buffers for the backward costs more than making
+        #: them again
+        self.sparse = 8 * (hi - lo) < self.experts
         self.scaling = float(scaling)
         self.eps = float(eps)
         self.expert_bias_stddev = float(bias_stddev)
@@ -240,11 +254,16 @@ class ExpertFFN(VjpForward):
     def param_specs(self, ishape):
         d, f, e = ishape[-1], self.hidden, self.experts
         n = self.held[1] - self.held[0]
-        return {"weights": ((d, e), (d, e)),
-                "weights13": ((n, d, 2 * f), (d, f)),
-                "weights2": ((n, f, d), (f, d)),
-                "norm": ((d,), "ones"),
-                "expert_bias": ((e,), self.expert_bias_stddev or "zeros")}
+        specs = {"weights": ((d, e), (d, e)),
+                 "weights13": ((n, d, 2 * f), (d, f)),
+                 "weights2": ((n, f, d), (f, d)),
+                 "norm": ((d,), "ones"),
+                 "expert_bias": ((e,), self.expert_bias_stddev or "zeros")}
+        if self.shared_hidden:
+            fs = self.shared_hidden
+            specs.update(shared13=((d, 2 * fs), (d, fs)),
+                         shared2=((fs, d), (fs, d)))
+        return specs
 
     # -- the math --------------------------------------------------------
 
@@ -320,17 +339,42 @@ class ExpertFFN(VjpForward):
             with jax.named_scope("veles.experts"):
                 return mm.grouped_dot(act, w2, sizes)
 
-        out = down(up(n.astype(mm.cd), p["weights13"]), p["weights2"])
-        with jax.named_scope("veles.route"):
+        def weighted(out, weight):
             # a weight past the real pairs is masked for its gradient's
             # sake: the weighting's pullback writes the prefix alone
-            out, touched = weigh(rows, out, jnp.where(
-                jnp.arange(order.size) < rows,
-                weight.reshape(-1)[order], 0))
-            y = combine(out, order, inv, rows).astype(f32)
+            with jax.named_scope("veles.route"):
+                out, touched = weigh(rows, out, jnp.where(
+                    jnp.arange(order.size) < rows,
+                    weight.reshape(-1)[order], 0))
+                return combine(out, order, inv, rows).astype(f32), touched
+
+        if self.sparse:
+            # the (T x k)-row buffers are mostly air: the backward keeps
+            # none of them and runs the held experts' part again
+            @recomputed
+            def routed(tokens, w13, w2, weight):
+                return weighted(down(up(tokens, w13), w2), weight)[0]
+
+            y = routed(n.astype(mm.cd), p["weights13"], p["weights2"],
+                       weight)
+            chunk = math.gcd(order.size, CHUNK)
+            touched = (rows + chunk - 1) // chunk * chunk
+        else:
+            y, touched = weighted(
+                down(up(n.astype(mm.cd), p["weights13"]), p["weights2"]),
+                weight)
+        with jax.named_scope("veles.route"):
             aux = {"pairs": rows, "max_load": sizes.max(),
                    "dropped": misplaced_pairs(local, held, inv, sizes),
                    "touched": touched}
+        if self.shared_hidden:
+            @jax.checkpoint     # as SwiGLUFFN: the backward gates again
+            def shared_down(h13, w2):
+                return mm.dot(swiglu(h13).astype(mm.cd), w2, f32)
+
+            with jax.named_scope("veles.shared"):
+                y = y + shared_down(mm.dot(n, p["shared13"]),
+                                    p["shared2"])
         return x.astype(f32) + y.reshape(x.shape), aux
 
     # -- counters ----------------------------------------------------------
@@ -396,4 +440,5 @@ class ExpertFFN(VjpForward):
 @gradient_for(ExpertFFN)
 class GDExpertFFN(GDVjp):
     EXTRA_PARAMS = (("weights13", False), ("weights2", False),
-                    ("norm", True))
+                    ("norm", True), ("shared13", False),
+                    ("shared2", False))

@@ -13,7 +13,12 @@ That is the pre-norm placement, one gain before the sub-layer. With
 ``sandwich`` the sub-layer's float32 output takes a second gain before
 the residual add, ``y = x + rms(merge(...) W_o; g_out)``; with
 ``qk_norm=False`` q and k go to the rotation as projected (no ``g_q``,
-``g_k``).
+``g_k``); with ``rope=False`` there is no rotation and no position
+anywhere (no tables are built); with ``gate=True`` the merged heads
+pass an elementwise sigmoid gate read from the normed input,
+``y = x + (merge(...) * sigmoid(n W_gate)) W_o``, and ``W_gate``
+(d x heads dh) is the last columns of ``W_qkv``: one product makes
+q, k, v and the gate.
 
 The projections, norms and rotation are ``jax.vjp`` of their trace
 (``ops/vjp_units.py``). The attention proper is the repo's own — the
@@ -71,10 +76,12 @@ class GQAttention(VjpForward, MultiHeadAttention):
 
     def __init__(self, workflow, heads=4, kv_heads=None, head_dim=None,
                  rope_theta=1e6, eps=1e-5, qk_norm=True, sandwich=False,
-                 **kwargs):
+                 rope=True, gate=False, **kwargs):
         kwargs.setdefault("residual", True)
         self.qk_norm = bool(qk_norm)
         self.sandwich = bool(sandwich)
+        self.rope = bool(rope)
+        self.gate = bool(gate)
         self.PARAMS = ("weights", "weights_out", "norm") \
             + (("q_norm", "k_norm") if self.qk_norm else ()) \
             + (("norm_out",) if self.sandwich else ())
@@ -90,7 +97,7 @@ class GQAttention(VjpForward, MultiHeadAttention):
     def param_specs(self, ishape):
         d = ishape[-1]
         dh = self.head_dim = int(self.head_dim or d // self.heads)
-        wide = (self.heads + 2 * self.kv_heads) * dh
+        wide = (self.heads * (1 + self.gate) + 2 * self.kv_heads) * dh
         specs = {"weights": ((d, wide), (d, wide)),
                  "weights_out": ((self.heads * dh, d),
                                  (self.heads * dh, d)),
@@ -114,28 +121,32 @@ class GQAttention(VjpForward, MultiHeadAttention):
     # -- the three stages ------------------------------------------------
 
     def project(self, ctx, p, x):
-        """x -> q, k, v, each (B, heads, S, dh) in the compute type."""
+        """x -> q, k, v, each (B, heads, S, dh) in the compute type,
+        and with ``gate`` the gate's input (B, S, heads x dh)."""
         import jax.numpy as jnp
         mm = Products(ctx)
         b, s, _ = x.shape
         h, kv, dh = self.heads, self.kv_heads, self.head_dim
         qkv = mm.dot(rms_norm(x, p["norm"], self.eps), p["weights"])
-        q, k, v = jnp.split(qkv, [h * dh, (h + kv) * dh], axis=-1)
+        q, k, v, *gate = jnp.split(
+            qkv, [h * dh, (h + kv) * dh, (h + 2 * kv) * dh][:2 + self.gate],
+            axis=-1)
 
         def heads(t, n):
             return t.reshape(b, s, n, dh).transpose(0, 2, 1, 3)
 
-        cos, sin = rope_tables(s, dh, self.rope_theta)
+        if self.rope:
+            cos, sin = rope_tables(s, dh, self.rope_theta)
 
         def turned(t, n, gain):
             t = heads(t, n)
             if self.qk_norm:
                 t = rms_norm(t, p[gain], self.eps)
-            return rope(t, cos, sin)
+            return rope(t, cos, sin) if self.rope else t
 
         q, k = turned(q, h, "q_norm"), turned(k, kv, "k_norm")
         k, v = (repeat_heads(t, h // kv) for t in (k, heads(v, kv)))
-        return tuple(t.astype(mm.cd) for t in (q, k, v))
+        return tuple(t.astype(mm.cd) for t in (q, k, v)) + tuple(gate)
 
     def attend(self, ctx, mode, q, k, v):
         """-> (context by head, what the core's backward wants):
@@ -156,8 +167,12 @@ class GQAttention(VjpForward, MultiHeadAttention):
         return dense_attention_core_bwd(
             jnp, q, k, v, saved[0], dctx, self.scale, ctx.dot)
 
-    def finish(self, ctx, p, merged):
+    def finish(self, ctx, p, merged, *gate):
+        import jax
         import jax.numpy as jnp
+        if gate:
+            merged = (merged.astype(jnp.float32) * jax.nn.sigmoid(
+                gate[0].astype(jnp.float32))).astype(merged.dtype)
         out = Products(ctx).dot(merged, p["weights_out"], jnp.float32)
         if self.sandwich:
             out = rms_norm(out, p["norm_out"], self.eps)
@@ -174,13 +189,13 @@ class GQAttention(VjpForward, MultiHeadAttention):
         def project(pre, x):
             return self.project(ctx, pre, x)
 
-        def finish(post, merged):
-            return self.finish(ctx, post, merged)
+        def finish(post, merged, *gate):
+            return self.finish(ctx, post, merged, *gate)
 
-        q, k, v = self.traced(ctx, project, pre, x)
+        q, k, v, *gate = self.traced(ctx, project, pre, x)
         with _core_scope():
             out, saved = self.attend(ctx, mode, q, k, v)
-        y = self.traced(ctx, finish, post, self._merge(out))
+        y = self.traced(ctx, finish, post, self._merge(out), *gate)
         ctx.set(self, "output",
                 (x.astype(jnp.float32) + y).astype(ctx.act_dtype))
         if ctx.train:
@@ -198,13 +213,14 @@ class GDGQAttention(GDVjp):
         f = self.forward
         x = ctx.get(f, "input")
         err = ctx.get(self, "err_output").reshape(x.shape)
-        grads, dmerged = self.pull(ctx, "finish", err.astype(jnp.float32))
+        grads, dmerged, *dgate = self.pull(ctx, "finish",
+                                           err.astype(jnp.float32))
         mode, q, k, v, saved = ctx.get(f, "core")
         with _core_scope():
             dq, dk, dv = f.attend_bwd(ctx, mode, q, k, v, saved,
                                       f._split(dmerged))
         pre, dx = self.pull(ctx, "project", tuple(
-            t.astype(q.dtype) for t in (dq, dk, dv)))
+            t.astype(q.dtype) for t in (dq, dk, dv)) + tuple(dgate))
         if self.need_err_input:
             ctx.set(self, "err_input",
                     (dx.astype(jnp.float32) + err).astype(ctx.act_dtype))

@@ -39,6 +39,28 @@ def rms_norm(x, gain, eps):
         * gain
 
 
+def recomputed(fn):
+    """``fn`` (arrays in, arrays out) whose backward runs it again:
+    nothing of its inside lives from the forward to the backward, as
+    under ``jax.checkpoint``, and the repeated forward waits behind an
+    ``optimization_barrier`` for the cotangent it meets. Left free,
+    XLA runs every recomputation of a step first and holds all their
+    residuals at once (``znicz_tpu/loop.py``; PERF.md section 6,
+    PR 32)."""
+    import jax
+
+    @jax.custom_vjp
+    def run(*args):
+        return fn(*args)
+
+    def backward(args, cotangent):
+        args, cotangent = jax.lax.optimization_barrier((args, cotangent))
+        return jax.vjp(fn, *args)[1](cotangent)
+
+    run.defvjp(lambda *args: (fn(*args), args), backward)
+    return run
+
+
 @functools.lru_cache(maxsize=None)
 def _products(cd):
     """(dot, grouped_dot) for the compute dtype ``cd``."""
@@ -156,8 +178,8 @@ class VjpForward(Forward):
 
     def param_specs(self, ishape):
         """{name: (shape, filling)}: filling is ``"ones"``, ``"zeros"``,
-        the (fan_in, fan_out) a Glorot-scaled draw takes, or a float
-        standard deviation of a normal draw."""
+        the (fan_in, fan_out) a Glorot-scaled draw takes, a float
+        standard deviation of a normal draw, or ``fill(prng, mem)``."""
         raise NotImplementedError
 
     def initialize(self, device=None, **kwargs):
@@ -174,6 +196,8 @@ class VjpForward(Forward):
                 arr.mem[...] = 1.0
             elif isinstance(filling, float):
                 self.fill_array(arr, "gaussian", filling)
+            elif callable(filling):
+                filling(self.prng, arr.mem)
             else:
                 self.fill_array(
                     arr, self.weights_filling, self.weights_stddev
