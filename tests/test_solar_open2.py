@@ -173,30 +173,46 @@ RECURRENCE_CASES = {
 }
 
 
+#: (S, CHUNK, BLOCK): chunk 16 as one block (the pairwise form over the
+#: whole chunk), chunk 16 in blocks of 4, and the cell's 64 / 16
+RECURRENCE_SHAPES = {"one_block": (64, 16, 16), "blocks_of_4": (64, 16, 4),
+                     "cell_64_16": (128, 64, 16)}
+
+
+def decays(gen, shape, scale, dt):
+    """Log-decays ``-A softplus(. + softplus^-1(dt))``."""
+    return -scale * numpy.logaddexp(
+        0, gen.randn(*shape) * 0.5 + numpy.log(numpy.expm1(dt)))
+
+
+def unit_rows(t):
+    return t / numpy.sqrt((t * t).sum(-1, keepdims=True))
+
+
+@pytest.mark.parametrize("shape", sorted(RECURRENCE_SHAPES))
 @pytest.mark.parametrize("case", sorted(RECURRENCE_CASES))
-def test_chunks_against_tokens(case):
-    """``delta_rule`` (chunks of 16, pairwise decays) against the
+def test_chunks_against_tokens(case, shape, monkeypatch):
+    """``delta_rule`` (chunks cut into blocks: pairwise decays inside a
+    block, products of two factors <= 1 below it) against the
     reference's scan over tokens: output, final state and the gradient
     of every input. At ``strong_decay`` the product form
     ``(exp(G) k)(exp(-G) k)^T`` holds exp(+300) inside one chunk."""
     import jax
     import jax.numpy as jnp
     scale, dt, step = RECURRENCE_CASES[case]
+    s, chunk, block = RECURRENCE_SHAPES[shape]
+    monkeypatch.setattr(delta, "CHUNK", chunk)
+    monkeypatch.setattr(delta, "BLOCK", block)
     gen = numpy.random.RandomState(3)
-    s, h, dk = 64, 3, 8
-
-    def unit(t):
-        return t / numpy.sqrt((t * t).sum(-1, keepdims=True))
-
-    q = unit(gen.randn(s, h, dk)) * dk ** -0.5
-    k, v = unit(gen.randn(s, h, dk)), gen.randn(s, h, dk)
-    a = -scale * numpy.logaddexp(
-        0, gen.randn(s, h, dk) * 0.5 + numpy.log(numpy.expm1(dt)))
+    h, dk = 3, 8
+    q = unit_rows(gen.randn(s, h, dk)) * dk ** -0.5
+    k, v = unit_rows(gen.randn(s, h, dk)), gen.randn(s, h, dk)
+    a = decays(gen, (s, h, dk), scale, dt)
     b = numpy.full((s, h), step)
     args = [jnp.asarray(t, jnp.float32) for t in (q, k, v, a, b)]
     weights = jnp.asarray(gen.randn(s, h, dk), jnp.float32)
     if scale == 16.0:
-        assert -float(a.reshape(4, 16, h, dk).sum(1).min()) > 88.0
+        assert -float(a.reshape(-1, 16, h, dk).sum(1).min()) > 88.0
 
     def chunked(*args):
         o, state = delta.delta_rule(*(t[None] for t in args))
@@ -215,6 +231,95 @@ def test_chunks_against_tokens(case):
         assert numpy.isfinite(numpy.asarray(mine)).all()
         bound = 2e-5 * max(1.0, float(jnp.abs(theirs).max()))
         assert float(jnp.abs(mine - theirs).max()) < bound
+
+
+def cell_chunk(mild_channels, c=64, dk=128):
+    """q, k, G of one chunk at the cell's widths under ``strong_decay``
+    (float64): a block's whole decay is past float32's range. The last
+    ``mild_channels`` channels decay mildly instead: scores far below
+    the diagonal that are not zero."""
+    gen = numpy.random.RandomState(11)
+    q = unit_rows(gen.randn(c, dk)) * dk ** -0.5
+    k = unit_rows(gen.randn(c, dk))
+    a = decays(gen, (c, dk), 16.0, 1.0)
+    if mild_channels:
+        a[:, -mild_channels:] = decays(gen, (c, mild_channels), 1.0, 0.01)
+    g = numpy.cumsum(a, 0)
+    assert -g[15, :dk - mild_channels].min() > 88.0
+    return q, k, g
+
+
+@pytest.mark.parametrize("mild_channels", [0, 64])
+def test_chunk_scores_at_the_cells_widths_against_float64(mild_channels):
+    """C 64, dk 128 in blocks of 16: finite, exactly zero above the
+    diagonal, within 1e-6 of the pairwise sum in float64, and the
+    gradients of ``q, k, g`` finite."""
+    import jax
+    import jax.numpy as jnp
+    assert (delta.BLOCK, delta.block_of(64)) == (16, 16)
+    q, k, g = cell_chunk(mild_channels)
+    later = numpy.tril(numpy.ones((64, 64), bool))
+    decay = numpy.exp(numpy.where(
+        later[:, :, None], g[:, None, :] - g[None, :, :], -numpy.inf))
+    want_a = (decay * k[None, :, :] * k[:, None, :]).sum(-1)
+    want_b = (decay * k[None, :, :] * q[:, None, :]).sum(-1)
+    args = [jnp.asarray(t, jnp.float32) for t in (q, k, g)]
+    for got, want in zip(delta.chunk_scores(*args), (want_a, want_b)):
+        got = numpy.asarray(got)
+        assert numpy.isfinite(got).all()
+        assert not numpy.triu(got, 1).any()
+        assert numpy.abs(got - want).max() <= 1e-6
+        # below the diagonal blocks: the products' part
+        assert (numpy.abs(numpy.tril(got, -16)).max() > 1e-3) \
+            == bool(mild_channels)
+
+    def total(*args):
+        a, b = delta.chunk_scores(*args)
+        return (a * a).sum() + (b * numpy.arange(64.0)).sum()
+
+    for grad in jax.grad(total, argnums=(0, 1, 2))(*args):
+        assert numpy.isfinite(numpy.asarray(grad)).all()
+        assert numpy.abs(numpy.asarray(grad)).max() > 0
+
+
+def intermediate_sizes(jaxpr):
+    """The element count of every value a jaxpr makes, inner jaxprs
+    included."""
+    import jax
+    for eqn in jaxpr.eqns:
+        for var in eqn.outvars:
+            yield int(numpy.prod(var.aval.shape, dtype=numpy.int64))
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            yield from intermediate_sizes(inner)
+
+
+@pytest.mark.parametrize("c,block", [
+    (64, 16),       # the cell's chunk: four blocks
+    (72, 72),       # 16 does not divide it: one block
+    (16, 16),       # a chunk of one block
+])
+def test_pairwise_tensor_is_a_diagonal_block(c, block):
+    """The mechanism itself: where the chunk is cut into blocks no
+    value of ``chunk_scores`` has ``C x C x dk`` elements — the largest
+    is one block's pairwise tensor, and there is one a block — and
+    where it falls back to one block the old tensor is there."""
+    import jax
+    import jax.numpy as jnp
+    assert delta.BLOCK == 16 and delta.block_of(c) == block
+
+    def sizes(c):
+        spec = jax.ShapeDtypeStruct((2, c, 128), jnp.float32)
+        out = jax.eval_shape(delta.chunk_scores, spec, spec, spec)
+        assert [t.shape for t in out] == [(2, c, c)] * 2
+        closed = jax.make_jaxpr(delta.chunk_scores)(spec, spec, spec)
+        return list(intermediate_sizes(closed.jaxpr))
+
+    pairwise = 2 * block * block * 128
+    made = sizes(c)
+    assert max(made) == pairwise
+    # as many such tensors a block as a chunk of one block makes
+    assert made.count(pairwise) \
+        == c // block * sizes(block).count(pairwise)
 
 
 # -- the share sums to the model ---------------------------------------------
@@ -359,6 +464,9 @@ def test_counters_and_gauges_ride_the_metric_fetch(tiny_lm):
                                       layer=layer) == 1
         assert registry.counter_total("veles_delta_tokens_total",
                                       layer=layer) == S
+        # S = 64 in chunks of 16, which are one block of 16 each
+        assert registry.counter_total(
+            "veles_delta_pairwise_pairs_total", layer=layer) == S * 16
     text = registry.render_prometheus()
 
     def gauges(name):

@@ -11,6 +11,7 @@ import os
 import re
 import subprocess
 import sys
+import time
 import urllib.request
 
 import numpy
@@ -491,7 +492,13 @@ def test_metrics_json_keeps_pre_registry_shape():
         assert set(m0) == GOLDEN_BATCHER_KEYS - {
             "latency_ms_p50", "latency_ms_p99"}
         b.predict(numpy.zeros((2, 3), numpy.float32))
+        # the worker wakes the caller BEFORE it counts the batch and
+        # observes its latency: give it a moment
+        deadline = time.monotonic() + 5.0
         m = b.metrics()
+        while "latency_ms_p50" not in m and time.monotonic() < deadline:
+            time.sleep(0.01)
+            m = b.metrics()
         assert set(m) == GOLDEN_BATCHER_KEYS
         assert m["requests_total"] == 1
         assert isinstance(m["requests_total"], int)

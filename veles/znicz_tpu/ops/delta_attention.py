@@ -31,22 +31,46 @@ channels, taken for ``s <= t`` only, where it is at most 1):
     O    = (exp(G) * Q) S_0 + B U
     S_C  = diag(exp(G_C)) S_0 + (exp(G_C - G) * K)^T U
 
-``A`` and ``B`` are made chunk by chunk from the PAIRWISE differences
-``G_t - G_s``: the product form ``(exp(G) k)(exp(-G) k)^T`` overflows
-float32 inside one chunk as soon as ``exp(A_log) = 16`` meets a
-softplus near 1. The triangular system (the delta rule inside a chunk)
-is solved for all chunks at once, it does not depend on the state; the
-three lines that do are a ``lax.scan`` over the chunks that carries
-``S``. The backward keeps ``q, k, v, a, b`` and runs all of this again
+``A`` and ``B`` are made chunk by chunk (:func:`chunk_scores`), the
+chunk cut into blocks of ``BLOCK`` tokens. With ``n(t)`` the first
+token of ``t``'s block and ``r_t = G_{n(t) - 1}`` (0 in the chunk's
+first block):
+
+    s >= n(t):  E_ts = exp(G_t - G_s)                 pairwise
+    s <  n(t):  E_ts = exp(G_t - r_t) * exp(r_t - G_s)
+                A_ts = (k_t exp(G_t - r_t)) . (k_s exp(r_t - G_s))
+
+Every ``a`` is <= 0, so ``G`` falls along the chunk and ``G_t <= r_t
+<= G_s`` there: both factors lie in (0, 1], nothing overflows, and
+where one underflows to 0 the pair's true decay is below float32's
+range too. It is the same sum over the channels in the same float32,
+taken as a product at ``Precision.HIGHEST`` (``B`` likewise, with
+``q_t``); only pairs inside one block, with no boundary between them,
+need the (w, w, dk) tensor of differences, masked before the
+exponential. The product form with ONE reference for a whole chunk,
+``(exp(G) k)(exp(-G) k)^T``, does overflow inside a chunk as soon as
+``exp(A_log) = 16`` meets a softplus near 1: its second factor is not
+bounded. Where ``BLOCK`` does not divide the chunk (a sequence that is
+one chunk of an odd length) the chunk is one block.
+
+The triangular system (the delta rule inside a chunk) is solved for
+all chunks at once, it does not depend on the state; the three lines
+that do are a ``lax.scan`` over the chunks that carries ``S``. The
+backward keeps ``q, k, v, a, b`` and runs all of this again
 (:func:`~veles.znicz_tpu.ops.vjp_units.recomputed`), and inside that
 each loop keeps its carries and a chunk's inputs alone
 (``jax.checkpoint`` on the bodies): ``S / CHUNK`` states of ``heads x
 dk x dv`` float32 a layer, alive in that layer's backward only.
 
 Counters, on the step's metric fetch: ``veles_delta_tokens_total
-{layer}``, ``veles_delta_steps_total{layer}``; gauges of the last
-training step ``veles_delta_decay_mean{layer}`` (mean ``exp(a)``: which
-numeric regime the chunks are in), ``veles_delta_beta_mean{layer}`` and
+{layer}``, ``veles_delta_steps_total{layer}``,
+``veles_delta_pairwise_pairs_total{layer}`` (pairs of tokens whose
+decay was taken pairwise, tokens x the block width in use: over tokens
+x ``CHUNK`` the share of a chunk's square still made of differences,
+1/4 at 64 / 16, 1 where a sequence fell back to one block); gauges of
+the last training step ``veles_delta_decay_mean{layer}`` (mean
+``exp(a)``: which numeric regime the chunks are in),
+``veles_delta_beta_mean{layer}`` and
 ``veles_delta_state_rms{layer}`` (the final state's root mean square: a
 recurrence that blows up under the negative eigenvalues ``b > 1``
 allows shows here before the loss does).
@@ -61,6 +85,17 @@ from veles.znicz_tpu.ops.vjp_units import (
 
 #: tokens a chunk of :func:`delta_rule` holds
 CHUNK = 64
+#: tokens a block of a chunk holds: :func:`chunk_scores` takes the
+#: decay of a pair of tokens pairwise inside a block alone
+BLOCK = 16
+#: chunks :func:`chunked_delta_rule` makes the scores of at a time:
+#: four chunks' diagonal blocks are as many lanes as ONE chunk's
+#: (C, C, dk) tensor was, so a trip holds what it held. A quarter of
+#: the loop trips: a row of blocks is some forty small operations, and
+#: at one chunk a trip a traced step held so many more device
+#: operations than before the blocks that the host's memory ran out
+#: reading the trace (PERF.md section 6, PR 35)
+CHUNKS_AT_ONCE = 4
 #: heads :func:`delta_rule` runs at a time. At 64 heads of 128 x 128
 #: and S = 4096 the chunks' terms, the saved states and their
 #: cotangents are 2.5 GB for all heads at once, in the middle of a
@@ -69,16 +104,27 @@ CHUNK = 64
 HEADS_AT_ONCE = 8
 
 
-def chunk_scores(q, k, g):
-    """(A, B) of one chunk, each (..., C, C) and zero above the
-    diagonal: the decayed products of a token's k (A; the solve reads
-    it below the diagonal alone) and q (B) with its own and every
-    earlier token's k. ``q, k, g``: (..., C, dk)."""
+def chunk_of(s):
+    """Tokens a chunk of a sequence of ``s`` tokens holds: ``CHUNK``,
+    or the whole sequence where that does not divide it."""
+    return CHUNK if s % CHUNK == 0 else s
+
+
+def block_of(c):
+    """Tokens a block of a chunk of ``c`` tokens holds: ``BLOCK``, or
+    the whole chunk where that does not divide it."""
+    return BLOCK if c % BLOCK == 0 else c
+
+
+def pairwise_scores(q, k, g):
+    """:func:`chunk_scores` of tokens that form one block: every
+    pair's decay from the difference ``G_t - G_s``, a (w, w, dk)
+    tensor."""
     import jax.numpy as jnp
-    c = g.shape[-2]
-    later = jnp.arange(c)[:, None] >= jnp.arange(c)[None, :]
+    w = g.shape[-2]
+    later = jnp.arange(w)[:, None] >= jnp.arange(w)[None, :]
     # masked BEFORE the exponential: above the diagonal the difference
-    # is positive and as large as the chunk's whole decay
+    # is positive and as large as the block's whole decay
     decay = jnp.exp(jnp.where(
         later[:, :, None], g[..., :, None, :] - g[..., None, :, :],
         -jnp.inf))
@@ -86,6 +132,46 @@ def chunk_scores(q, k, g):
     a = (pairs * k[..., :, None, :]).sum(-1)
     b = (pairs * q[..., :, None, :]).sum(-1)
     return a, b
+
+
+def chunk_scores(q, k, g):
+    """(A, B) of one chunk, each (..., C, C) and zero above the
+    diagonal: the decayed products of a token's k (A; the solve reads
+    it below the diagonal alone) and q (B) with its own and every
+    earlier token's k. ``q, k, g``: (..., C, dk), float32.
+
+    The chunk is cut into blocks of :func:`block_of` tokens, and the
+    scores are made a row of blocks at a time: the block on the
+    diagonal pairwise (:func:`pairwise_scores`), everything before it
+    as ONE float32 product of the block's tokens, grown from ``G`` at
+    the last token before the block, with the earlier tokens decayed
+    to it: both factors at most 1. A chunk of one block is the pairwise
+    form of the whole chunk."""
+    import jax
+    import jax.numpy as jnp
+    c = g.shape[-2]
+    w = block_of(c)
+    rows = []
+    for start in range(0, c, w):
+        q_own, k_own, g_own = (t[..., start:start + w, :]
+                               for t in (q, k, g))
+        parts = [pairwise_scores(q_own, k_own, g_own)]
+        if start:
+            # every token of the block lies after the reference, every
+            # earlier one before it or on it: both differences are <= 0
+            ref = g[..., start - 1:start, :]
+            grown = jnp.exp(g_own - ref)
+            earlier = k[..., :start, :] * jnp.exp(ref - g[..., :start, :])
+            below = jnp.matmul(
+                jnp.concatenate([grown * k_own, grown * q_own], -2),
+                earlier.swapaxes(-1, -2),
+                precision=jax.lax.Precision.HIGHEST)
+            parts.insert(0, (below[..., :w, :], below[..., w:, :]))
+        if start + w < c:
+            above = jnp.zeros(g.shape[:-2] + (w, c - start - w), g.dtype)
+            parts.append((above, above))
+        rows.append([jnp.concatenate(side, -1) for side in zip(*parts)])
+    return tuple(jnp.concatenate(side, -2) for side in zip(*rows))
 
 
 def delta_rule(q, k, v, a, beta):
@@ -125,7 +211,7 @@ def chunked_delta_rule(q, k, v, a, beta):
     exact = jax.lax.Precision.HIGHEST
     b, s, h, dk = q.shape
     dv = v.shape[-1]
-    c = CHUNK if s % CHUNK == 0 else s
+    c = chunk_of(s)
     n = s // c
 
     def chunks(t):      # (B, S, H, w) -> (N, B, H, C, w)
@@ -136,7 +222,7 @@ def chunked_delta_rule(q, k, v, a, beta):
     g = jnp.cumsum(a, axis=-2)
     scores, reads = jax.lax.map(
         jax.checkpoint(lambda x: chunk_scores(*x), prevent_cse=False),
-        (q, k, g))
+        (q, k, g), batch_size=CHUNKS_AT_ONCE)
     grown = jnp.exp(g)
     w = jax.lax.linalg.triangular_solve(
         beta * scores, beta * jnp.concatenate([v, grown * k], -1),
@@ -283,10 +369,17 @@ class DeltaAttention(VjpForward):
         """``XLAStep``'s hook, once a training step's sinks are filled."""
         if "step_decay" not in fresh:
             return
+        tokens = self.input.size // self.input.shape[-1]
         telemetry.counter(
             "veles_delta_tokens_total", "Tokens a delta-rule layer's "
             "recurrence ran over, training steps", ("layer",)
-        ).labels(self.name).inc(self.input.size // self.input.shape[-1])
+        ).labels(self.name).inc(tokens)
+        telemetry.counter(
+            "veles_delta_pairwise_pairs_total", "Pairs of tokens whose "
+            "decay a delta-rule layer took pairwise (tokens x the block "
+            "width in use), training steps", ("layer",)
+        ).labels(self.name).inc(
+            tokens * block_of(chunk_of(self.input.shape[-2])))
         telemetry.counter(
             "veles_delta_steps_total", "Training steps a delta-rule "
             "layer ran", ("layer",)).labels(self.name).inc()
