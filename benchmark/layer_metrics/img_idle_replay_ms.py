@@ -1,0 +1,3 @@
+"""``idle_replay_ms`` of a cell judged on ``train_images_per_s``."""
+
+from benchmark.layer_metrics.idle_replay_ms import read  # noqa: F401

@@ -348,6 +348,9 @@ class Launcher(Logger):
             self._preemption_exit()
         if self.stats:
             wf.print_stats(sys.stderr)
+            step = getattr(wf, "xla_step", None)
+            if step is not None:
+                step.print_dispatch_phases(sys.stderr)
         return wf
 
     def _preemption_exit(self):

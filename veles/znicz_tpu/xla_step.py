@@ -17,6 +17,10 @@ sharding, so data parallelism falls out of XLA auto-partitioning with
 collectives over ICI.
 """
 
+import collections
+import functools
+import itertools
+import sys
 import time
 
 import numpy
@@ -41,6 +45,60 @@ def _record_dispatch(kind, warm, start, dt, **args):
         telemetry.tracer.add_complete(
             "xla.dispatch.%s" % kind, start, dt,
             warm=bool(warm), **args)
+
+
+#: the phases that tile the host's time from one dispatch to the next
+PHASES = ("build", "launch", "fetch", "replay")
+#: one ordinal a dispatch, unique in the process as the flight recorder
+#: is: a parent span and its phase spans carry the same one
+_ORDINALS = itertools.count()
+
+
+def _record_phase(kind, phase, start, dt, **args):
+    """One phase of a dispatch, as ``xla.dispatch.<kind>.<phase>`` in
+    the tracer (``dispatch``: the parent span's ordinal) and in the
+    histogram an operator scrapes: where the host's time between two
+    steps goes, without a trace."""
+    telemetry.histogram(
+        "veles_xla_dispatch_phase_seconds",
+        "Host wall time of one dispatch by phase: build (arguments and "
+        "program look-up), launch (the jit call), fetch (wait for the "
+        "device, one packed transfer), replay (serving the chunk's "
+        "minibatches until the next dispatch)",
+        ("kind", "phase")).labels(kind, phase).observe(dt)
+    if telemetry.tracer.active:
+        telemetry.tracer.add_complete(
+            "xla.dispatch.%s.%s" % (kind, phase), start, dt, **args)
+
+
+#: jax's duration event around every backend compile, a persistent
+#: cache's load included (``jax._src.dispatch.BACKEND_COMPILE_EVENT``)
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+def _on_compile(event, seconds, **_):
+    if event == _COMPILE_EVENT:
+        telemetry.counter(
+            "veles_xla_compilations_total",
+            "Backend compilations of the process, loads from the "
+            "persistent cache included").inc()
+        telemetry.counter(
+            "veles_xla_compile_seconds_total",
+            "Seconds inside those compilations and loads").inc(seconds)
+
+
+@functools.cache
+def _count_compilations():
+    """Register the compile listener; cached, so once a process: jax
+    keeps its listeners for the process's life, whatever registry is
+    active."""
+    import jax.monitoring
+    jax.monitoring.register_event_duration_secs_listener(_on_compile)
+
+
+def _compilations():
+    return telemetry.get_registry().counter_total(
+        "veles_xla_compilations_total")
 
 
 def _record_host_leaves(kind, args):
@@ -116,6 +174,7 @@ class XLAStep(Unit):  # zlint: disable=checkpoint-state (params/state/step_index
         super().initialize(**kwargs)
         self.device = device or getattr(self.workflow, "device", None)
         self.compiler = StepCompiler(self.train_units, self.device)
+        _count_compilations()
         self.compiler.loop = getattr(self.workflow, "loop", None)
         self.compiler.collect_stats = bool(self.collect_model_stats)
         self.compiler.stats_stride = max(1, int(self.stats_interval))
@@ -179,6 +238,10 @@ class XLAStep(Unit):  # zlint: disable=checkpoint-state (params/state/step_index
         self.target_dispatch_seconds = 2.0
         self._last_epoch_seconds = None
         self._seen_chunk_lengths = set()
+        #: (end of the fetch, span arguments) of the last dispatch while
+        #: its replay is open; a deque, because ``stop()`` may close it
+        #: from another thread and ``pop()`` is one atomic step
+        self._replay_open = collections.deque(maxlen=1)
         self._pre_epoch_params = None
         self._pre_epoch_state = None
         self._pre_epoch_step_index = 0
@@ -440,35 +503,62 @@ class XLAStep(Unit):  # zlint: disable=checkpoint-state (params/state/step_index
     def _dispatch_epoch(self):
         """Run a CHUNK of whole epochs (every class segment, serving
         order) as one compiled program; fetch all stacked metrics in
-        one host round-trip."""
+        one host round-trip. The host's time is recorded in four
+        phases (``PHASES``) that tile it from one dispatch to the next:
+        ``launch`` + ``fetch`` are the ``xla.dispatch.epoch`` span,
+        ``build`` + ``replay`` what lies outside it. The first three
+        are also ``jax.profiler.TraceAnnotation``s, which put the same
+        intervals on a device trace's clock (one flag test each while
+        no profiler session runs)."""
         import jax
+        annotate = jax.profiler.TraceAnnotation
         loader = self.loader
-        fn, args, n_epochs, serves_per_epoch, classes = \
-            self._epoch_program()
-        # Stash a CONSISTENT epoch-entry view (params + optimizer state
-        # + step counter — the point the epoch's validation metric
-        # describes, since valid is served before train): improved-
-        # gated snapshots must save THESE, not the post-train values
-        # (per-step-mode / reference semantics, SURVEY.md §3.4). Only
-        # paid for when a snapshotter can consume it.
-        if self._keep_epoch_entry:
-            import jax.numpy as jnp
-            copy = (lambda t: jax.tree_util.tree_map(jnp.copy, t))
-            self._pre_epoch_params = copy(self.params)
-            self._pre_epoch_state = copy(self.state)
-            self._pre_epoch_step_index = self.step_index
-        self.step_index += serves_per_epoch * n_epochs
-        # cost BEFORE the call: analysis traces the program from its
-        # live arguments, and donation invalidates them afterwards
-        cost = perf.ledger.cost(
-            ("epoch", id(fn), n_epochs, serves_per_epoch), fn, args)
-        _record_host_leaves("epoch", args)
+        t_build = time.perf_counter()
+        self._close_replay(t_build)
+        ordinal = next(_ORDINALS)
+        with annotate("veles.dispatch.build"):
+            fn, args, n_epochs, serves_per_epoch, classes = \
+                self._epoch_program()
+            # Stash a CONSISTENT epoch-entry view (params + optimizer
+            # state + step counter — the point the epoch's validation
+            # metric describes, since valid is served before train):
+            # improved-gated snapshots must save THESE, not the
+            # post-train values (per-step-mode / reference semantics,
+            # SURVEY.md §3.4). Only paid for when a snapshotter can
+            # consume it.
+            if self._keep_epoch_entry:
+                import jax.numpy as jnp
+                copy = (lambda t: jax.tree_util.tree_map(jnp.copy, t))
+                self._pre_epoch_params = copy(self.params)
+                self._pre_epoch_state = copy(self.state)
+                self._pre_epoch_step_index = self.step_index
+            self.step_index += serves_per_epoch * n_epochs
+            # cost BEFORE the call: analysis traces the program from
+            # its live arguments, and donation invalidates them
+            # afterwards
+            cost = perf.ledger.cost(
+                ("epoch", id(fn), n_epochs, serves_per_epoch), fn, args)
+            _record_host_leaves("epoch", args)
+            compiled = _compilations()
         t0 = time.perf_counter()
-        self.params, self.state, outs = fn(*args)
-        host_outs = _fetch_tree(outs)
+        with annotate("veles.dispatch.launch"):
+            self.params, self.state, outs = fn(*args)
+        t_fetch = time.perf_counter()
+        with annotate("veles.dispatch.fetch"):
+            host_outs = _fetch_tree(outs)
         dt = time.perf_counter() - t0
         warm = n_epochs in self._seen_chunk_lengths
-        _record_dispatch("epoch", warm, t0, dt, epochs=n_epochs)
+        # ``compiles``: what was compiled or loaded inside the span,
+        # counted and not guessed as ``warm`` is
+        _record_dispatch("epoch", warm, t0, dt, epochs=n_epochs,
+                         dispatch=ordinal,
+                         compiles=int(_compilations() - compiled))
+        ids = {"dispatch": ordinal, "epochs": n_epochs}
+        for phase, start, end in (("build", t_build, t0),
+                                  ("launch", t0, t_fetch),
+                                  ("fetch", t_fetch, t0 + dt)):
+            _record_phase("epoch", phase, start, end - start, **ids)
+        self._replay_open.append((t0 + dt, ids))
         samples = n_epochs * int(loader.total_samples)
         tps = self._tokens_per_sample()
         perf.ledger.record_dispatch(
@@ -487,6 +577,55 @@ class XLAStep(Unit):  # zlint: disable=checkpoint-state (params/state/step_index
         self._chunk_epoch0 = loader.epoch_number
         self._chunk_len = n_epochs
         self._dispatched_epoch = loader.epoch_number
+
+    def _close_replay(self, now):
+        """Record the open ``replay`` phase, if there is one: from the
+        end of the last dispatch's fetch to ``now``, the entry of the
+        next dispatch or the workflow's stop."""
+        try:
+            start, ids = self._replay_open.pop()
+        except IndexError:
+            return
+        _record_phase("epoch", "replay", start, now - start, **ids)
+
+    def stop(self):
+        super().stop()
+        self._close_replay(time.perf_counter())
+
+    def print_dispatch_phases(self, stream=sys.stderr, newest=24):
+        """One line of the run's end (``Launcher.run``, beside the
+        per-unit table): where each of the newest scan-mode dispatches
+        the flight recorder still holds spent its host time, and how
+        many programs it compiled or loaded. A stalled dispatch reads
+        here as a long ``launch`` with ``compiles`` 1 (a compilation or
+        a cache load) or a long ``fetch`` (the device, or the host
+        thread's wake-up)."""
+        parent = "xla.dispatch.epoch"
+        rows = {}
+        for _, ev in telemetry.tracer.flight_spans():
+            name, args = ev["name"], ev.get("args", {})
+            if name.startswith(parent) and "dispatch" in args:
+                row = rows.setdefault(args["dispatch"], {})
+                if name == parent:
+                    row["compiles"] = args["compiles"]
+                else:
+                    row[name[len(parent) + 1:]] = ev["dur"] / 1e3
+        found = [(k, rows[k]) for k in sorted(rows) if "compiles" in rows[k]]
+        if not found:
+            return
+        counted = sum(
+            child.count for items, child in telemetry.histogram(
+                "veles_xla_dispatch_seconds", "",
+                ("kind", "warm")).children() if ("kind", "epoch") in items)
+        stream.write(
+            "dispatch phases: %s ms, compiles; %d dispatches counted, %d "
+            "in the flight recorder, the newest %d: %s\n" % (
+                "/".join(PHASES), counted, len(found),
+                min(newest, len(found)), "; ".join(
+                    "#%d %s c%d" % (k, "/".join(
+                        "%.1f" % row[p] if p in row else "-"
+                        for p in PHASES), row["compiles"])
+                    for k, row in found[-newest:])))
 
     # -- streaming dispatch -------------------------------------------
 
