@@ -105,6 +105,24 @@ text = jax.jit(lambda h13, rows: gate(rows, h13)[0]).lower(
 assert " while(" in text and text.count("tpu_custom_call") == 1, text
 assert "bf16[65536,1536]{1,0:T(8,128)(2,1)} broadcast(" not in text, text
 print("COMPILED expert row stage", flush=True)
+# the delta rule's pass over chunks (parallel/pallas_delta.py) at the
+# Solar-Open2 cell's shape, a group of 8 heads of 128 x 128 over 64
+# chunks of 64: the plain forward, and the forward that keeps the
+# entry states with the backward
+from veles.znicz_tpu.parallel import pallas_delta
+terms = [jax.ShapeDtypeStruct((64, 1, 8, rows, cols), jnp.float32,
+                              sharding=on_chip)
+         for rows, cols in ((64, 128),) * 3 + ((64, 64), (64, 128),
+                                               (1, 128))]
+def state_pass(*terms):
+    def total(*terms):
+        o, state = pallas_delta.state_pass(*terms, rows=8)
+        return (o * o).sum() + (state * state).sum()
+    return (pallas_delta.state_pass(*terms, rows=8),
+            jax.grad(total, argnums=tuple(range(6)))(*terms))
+text = jax.jit(state_pass).lower(*terms).compile().as_text()
+assert text.count('custom_call_target="tpu_custom_call"') == 3, text
+print("COMPILED delta state pass", flush=True)
 """
 
 
@@ -127,7 +145,7 @@ def test_every_pallas_variant_compiles_for_v5e(tmp_path):
         pytest.skip("libtpu gives no compile-only v5e topology here: "
                     + proc.stdout.strip()[:200])
     assert proc.returncode == 0, proc.stderr[-3000:]
-    assert proc.stdout.count("COMPILED ") == 7, proc.stdout
+    assert proc.stdout.count("COMPILED ") == 8, proc.stdout
 
 
 def test_smoke_refuses_a_machine_without_a_tpu():

@@ -173,10 +173,14 @@ RECURRENCE_CASES = {
 }
 
 
-#: (S, CHUNK, BLOCK): chunk 16 as one block (the pairwise form over the
-#: whole chunk), chunk 16 in blocks of 4, and the cell's 64 / 16
-RECURRENCE_SHAPES = {"one_block": (64, 16, 16), "blocks_of_4": (64, 16, 4),
-                     "cell_64_16": (128, 64, 16)}
+#: (S, CHUNK, BLOCK, heads, dk, how the state pass runs): chunk 16 as
+#: one block (the pairwise form over the whole chunk), chunk 16 in
+#: blocks of 4, the cell's 64 / 16, and that at the cell's head width
+#: with the pass over chunks as the Pallas kernel pair (interpreted)
+RECURRENCE_SHAPES = {"one_block": (64, 16, 16, 3, 8, None),
+                     "blocks_of_4": (64, 16, 4, 3, 8, None),
+                     "cell_64_16": (128, 64, 16, 3, 8, None),
+                     "cell_kernels": (128, 64, 16, 2, 128, "interpret")}
 
 
 def decays(gen, shape, scale, dt):
@@ -200,11 +204,10 @@ def test_chunks_against_tokens(case, shape, monkeypatch):
     import jax
     import jax.numpy as jnp
     scale, dt, step = RECURRENCE_CASES[case]
-    s, chunk, block = RECURRENCE_SHAPES[shape]
+    s, chunk, block, h, dk, kernels = RECURRENCE_SHAPES[shape]
     monkeypatch.setattr(delta, "CHUNK", chunk)
     monkeypatch.setattr(delta, "BLOCK", block)
     gen = numpy.random.RandomState(3)
-    h, dk = 3, 8
     q = unit_rows(gen.randn(s, h, dk)) * dk ** -0.5
     k, v = unit_rows(gen.randn(s, h, dk)), gen.randn(s, h, dk)
     a = decays(gen, (s, h, dk), scale, dt)
@@ -215,7 +218,7 @@ def test_chunks_against_tokens(case, shape, monkeypatch):
         assert -float(a.reshape(-1, 16, h, dk).sum(1).min()) > 88.0
 
     def chunked(*args):
-        o, state = delta.delta_rule(*(t[None] for t in args))
+        o, state = delta.delta_rule(*(t[None] for t in args), kernels)
         return (o[0] * weights).sum() + state.sum(), (o[0], state[0])
 
     def by_token(*args):
@@ -231,6 +234,105 @@ def test_chunks_against_tokens(case, shape, monkeypatch):
         assert numpy.isfinite(numpy.asarray(mine)).all()
         bound = 2e-5 * max(1.0, float(jnp.abs(theirs).max()))
         assert float(jnp.abs(mine - theirs).max()) < bound
+
+
+# -- the pass over chunks: the kernel pair against the scan -------------------
+
+
+def pass_terms(n, heads, c=64, dk=128, dv=128):
+    """The six terms of :func:`delta.state_pass` for ``n`` chunks of
+    ``c`` tokens, (N, 1, heads, ., .), at the sizes the cell's have: a
+    solved system, keys and queries of unit length, decays in (0, 1]."""
+    import jax.numpy as jnp
+    gen = numpy.random.RandomState(17)
+    lead = (n, 1, heads)
+    last = decays(gen, lead + (1, dk), 4.0, 0.05)
+    terms = (gen.randn(*lead, c, dv), unit_rows(gen.randn(*lead, c, dk)),
+             unit_rows(gen.randn(*lead, c, dk)) * dk ** -0.5,
+             numpy.tril(gen.randn(*lead, c, c)) * dk ** -0.5,
+             unit_rows(gen.randn(*lead, c, dk)), last)
+    return [jnp.asarray(t, jnp.float32) for t in terms]
+
+
+@pytest.mark.parametrize("n,heads", [(3, 2), (1, 1)])
+def test_state_pass_kernels_against_scan(n, heads):
+    """The Pallas pair (interpreted) against the ``lax.scan`` at the
+    cell's widths: the output, the final state, the entry states the
+    backward reads, and all six cotangents, with a cotangent on the
+    final state too."""
+    import jax
+    import jax.numpy as jnp
+    from veles.znicz_tpu.parallel import pallas_delta
+    terms = pass_terms(n, heads)
+    gen = numpy.random.RandomState(19)
+    w_o = jnp.asarray(gen.randn(n, 1, heads, 64, 128), jnp.float32)
+    w_s = jnp.asarray(gen.randn(1, heads, 128, 128), jnp.float32)
+
+    def close(mine, theirs):
+        assert mine.shape == theirs.shape
+        assert numpy.isfinite(numpy.asarray(mine)).all()
+        bound = 2e-5 * max(1.0, float(jnp.abs(theirs).max()))
+        assert float(jnp.abs(mine - theirs).max()) < bound
+
+    def total(run):
+        def loss(*terms):
+            o, state = run(*terms)
+            return (o * w_o).sum() + (state * w_s).sum(), (o, state)
+        return jax.value_and_grad(loss, argnums=range(6), has_aux=True)
+
+    with jax.default_matmul_precision("highest"):
+        (_, want), g_want = total(delta.scan_pass)(*terms)
+        (_, got), g_got = total(
+            lambda *t: delta.state_pass(*t, "interpret"))(*terms)
+        plain = delta.state_pass(*terms, "interpret")
+        entry = [jnp.zeros_like(w_s)] + [
+            delta.scan_pass(*(t[:i] for t in terms))[1]
+            for i in range(1, n)]
+        kept = pallas_delta._forward(
+            *(t[:, 0] for t in terms), interpret=True, rows=heads,
+            keep=True)[2]
+    assert float(jnp.abs(want[1]).max()) > 0.1
+    for mine, theirs in zip(got + plain + g_got, want + want + g_want):
+        close(mine, theirs)
+    # W_k, Q_in and ``last`` meet the ENTRY state, zero before the
+    # first chunk
+    for i, grad in enumerate(g_want):
+        assert (float(jnp.abs(grad).max()) > 0) == (n > 1 or i in (0, 3, 4))
+    close(kept.swapaxes(-1, -2), jnp.stack(entry)[:, 0])
+
+
+@pytest.mark.parametrize("c,dk,dv,on_a_tpu", [
+    (64, 128, 128, "mosaic"),   # the cell
+    (64, 256, 128, "mosaic"),
+    (64, 8, 8, None),           # the tiny presets' heads
+    (64, 16, 16, None),
+    (64, 128, 64, None),        # half a lane tile
+    (37, 128, 128, None),       # a sequence that is one odd chunk
+    (16, 128, 128, None),
+])
+def test_rule_that_picks_the_state_pass(c, dk, dv, on_a_tpu, monkeypatch):
+    """The kernels where the step compiles for a TPU and the widths are
+    whole lane tiles under chunks of ``CHUNK``; the scan on any other
+    shape whatever the platform, and on the CPU whatever the shape."""
+    monkeypatch.setattr(delta, "CHUNK", 64)
+    assert delta.state_pass_kernels("tpu", c, dk, dv) == on_a_tpu
+    assert delta.state_pass_kernels("cpu", c, dk, dv) is None
+
+
+def test_cpu_step_at_the_cells_widths_holds_no_kernel(monkeypatch):
+    """A delta-rule layer of 2 heads of 128 over one chunk of 64,
+    forward + backward lowered for the CPU: the scan, no Pallas call in
+    the program's text; the same layer with the kernels forced holds
+    them (so the text would show one)."""
+    monkeypatch.setattr(delta, "CHUNK", 64)
+    wide = dict(DELTA, heads=2, head_dim=128)
+    text = program_text(DeltaAttention, wide, debug_info=True)
+    assert (text.count("pallas"), text.count("tpu_custom_call")) == (0, 0)
+    assert text.count("stablehlo.while") > 0
+    monkeypatch.setattr(delta, "state_pass_kernels",
+                        lambda *shape: "interpret")
+    assert program_text(DeltaAttention, wide,
+                        debug_info=True).count("pallas_call") > 0
 
 
 def cell_chunk(mild_channels, c=64, dk=128):
@@ -467,7 +569,12 @@ def test_counters_and_gauges_ride_the_metric_fetch(tiny_lm):
         # S = 64 in chunks of 16, which are one block of 16 each
         assert registry.counter_total(
             "veles_delta_pairwise_pairs_total", layer=layer) == S * 16
+        # on the CPU the pass over chunks is the scan
+        assert registry.counter_total(
+            "veles_delta_kernel_chunks_total", layer=layer) == 0
     text = registry.render_prometheus()
+    assert 'veles_delta_kernel_chunks_total{layer="%s"} 0' % layers[0] \
+        in text
 
     def gauges(name):
         return [float(line.split()[-1]) for line in text.splitlines()
@@ -483,6 +590,35 @@ def test_counters_and_gauges_ride_the_metric_fetch(tiny_lm):
             assert registry.counter_total(
                 "veles_moe_pairs_total", layer=unit.name) == S * 2
     assert registry.counter_total("veles_moe_dropped_pairs_total") == 0
+
+
+def test_kernel_chunks_are_counted_where_the_kernels_run(tiny_lm,
+                                                         monkeypatch):
+    """The kernels forced (interpreted) in the whole LM: every chunk of
+    the step is counted, and the step trains to the scan's loss."""
+    with telemetry.scoped():
+        wf = tiny_lm.create_workflow()
+        wf.initialize(device="cpu")
+        wf.run()
+    want = wf.decision.history[0]
+    prng.seed_all(5)
+    monkeypatch.setattr(delta, "state_pass_kernels",
+                        lambda *shape: "interpret")
+    wf = tiny_lm.create_workflow()
+    wf.initialize(device="cpu")
+    wf.run()
+    registry = telemetry.get_registry()
+    layers = [u.name for u in wf.forwards
+              if isinstance(u, DeltaAttention)]
+    assert len(layers) == 3
+    for layer in layers:
+        assert registry.counter_total("veles_delta_tokens_total",
+                                      layer=layer) == S
+        assert registry.counter_total(
+            "veles_delta_kernel_chunks_total", layer=layer) == S // 16
+    got = wf.decision.history[0]
+    for phase in ("validation", "train"):
+        assert abs(got[phase]["loss"] - want[phase]["loss"]) < 1e-5
 
 
 def test_step_program_names_the_new_scopes(tiny_lm):
@@ -571,7 +707,7 @@ ACCEPTED = {
 }
 
 
-def program_text(cls, kwargs):
+def program_text(cls, kwargs, debug_info=False):
     import jax
     feed, fwd, gd, x, err, comp = build(cls, **kwargs)
 
@@ -587,7 +723,7 @@ def program_text(cls, kwargs):
                 ctx.values.get((gd.name, "err_input")), ctx.params)
 
     return jax.jit(fn).lower(comp.gather_params(), comp.gather_state(),
-                             x, err).as_text()
+                             x, err).as_text(debug_info=debug_info)
 
 
 @pytest.mark.parametrize("case", sorted(ACCEPTED))

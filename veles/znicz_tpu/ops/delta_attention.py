@@ -55,26 +55,48 @@ one chunk of an odd length) the chunk is one block.
 
 The triangular system (the delta rule inside a chunk) is solved for
 all chunks at once, it does not depend on the state; the three lines
-that do are a ``lax.scan`` over the chunks that carries ``S``. The
-backward keeps ``q, k, v, a, b`` and runs all of this again
+that do are the pass over chunks, :func:`state_pass`: one algorithm,
+two implementations. Where the step compiles for a TPU, ``dk`` and
+``dv`` are whole 128-lane tiles and the chunks hold ``CHUNK`` tokens
+(:func:`state_pass_kernels`: the shape and the platform decide, no
+key) it is a pair of Pallas kernels under one ``jax.custom_vjp``
+(``parallel/pallas_delta.py``): grid (heads, chunks), the float32
+state in VMEM from a head's first chunk to its last, ``HEADS_AT_ONCE``
+heads a program. Everywhere else - the CPU, heads of 8 or 16, a
+sequence that is one odd chunk - it is a ``lax.scan`` over the chunks
+that carries ``S`` (:func:`scan_pass`), the kernels' oracle, as
+``parallel/flash.py`` is the attention kernels'.
+
+The backward keeps ``q, k, v, a, b`` and runs all of this again
 (:func:`~veles.znicz_tpu.ops.vjp_units.recomputed`), and inside that
-each loop keeps its carries and a chunk's inputs alone
-(``jax.checkpoint`` on the bodies): ``S / CHUNK`` states of ``heads x
-dk x dv`` float32 a layer, alive in that layer's backward only.
+the loop over head groups and the scores' keep a trip's inputs alone
+(``jax.checkpoint`` on their bodies). The pass keeps every chunk's
+ENTRY state - ``S / CHUNK`` states of ``heads x dk x dv`` float32 a
+group, alive in that group's backward only: the kernels write them
+beside the output when they run under differentiation (and none in a
+forward nobody differentiates), the scan has them as its carries, its
+body under ``jax.checkpoint`` too. With the kernels a step evaluates
+the pass forward twice (the forward pass; the group's re-evaluation in
+the backward, which keeps the states) and backward once; with the scan
+a third forward runs inside the body's checkpoint.
 
 Counters, on the step's metric fetch: ``veles_delta_tokens_total
 {layer}``, ``veles_delta_steps_total{layer}``,
 ``veles_delta_pairwise_pairs_total{layer}`` (pairs of tokens whose
 decay was taken pairwise, tokens x the block width in use: over tokens
 x ``CHUNK`` the share of a chunk's square still made of differences,
-1/4 at 64 / 16, 1 where a sequence fell back to one block); gauges of
-the last training step ``veles_delta_decay_mean{layer}`` (mean
-``exp(a)``: which numeric regime the chunks are in),
-``veles_delta_beta_mean{layer}`` and
+1/4 at 64 / 16, 1 where a sequence fell back to one block),
+``veles_delta_kernel_chunks_total{layer}`` (chunks whose state pass the
+kernel pair ran: x ``CHUNK`` over the tokens 1 where the kernels run,
+0 where the scan does); gauges of the last training step
+``veles_delta_decay_mean{layer}`` (mean ``exp(a)``: which numeric
+regime the chunks are in), ``veles_delta_beta_mean{layer}`` and
 ``veles_delta_state_rms{layer}`` (the final state's root mean square: a
 recurrence that blows up under the negative eigenvalues ``b > 1``
 allows shows here before the loss does).
 """
+
+import math
 
 import numpy
 
@@ -174,19 +196,20 @@ def chunk_scores(q, k, g):
     return tuple(jnp.concatenate(side, -2) for side in zip(*rows))
 
 
-def delta_rule(q, k, v, a, beta):
+def delta_rule(q, k, v, a, beta, kernels=None):
     """The recurrence above for ``q, k, a`` (B, S, H, dk), ``v``
     (B, S, H, dv), ``beta`` (B, S, H), float32, from a zero state;
     -> (o (B, S, H, dv), the final state (B, H, dk, dv)). The heads do
     not meet: ``HEADS_AT_ONCE`` of them run at a time, one group after
     the other (a ``lax.map`` whose backward keeps a group's inputs
     alone), so the chunks' terms and the saved states of one group are
-    alive at a time, not the layer's."""
+    alive at a time, not the layer's. ``kernels``: how
+    :func:`state_pass` runs (:func:`state_pass_kernels`)."""
     import jax
     b, s, h, _ = q.shape
     groups = h // HEADS_AT_ONCE if h % HEADS_AT_ONCE == 0 else 1
     if groups == 1:
-        return chunked_delta_rule(q, k, v, a, beta)
+        return chunked_delta_rule(q, k, v, a, beta, kernels)
 
     def split(t):       # (B, S, H, w) -> (G, B, S, H / G, w)
         return t.reshape(b, s, groups, h // groups, -1) \
@@ -194,7 +217,7 @@ def delta_rule(q, k, v, a, beta):
 
     o, state = jax.lax.map(
         jax.checkpoint(
-            lambda x: chunked_delta_rule(*x[:4], x[4][..., 0]),
+            lambda x: chunked_delta_rule(*x[:4], x[4][..., 0], kernels),
             prevent_cse=False),
         tuple(split(t) for t in (q, k, v, a, beta[..., None])))
     return (o.transpose(1, 2, 0, 3, 4).reshape(b, s, h, -1),
@@ -202,13 +225,12 @@ def delta_rule(q, k, v, a, beta):
                                                    + state.shape[3:]))
 
 
-def chunked_delta_rule(q, k, v, a, beta):
+def chunked_delta_rule(q, k, v, a, beta, kernels=None):
     """:func:`delta_rule` for all the heads it is given, in chunks of
     ``CHUNK`` tokens (one chunk where that does not divide S)."""
     import jax
     import jax.numpy as jnp
     f32 = jnp.float32
-    exact = jax.lax.Precision.HIGHEST
     b, s, h, dk = q.shape
     dv = v.shape[-1]
     c = chunk_of(s)
@@ -228,6 +250,52 @@ def chunked_delta_rule(q, k, v, a, beta):
         beta * scores, beta * jnp.concatenate([v, grown * k], -1),
         left_side=True, lower=True, unit_diagonal=True)
     last = g[..., -1:, :]
+    o, state = state_pass(
+        w[..., :dv], w[..., dv:], grown * q, reads, jnp.exp(last - g) * k,
+        last, kernels)
+    return o.transpose(1, 0, 3, 2, 4).reshape(b, s, h, dv), state
+
+
+def state_pass_kernels(platform, c, dk, dv):
+    """How :func:`state_pass` runs for chunks of ``c`` tokens and heads
+    of ``dk x dv`` in a step that compiles for ``platform``: "mosaic",
+    the Pallas pair of ``parallel/pallas_delta.py``, on a TPU where the
+    widths are whole 128-lane tiles and the chunk is ``CHUNK``; None,
+    the ``lax.scan``, everywhere else."""
+    from veles.backends import is_tpu
+    if is_tpu(platform) and c == CHUNK and dk % 128 == 0 \
+            and dv % 128 == 0:
+        return "mosaic"
+    return None
+
+
+def state_pass(w_v, w_k, q_in, reads, k_out, last, kernels=None):
+    """The pass over chunks that carries the state, from a zero state:
+    chunk by chunk ``U = W_v - W_k S``, ``O = Q_in S + R U``, ``S <-
+    exp(last) * S + K_out^T U``. Terms (N, B, H, C, .) float32,
+    ``last`` (N, B, H, 1, dk) -> (O (N, B, H, C, dv), the final state
+    (B, H, dk, dv)). One algorithm, two implementations: ``kernels``
+    None the ``lax.scan`` (:func:`scan_pass`), "mosaic" the Pallas
+    kernel pair, "interpret" the pair in Pallas's interpreter (the
+    tests)."""
+    if kernels is None:
+        return scan_pass(w_v, w_k, q_in, reads, k_out, last)
+    from veles.znicz_tpu.parallel import pallas_delta
+    # a program takes a group's heads, or what of a group divides the
+    # call's: a grid step costs as much as a head's chunk does
+    return pallas_delta.state_pass(
+        w_v, w_k, q_in, reads, k_out, last,
+        rows=math.gcd(w_v.shape[1] * w_v.shape[2], HEADS_AT_ONCE),
+        interpret=kernels == "interpret")
+
+
+def scan_pass(w_v, w_k, q_in, reads, k_out, last):
+    """:func:`state_pass` as a ``lax.scan`` over the chunks whose body
+    is under ``jax.checkpoint``: the backward keeps the carries and
+    makes a chunk's ``U`` again."""
+    import jax
+    import jax.numpy as jnp
+    exact = jax.lax.Precision.HIGHEST
 
     @jax.checkpoint
     def step(state, x):
@@ -240,10 +308,10 @@ def chunked_delta_rule(q, k, v, a, beta):
         return state, o
 
     state, o = jax.lax.scan(
-        step, jnp.zeros((b, h, dk, dv), f32),
-        (w[..., :dv], w[..., dv:], grown * q, reads,
-         jnp.exp(last - g) * k, jnp.exp(last).swapaxes(-1, -2)))
-    return o.transpose(1, 0, 3, 2, 4).reshape(b, s, h, dv), state
+        step, jnp.zeros(w_k.shape[1:3] + (w_k.shape[-1], w_v.shape[-1]),
+                        jnp.float32),
+        (w_v, w_k, q_in, reads, k_out, jnp.exp(last).swapaxes(-1, -2)))
+    return o, state
 
 
 @forward_unit("delta_attention")
@@ -295,6 +363,13 @@ class DeltaAttention(VjpForward):
                 "norm": ((d,), "ones"),
                 "norm_out": ((dh,), "ones")}
 
+    def pass_kernels(self, platform):
+        """:func:`state_pass_kernels` for this layer's shape in a step
+        that compiles for ``platform``."""
+        return state_pass_kernels(
+            platform, chunk_of(self.input.shape[-2]), self.head_dim,
+            self.head_dim)
+
     def apply(self, ctx, p, x):
         import jax
         import jax.numpy as jnp
@@ -323,8 +398,9 @@ class DeltaAttention(VjpForward):
                 (mm.dot(low, w_decay, f32) + dt_bias).reshape(b, s, h, dh))
             beta = 2.0 * jax.nn.sigmoid(beta)
             with jax.named_scope("veles.delta"):
-                o, state = delta_rule(unit(q) * dh ** -0.5, unit(k), v,
-                                      a, beta)
+                o, state = delta_rule(
+                    unit(q) * dh ** -0.5, unit(k), v, a, beta,
+                    self.pass_kernels(ctx._compiler.device.platform))
             state = jax.lax.stop_gradient(state)
             return o.astype(mm.act), {
                 "decay": jnp.exp(jax.lax.stop_gradient(a)).mean(),
@@ -380,6 +456,13 @@ class DeltaAttention(VjpForward):
             "width in use), training steps", ("layer",)
         ).labels(self.name).inc(
             tokens * block_of(chunk_of(self.input.shape[-2])))
+        telemetry.counter(
+            "veles_delta_kernel_chunks_total", "Chunks whose state pass "
+            "a delta-rule layer ran as the Pallas kernel pair (0: the "
+            "lax.scan), training steps", ("layer",)
+        ).labels(self.name).inc(
+            tokens // chunk_of(self.input.shape[-2])
+            if self.pass_kernels(self.device.platform) else 0)
         telemetry.counter(
             "veles_delta_steps_total", "Training steps a delta-rule "
             "layer ran", ("layer",)).labels(self.name).inc()
