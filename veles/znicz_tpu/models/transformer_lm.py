@@ -65,7 +65,18 @@ root.lm.update({
     # elementwise sigmoid output gate, and "delta_attention" a gated
     # delta-rule linear-attention layer (ops/delta_attention.py) of
     # delta_heads heads of delta_head_dim, delta_conv_kernel taps and
-    # gates of rank delta_gate_rank. norm: "pre" (one gain
+    # gates of rank delta_gate_rank. operators: {operator's name: the
+    # GQAttention keywords that are ITS OWN, over the model's} for a
+    # model whose attention layers differ in more than a switch - a
+    # layer pattern whose head count, rotary base, rotated share of a
+    # head (rotary_dim), rope_scaling (a rope_parameters entry of
+    # rope_type "yarn"), window (a query sees itself and the window - 1
+    # tokens before it) or gate ("elementwise" | "head": one sigmoid
+    # gate a head and token) change by layer type; a name the table
+    # below has keeps its switches under the model's keywords, a new
+    # name ("sliding_attention") is the model's to define, and the
+    # kernel and its tile follow from the shape and the window, never
+    # from a key. norm: "pre" (one gain
     # before each sub-layer) or "sandwich" (a second gain on the
     # sub-layer's output, before the residual add). ut_steps > 1 runs
     # the layers and the final norm ut_steps times in a row over the
@@ -91,7 +102,7 @@ root.lm.update({
               "ut_steps": 1, "exit_entropy_weight": 0.0,
               "moe_shared_hidden": 0, "delta_heads": None,
               "delta_head_dim": None, "delta_conv_kernel": 4,
-              "delta_gate_rank": None},
+              "delta_gate_rank": None, "operators": {}},
     "train": {"learning_rate": 0.05, "gradient_moment": 0.9,
               "weights_decay": 0.0},
     "decision": {"max_epochs": 8, "fail_iterations": 50},
@@ -247,8 +258,17 @@ ATTENTION_OPERATORS = {
     "plain_attention": {"qk_norm": False},
     "gated_nope_attention": {"qk_norm": False, "rope": False,
                              "gate": True}}
-#: every operator a pre_norm layer can have
-PRE_NORM_OPERATORS = ("conv", "delta_attention") + tuple(ATTENTION_OPERATORS)
+
+
+def attention_operators(m):
+    """``ATTENTION_OPERATORS`` under the model's own
+    (``root.lm.model.operators``): {name: what it changes of
+    ``GQAttention`` and of the model's heads, rope_theta, ...}."""
+    own = m.get("operators")
+    own = own.to_dict() if hasattr(own, "to_dict") else dict(own or {})
+    return dict(ATTENTION_OPERATORS, **{
+        name: dict(ATTENTION_OPERATORS.get(name, {}), **keywords)
+        for name, keywords in own.items()})
 
 
 def loop_passes(m):
@@ -265,10 +285,12 @@ def pre_norm_body(m, t):
     > 1 each layer dict says its place in the loop (``"loop"``: a
     layer's operator starts a recomputed segment)."""
     operators = layer_operators(m)
-    if set(operators) - set(PRE_NORM_OPERATORS):
+    attention = attention_operators(m)
+    # every operator a pre_norm layer can have
+    known = ("conv", "delta_attention") + tuple(attention)
+    if set(operators) - set(known):
         raise ValueError("block='pre_norm' has the operators %s, got %r"
-                         % (", ".join(map(repr, PRE_NORM_OPERATORS)),
-                            operators))
+                         % (", ".join(map(repr, known)), operators))
     norm = m.get("norm", "pre")
     if norm not in ("pre", "sandwich"):
         raise ValueError("norm is 'pre' or 'sandwich', got %r" % (norm,))
@@ -290,7 +312,7 @@ def pre_norm_body(m, t):
             "the expert and delta-rule layers' counters are one visit's"
             % (steps,))
     if norm == "sandwich" and not (
-            dense and set(operators) <= set(ATTENTION_OPERATORS)):
+            dense and set(operators) <= set(attention)):
         raise ValueError("norm='sandwich' is for attention layers over "
                          "a SwiGLU feed-forward")
     eps = m.get("norm_eps", 1e-5)
@@ -315,12 +337,12 @@ def pre_norm_body(m, t):
         else:
             body.append({
                 "type": "gqa_attention",
-                "->": dict(attention_kernel_keys(m), heads=m.heads,
-                           kv_heads=m.get("kv_heads"),
-                           head_dim=m.get("head_dim"),
-                           rope_theta=m.get("rope_theta", 1e6),
-                           eps=eps, **ATTENTION_OPERATORS[kind],
-                           **sandwich),
+                "->": dict(dict(attention_kernel_keys(m), heads=m.heads,
+                                kv_heads=m.get("kv_heads"),
+                                head_dim=m.get("head_dim"),
+                                rope_theta=m.get("rope_theta", 1e6),
+                                eps=eps, **sandwich),
+                           **attention[kind]),
                 "<-": dict(t), "loop": "segment"})
         if index < m.get("dense_layers", 0):
             body.append({"type": "swiglu_ffn",

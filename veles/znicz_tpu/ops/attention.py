@@ -302,17 +302,24 @@ def _core_scope(xp=None):
     return jax.named_scope("veles.core")
 
 
-def dense_attention_core_fwd(xp, q, k, v, causal, scale, dot=None):
+def dense_attention_core_fwd(xp, q, k, v, causal, scale, dot=None,
+                             window=None):
     """(probs, ctx) with ctx = softmax(qkᵀ·scale [+ causal mask])·v.
     ``dot``: matmul implementation (``ctx.dot`` on the traced path for
-    bf16 MXU inputs; defaults to the plain xp matmul)."""
+    bf16 MXU inputs; defaults to the plain xp matmul). ``window``: a
+    causal query sees itself and the ``window - 1`` tokens before it
+    (keys further back are masked like those after it)."""
     dot = dot or xp.matmul
     s = q.shape[2]
     scores = dot(q, k.transpose(0, 1, 3, 2)) * scale
     if causal:
-        mask = xp.asarray(
-            numpy.triu(numpy.full((s, s), -1e9, numpy.float32), 1))
-        scores = scores + mask
+        mask = numpy.triu(numpy.full((s, s), -1e9, numpy.float32), 1)
+        if window is not None:
+            mask += numpy.tril(
+                numpy.full((s, s), -1e9, numpy.float32), -int(window))
+        scores = scores + xp.asarray(mask)
+    elif window is not None:
+        raise ValueError("a window is of a causal row")
     probs = A.softmax(xp, scores)
     return probs, dot(probs, v)
 
@@ -349,6 +356,10 @@ class MultiHeadAttention(Forward):
         self.heads = int(heads)
         self.causal = causal
         self.residual = residual
+        #: a causal query sees itself and the ``window - 1`` tokens
+        #: before it (None: all of them); set by the units that have
+        #: one (``ops/gqa_attention.py``), read by the flash cores
+        self.window = None
         self.weights_out = Array()
         self.bias_out = Array()
         #: jax Mesh with a sequence axis -> the traced path streams
@@ -390,7 +401,9 @@ class MultiHeadAttention(Forward):
     def initialize(self, device=None, **kwargs):
         super().initialize(device=device, **kwargs)
         b, s, d = self.input.shape
-        if d % self.heads:
+        if d % self.heads and not getattr(self, "head_dim", None):
+            # (a unit that states its head's width, ops/gqa_attention.py,
+            # has heads x head_dim of its own beside d)
             raise ValueError("dim %d not divisible by %d heads"
                              % (d, self.heads))
         self.init_weights((d, 3 * d), d, 3 * d)
@@ -627,12 +640,13 @@ class MultiHeadAttention(Forward):
             kernel = self._pallas_on_mesh(ctx, PA.jitted(
                 PA.flash_attention_fwd, causal=self.causal,
                 block_q=blk, block_k=blk,
-                interpret=self._pallas_interpret(ctx)), "ttt", "tr")
+                interpret=self._pallas_interpret(ctx),
+                window=self.window), "ttt", "tr")
             return kernel(q, k, v)
         from veles.znicz_tpu.parallel import flash
         return flash.blocked_attention_fwd(
             q, k, v, causal=self.causal, block=self.attn_block_size,
-            dot=ctx.dot)
+            dot=ctx.dot, window=self.window)
 
     def core_bwd(self, ctx, mode, q, k, v, out_heads, lse, dctx):
         """-> (dq, dk, dv) of :meth:`core_fwd`; ``dctx`` by head."""
@@ -643,14 +657,15 @@ class MultiHeadAttention(Forward):
             kernel = self._pallas_on_mesh(ctx, PA.jitted(
                 PA.flash_attention_bwd, causal=self.causal,
                 block_q=blk, block_k=blk,
-                interpret=self._pallas_interpret(ctx)),
+                interpret=self._pallas_interpret(ctx),
+                window=self.window),
                 "ttttrt", "ttt")     # q k v out lse dout -> dq dk dv
             return kernel(q, k, v, out_heads, lse, dctx.astype(cd))
         from veles.znicz_tpu.parallel import flash
         return flash.blocked_attention_bwd(
             q, k, v, out_heads, lse, dctx.astype(cd),
             causal=self.causal, block=self.attn_block_size,
-            dot=ctx.dot)
+            dot=ctx.dot, window=self.window)
 
     def _ring_inner(self, ctx):
         """(inner, block) for the ring path — which kernel each ring

@@ -26,10 +26,22 @@ exactly against the dense formulation in tests.
 import numpy
 
 
-def blocked_attention_fwd(q, k, v, causal=True, block=128, dot=None):
+def _hidden(kpos, qpos, window):
+    """(S, block) bool: the keys a query under a ``window`` does not
+    see — those after it and those ``window`` or more tokens before it
+    (the kernels' band, ``parallel/pallas_attention.py``; here a mask
+    alone: the scan visits every block)."""
+    ahead = qpos[:, None] - kpos[None, :]
+    return (ahead < 0) | (ahead >= window)
+
+
+def blocked_attention_fwd(q, k, v, causal=True, block=128, dot=None,
+                          window=None):
     """q/k/v: (B, H, S, dh) → (out, lse); exact softmax(qkᵀ)v with
     O(S·block) peak score memory. ``block`` must divide S. ``dot``:
-    matmul implementation (``ctx.dot`` for bf16 MXU inputs)."""
+    matmul implementation (``ctx.dot`` for bf16 MXU inputs).
+    ``window``: a causal query sees itself and the ``window - 1``
+    tokens before it."""
     import jax.numpy as jnp
     from jax import lax
     dot = dot or jnp.matmul
@@ -38,6 +50,8 @@ def blocked_attention_fwd(q, k, v, causal=True, block=128, dot=None):
     if s % block:
         raise ValueError("block %d does not divide sequence %d"
                          % (block, s))
+    if window is not None and not causal:
+        raise ValueError("a window is of a causal row")
     n = s // block
     scale = numpy.float32(1.0 / numpy.sqrt(dh))
     qpos = jnp.arange(s)
@@ -48,7 +62,10 @@ def blocked_attention_fwd(q, k, v, causal=True, block=128, dot=None):
         m, l, acc = carry
         i, k_blk, v_blk = xs
         sc = dot(q, k_blk.transpose(0, 1, 3, 2)) * scale  # (B,H,S,blk)
-        if causal:
+        if window is not None:
+            sc = jnp.where(_hidden(i * block + jnp.arange(block), qpos,
+                                   window), jnp.float32(-1e9), sc)
+        elif causal:
             kpos = i * block + jnp.arange(block)
             mask = (kpos[None, :] > qpos[:, None]) * jnp.float32(-1e9)
             sc = sc + mask[None, None, :, :]
@@ -72,7 +89,7 @@ def blocked_attention_fwd(q, k, v, causal=True, block=128, dot=None):
 
 
 def blocked_attention_bwd(q, k, v, out, lse, dout, causal=True,
-                          block=128, dot=None, delta=None):
+                          block=128, dot=None, delta=None, window=None):
     """Backward by block recomputation from ``lse``; -> (dq, dk, dv),
     all exact (same formulas as the dense adjoint). The ds / p tiles
     are cast to the compute dtype before their three matmuls (same
@@ -87,6 +104,8 @@ def blocked_attention_bwd(q, k, v, out, lse, dout, causal=True,
     if s % block:
         raise ValueError("block %d does not divide sequence %d"
                          % (block, s))
+    if window is not None and not causal:
+        raise ValueError("a window is of a causal row")
     n = s // block
     scale = numpy.float32(1.0 / numpy.sqrt(dh))
     qpos = jnp.arange(s)
@@ -99,7 +118,10 @@ def blocked_attention_bwd(q, k, v, out, lse, dout, causal=True,
     def body(dq, xs):
         i, k_blk, v_blk = xs
         sc = dot(q, k_blk.transpose(0, 1, 3, 2)) * scale
-        if causal:
+        if window is not None:
+            sc = jnp.where(_hidden(i * block + jnp.arange(block), qpos,
+                                   window), jnp.float32(-1e9), sc)
+        elif causal:
             kpos = i * block + jnp.arange(block)
             mask = (kpos[None, :] > qpos[:, None]) * jnp.float32(-1e9)
             sc = sc + mask[None, None, :, :]

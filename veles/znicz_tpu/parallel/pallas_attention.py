@@ -83,6 +83,31 @@ Causal masking is paid only where it can matter (round 5): the
 fori_loops split at the diagonal — blocks fully below it skip the
 iota/where pass entirely, the diagonal remnant keeps it.
 
+A ``window`` W (static; a sliding-window layer: a query sees itself
+and the W - 1 tokens before it) makes the triangle a BAND, by the same
+two rules (PR 38). The K-loop pair's loops get a second bound — the
+forward's loop over K tiles a LOWER one, ``max(q0 - W + 1, 0) //
+block_k`` for the Q block that starts at ``q0`` (tiles wholly older
+than the window are skipped as tiles wholly in the future are), the
+fused backward's loop over Q tiles an UPPER one, ``cdiv(k0 + block_k +
+W - 1, block_q)`` — as scalars of the ``fori_loop``s, and the loops
+split in three: the tiles the band's OLD edge cuts (masked with both
+terms: under a window shorter than a tile the diagonal runs through
+them too), the tiles wholly inside (no mask pass), the tiles the
+diagonal cuts (the causal term alone, as without a window)
+(:func:`_band_spans_fwd`, :func:`_band_spans_bwd`). The one-tile pair
+gets one more term in ``_tile_mask``. A window >= S hides nothing a
+causal row shows and runs the kernels of ``window=None``, whose
+programs are the ones they were; no argument selects a kernel, shape
+and window do. Counts (:func:`band_pairs`, :func:`visited_pairs`): at
+S = 8192, W = 512 a head's band holds 4,063,488 query-key pairs where
+the triangle holds 33,558,528 (8.3 times: what a masked full kernel
+would do for three layers of 72 heads); at tile 512 the bounds visit 31
+tiles a row, 8,126,464 pairs, 2.0 times the band — each Q block its
+diagonal tile and the one before it, both masked, none plain: what the
+tile's rounding costs is the tile choice's to win back (a 256 tile
+visits 1.5 times the band, a 128 tile 1.25).
+
 Consumed by ``MultiHeadAttention(attn_impl="pallas")``; backward is
 wired through the explicit GD unit (znicz style), so no custom-VJP
 registration is needed — autodiff never touches these.
@@ -207,8 +232,94 @@ def _after(jnp, k0, q0, block_k, block_q):
         > q0 + lax.broadcasted_iota(jnp.int32, shape, 1)
 
 
+def _hidden(jnp, k0, q0, block_k, block_q, masked, window):
+    """What a masked tile hides, (block_k, block_q) bool over (key,
+    query): ``masked`` "causal" the keys after the query, "band" those
+    and the keys ``window`` or more tokens before it (a query sees
+    itself and the ``window - 1`` tokens before it)."""
+    if masked != "band":
+        return _after(jnp, k0, q0, block_k, block_q)
+    from jax import lax
+    shape = (block_k, block_q)
+    ahead = q0 - k0 + lax.broadcasted_iota(jnp.int32, shape, 1) \
+        - lax.broadcasted_iota(jnp.int32, shape, 0)
+    return (ahead < 0) | (ahead >= window)
+
+
+def _least_most(index):
+    """(max, min) for a bound computed from ``index``: Python's for an
+    int (the counters' rule), jax's for a kernel's program id."""
+    if isinstance(index, int):
+        return max, min
+    import jax.numpy as jnp
+    return jnp.maximum, jnp.minimum
+
+
+def _band_spans_fwd(qi, block_q, block_k, window):
+    """The K tiles the forward's Q block ``qi`` visits under a band,
+    as :func:`_split_loop` spans. ``qi`` is a Python int (the counters'
+    rule) or the kernel's program id (its loop bounds): the tiles from
+    the one holding the oldest key the block's FIRST query sees to the
+    diagonal's; masked where an edge of the band cuts them — the old
+    edge below the first tile whose keys every query of the block
+    still sees (both terms: a window under a tile meets the diagonal
+    there too), the diagonal as without a window — and plain between."""
+    q0 = qi * block_q
+    hi = (q0 + block_q + block_k - 1) // block_k
+    clear = q0 // block_k
+    least, most = _least_most(qi)
+    lo = least(q0 - window + 1, 0) // block_k
+    inside = most(least((least(q0 + block_q - window, 0) + block_k - 1)
+                        // block_k, lo), hi)
+    return [(lo, inside, "band"), (inside, least(clear, inside), False),
+            (least(clear, inside), hi, "causal")]
+
+
+def _band_spans_bwd(ki, block_q, block_k, window, n_qb):
+    """The Q tiles the backward's K block ``ki`` visits under a band:
+    from the diagonal's to the one holding the last query that sees the
+    block's LAST key; the diagonal remnant masked as without a window,
+    the tiles past the last one whose queries all see the block's first
+    key masked with both terms, plain between."""
+    k0 = ki * block_k
+    lo = k0 // block_q
+    clear = (k0 + block_k - 1 + block_q - 1) // block_q
+    least, most = _least_most(ki)
+    hi = most((k0 + block_k + window - 1 + block_q - 1) // block_q, n_qb)
+    inside = most(least((k0 + window) // block_q, lo), hi)
+    return [(lo, most(clear, inside), "causal"),
+            (most(clear, inside), inside, False), (inside, hi, "band")]
+
+
+def visited_pairs(s, block_q, block_k, window):
+    """Query-key pairs of the tiles the K-loop kernels' loop bounds
+    visit for ONE (batch, head) row of length ``s``: the static count
+    behind ``veles_window_tile_pairs_total``. Over :func:`band_pairs`
+    it is what the tile's rounding costs (2.0 at tile 512, window 512,
+    S = 8192; 1.06 for the causal triangle there)."""
+    block_q, block_k = min(block_q, s), min(block_k, s)
+    if window is None or window >= s:
+        tiles = sum(-(-(qi + 1) * block_q // block_k)
+                    for qi in range(s // block_q))
+    else:
+        tiles = 0
+        for qi in range(s // block_q):
+            spans = _band_spans_fwd(qi, block_q, block_k, window)
+            tiles += spans[-1][1] - spans[0][0]
+    return tiles * block_q * block_k
+
+
+def band_pairs(s, window=None):
+    """Query-key pairs a causal row of length ``s`` attends: query
+    ``t`` sees ``min(t + 1, window)`` keys (4,063,488 at S = 8192,
+    window 512, where the triangle holds 33,558,528)."""
+    if window is None or window >= s:
+        return s * (s + 1) // 2
+    return window * (window + 1) // 2 + (s - window) * window
+
+
 def _fwd_kernel(q_ref, k_ref, vt_ref, o_ref, lse_ref, *, block_q,
-                block_k, n_kb, causal, scale):
+                block_k, n_kb, causal, scale, window=None):
     """K-LOOP forward, one q block a program, the K row and the
     TRANSPOSED V row (dh, S) resident. The score tile is held
     transposed, (keys on sublanes, queries on lanes), as the one-tile
@@ -241,7 +352,8 @@ def _fwd_kernel(q_ref, k_ref, vt_ref, o_ref, lse_ref, *, block_q,
                 preferred_element_type=jnp.float32) * scale
             if masked:
                 st = jnp.where(
-                    _after(jnp, k0, qi * block_q, block_k, block_q),
+                    _hidden(jnp, k0, qi * block_q, block_k, block_q,
+                            masked, window),
                     jnp.float32(-1e9), st)
             m_new = jnp.maximum(m, st.max(axis=0, keepdims=True))
             coef = jnp.exp(m - m_new)               # (1, bq)
@@ -258,7 +370,11 @@ def _fwd_kernel(q_ref, k_ref, vt_ref, o_ref, lse_ref, *, block_q,
     m0 = jnp.full((1, block_q), -jnp.inf, jnp.float32)
     l0 = jnp.zeros((1, block_q), jnp.float32)
     acc0 = jnp.zeros((dh, block_q), jnp.float32)
-    if causal:
+    if window is not None:
+        # ... and K blocks wholly older than the window likewise: the
+        # mask is paid where an edge of the band cuts a tile
+        spans = _band_spans_fwd(qi, block_q, block_k, window)
+    elif causal:
         # K blocks past this Q block's last row are all-masked — skip
         # them entirely; only the diagonal remnant needs the mask
         hi = pl.cdiv((qi + 1) * block_q, block_k)
@@ -273,7 +389,7 @@ def _fwd_kernel(q_ref, k_ref, vt_ref, o_ref, lse_ref, *, block_q,
 
 def _dkvq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                  dk_ref, dv_ref, dqt_ref, *, block_q, block_k, n_qb,
-                 causal, scale):
+                 causal, scale, window=None):
     """FUSED backward: one pass over the (q-block, k-block) pairs
     computes dk, dv AND dq, 5 block matmuls and 1 exp pass per pair
     (a dq kernel beside a dk/dv kernel would recompute s and dp: 7
@@ -321,7 +437,8 @@ def _dkvq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 preferred_element_type=jnp.float32) * scale
             if masked:
                 st = jnp.where(
-                    _after(jnp, ki * block_k, q0, block_k, block_q),
+                    _hidden(jnp, ki * block_k, q0, block_k, block_q,
+                            masked, window),
                     jnp.float32(-1e9), st)
             pt = jnp.exp(st - lse_ref[0, :, rows])  # lse: (1, bq)
             dv = dv + jnp.dot(pt.astype(dob.dtype), dob,
@@ -339,7 +456,10 @@ def _dkvq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     dk0 = jnp.zeros((bk, dh), jnp.float32)
     dv0 = jnp.zeros((bk, dh), jnp.float32)
-    if causal:
+    if window is not None:
+        # ... and stop at the last Q block that still sees this K block
+        spans = _band_spans_bwd(ki, block_q, block_k, window, n_qb)
+    elif causal:
         # Q blocks strictly above this K block's first column see only
         # masked scores — start below them; only the diagonal remnant
         # [lo, clear) needs the mask
@@ -389,16 +509,20 @@ def _tile_params(rows, s, dh, itemsize, interpret):
         vmem_limit_bytes=limit)}
 
 
-def _tile_mask(jnp, s):
+def _tile_mask(jnp, s, window=None):
     """(s, s) bool over (key, query): True where the key comes after
-    the query."""
+    the query or, under a ``window``, that many tokens or more before
+    it."""
     from jax import lax
-    return lax.broadcasted_iota(jnp.int32, (s, s), 0) \
-        > lax.broadcasted_iota(jnp.int32, (s, s), 1)
+    key = lax.broadcasted_iota(jnp.int32, (s, s), 0)
+    query = lax.broadcasted_iota(jnp.int32, (s, s), 1)
+    if window is None:
+        return key > query
+    return (key > query) | (query - key >= window)
 
 
 def _tile_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, rows,
-                     causal, scale):
+                     causal, scale, window=None):
     """SHORT-SEQUENCE forward: the whole (S, S) score square of a
     (batch, head) row is ONE tile, so there is no K loop and no
     running state — plain softmax in VMEM — and a program takes
@@ -416,7 +540,7 @@ def _tile_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, rows,
     import jax.numpy as jnp
 
     if causal:
-        after = _tile_mask(jnp, q_ref.shape[1])
+        after = _tile_mask(jnp, q_ref.shape[1], window)
     for r in range(rows):
         v = v_ref[r]
         st = jnp.dot(k_ref[r], q_ref[r].T,
@@ -433,7 +557,8 @@ def _tile_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, rows,
 
 
 def _tile_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                     dq_ref, dk_ref, dv_ref, *, rows, causal, scale):
+                     dq_ref, dk_ref, dv_ref, *, rows, causal, scale,
+                     window=None):
     """SHORT-SEQUENCE backward, the pair of ``_tile_fwd_kernel`` and
     transposed like it (lse and delta arrive as the (1, S) lane
     vectors they are stored as; dv and dk are plain products of the
@@ -445,7 +570,7 @@ def _tile_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     import jax.numpy as jnp
 
     if causal:
-        after = _tile_mask(jnp, q_ref.shape[1])
+        after = _tile_mask(jnp, q_ref.shape[1], window)
     for r in range(rows):
         q, k, v, do = q_ref[r], k_ref[r], v_ref[r], do_ref[r]
         st = jnp.dot(k, q.T, preferred_element_type=jnp.float32) * scale
@@ -488,8 +613,20 @@ def _specs(block_rows, s, dh):
     return blocked, full, full_lanes
 
 
+def _band(window, causal, s):
+    """``window`` as the kernels take it: None where it hides nothing
+    a causal row of length ``s`` would show (a window >= S is plain
+    causal, and runs today's kernels)."""
+    if window is None:
+        return None
+    if not causal or window < 1:
+        raise ValueError("a window (%r) is of a causal row and at "
+                         "least 1" % (window,))
+    return None if window >= s else int(window)
+
+
 def flash_attention_fwd(q, k, v, causal=True, block_q=128,
-                        block_k=128, interpret=None):
+                        block_k=128, interpret=None, window=None):
     """q/k/v: (B, H, S, dh) → (out (B, H, S, dh), lse (B, H, S)
     f32); exact. Blocks must divide S. ``interpret``: False = the
     real Mosaic kernel, True = the Pallas interpreter (how the CPU
@@ -511,6 +648,7 @@ def flash_attention_fwd(q, k, v, causal=True, block_q=128,
                          % (block_q, block_k, s))
     if interpret is None:
         interpret = not _on_tpu()
+    window = _band(window, causal, s)
     scale = numpy.float32(1.0 / numpy.sqrt(dh))
     flat = (b * h, s, dh)
     qf, kf, vf = (t.reshape(flat) for t in (q, k, v))
@@ -521,7 +659,8 @@ def flash_attention_fwd(q, k, v, causal=True, block_q=128,
         tensor, lanes = _tile_specs(rows, s, dh)
         out, lse = pl.pallas_call(
             functools.partial(_tile_fwd_kernel, rows=rows,
-                              causal=causal, scale=scale),
+                              causal=causal, scale=scale,
+                              window=window),
             grid=(b * h // rows,),
             in_specs=[tensor, tensor, tensor],
             out_specs=[tensor, lanes],
@@ -537,7 +676,8 @@ def flash_attention_fwd(q, k, v, causal=True, block_q=128,
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, block_q=block_q,
                           block_k=block_k, n_kb=s // block_k,
-                          causal=causal, scale=scale),
+                          causal=causal, scale=scale,
+                              window=window),
         grid=(b * h, s // block_q),
         in_specs=[blocked, full,
                   pl.BlockSpec((1, dh, s), lambda bh, i: (bh, 0, 0))],
@@ -552,7 +692,7 @@ def flash_attention_fwd(q, k, v, causal=True, block_q=128,
 
 def flash_attention_bwd(q, k, v, out, lse, dout, causal=True,
                         block_q=128, block_k=128, interpret=None,
-                        delta=None):
+                        delta=None, window=None):
     """Block-recomputation backward → (dq, dk, dv), exact. ``delta``:
     optional precomputed ``rowsum(dout*out)`` (B, H, S) f32 — callers
     that invoke this kernel repeatedly on the same out/dout (the ring's
@@ -578,6 +718,7 @@ def flash_attention_bwd(q, k, v, out, lse, dout, causal=True,
                          % (block_q, block_k, s))
     if interpret is None:
         interpret = not _on_tpu()
+    window = _band(window, causal, s)
     scale = numpy.float32(1.0 / numpy.sqrt(dh))
     flat = (b * h, s, dh)
     qf, kf, vf, dof = (t.reshape(flat) for t in (q, k, v, dout))
@@ -593,7 +734,8 @@ def flash_attention_bwd(q, k, v, out, lse, dout, causal=True,
         tensor, lanes = _tile_specs(rows, s, dh)
         dq, dk, dv = pl.pallas_call(
             functools.partial(_tile_bwd_kernel, rows=rows,
-                              causal=causal, scale=scale),
+                              causal=causal, scale=scale,
+                              window=window),
             grid=(b * h // rows,),
             in_specs=[tensor, tensor, tensor, tensor, lanes, lanes],
             out_specs=[tensor, tensor, tensor],
@@ -622,7 +764,8 @@ def flash_attention_bwd(q, k, v, out, lse, dout, causal=True,
     dk, dv, dq_t = pl.pallas_call(
         functools.partial(_dkvq_kernel, block_q=block_q,
                           block_k=block_k, n_qb=s // block_q,
-                          causal=causal, scale=scale),
+                          causal=causal, scale=scale,
+                              window=window),
         grid=(b * h, s // block_k),
         in_specs=[qfull, kblocked, kblocked, qfull, qfull_lanes,
                   qfull_lanes],
