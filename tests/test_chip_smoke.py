@@ -105,6 +105,17 @@ text = jax.jit(lambda h13, rows: gate(rows, h13)[0]).lower(
 assert " while(" in text and text.count("tpu_custom_call") == 1, text
 assert "bf16[65536,1536]{1,0:T(8,128)(2,1)} broadcast(" not in text, text
 print("COMPILED expert row stage", flush=True)
+# the thin share's combine (PR 40) at the Laguna cell's shape: one loop
+# of one-hot products on the MXU, no gather
+rows = 8192 * 10
+text = jax.jit(expert_ffn.pair_moves(10, True)[1]).lower(
+    jax.ShapeDtypeStruct((rows, 3072), jnp.bfloat16, sharding=on_chip),
+    *(jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=on_chip),) * 2,
+    jax.ShapeDtypeStruct((), jnp.int32, sharding=on_chip)
+).compile().as_text()
+assert text.count(" while(") == 1 and " gather(" not in text, text
+assert " convolution(" in text, text
+print("COMPILED thin-share combine", flush=True)
 # the delta rule's pass over chunks (parallel/pallas_delta.py) at the
 # Solar-Open2 cell's shape, a group of 8 heads of 128 x 128 over 64
 # chunks of 64: the plain forward, and the forward that keeps the
@@ -145,7 +156,7 @@ def test_every_pallas_variant_compiles_for_v5e(tmp_path):
         pytest.skip("libtpu gives no compile-only v5e topology here: "
                     + proc.stdout.strip()[:200])
     assert proc.returncode == 0, proc.stderr[-3000:]
-    assert proc.stdout.count("COMPILED ") == 8, proc.stdout
+    assert proc.stdout.count("COMPILED ") == 9, proc.stdout
 
 
 def test_smoke_refuses_a_machine_without_a_tpu():

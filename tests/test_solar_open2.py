@@ -27,7 +27,9 @@ from veles.znicz_tpu.ops.expert_ffn import ExpertFFN
 from veles.znicz_tpu.ops.gqa_attention import GQAttention
 
 from tests.test_conv_stack import xla_backward, xla_forward
-from tests.test_lfm2_moe import B, D, S, build
+from tests.test_lfm2_moe import B, D, S, build, rigged, step_aux
+from tests import test_lfm2_moe as lfm2
+from tests.test_lfm2_moe import chunk_of_16, poisoned_rows  # noqa: F401
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -159,6 +161,115 @@ def test_unit_against_reference(case, monkeypatch):
     tol = 2e-3 if "pallas" in case else 2e-5    # interpreted kernels
     check_unit(cls, kwargs, kind, dict(MODEL, **PATCHES.get(case, {})),
                tol=tol, prepare=prepare)
+
+
+# -- the thin share's combine: one-hot products over the real pairs ---------
+
+#: 2 of 24 experts held, 4 a token: ``sparse``, so ``combine`` runs over
+#: the chunks that hold a real pair (no shared expert: it adds outside)
+THIN = dict(lfm2.EXPERT, experts=24, top_k=4, experts_held=(3, 5))
+THIN_MODEL = dict(lfm2.MODEL, moe_experts=24, moe_top_k=4,
+                  experts_held=[3, 5])
+#: name: (the experts every token is made to select or None for the
+#: router's own choice, real pairs or None, CHUNK or None for 48: rows
+#: of 16)
+THIN_CASES = {
+    "no_held_pair_selected": ((6, 7, 8, 9), 0, None),
+    "rows_inside_a_chunk": (None, None, None),
+    # CHUNK 128: the T = 128 pairs of expert 3 are exactly one chunk
+    "one_full_chunk": ((3, 6, 7, 8), B * S, 128),
+    "every_held_pair_real": ((3, 4, 6, 7), B * S * 2, None),
+}
+
+
+def combine_forms_agree(rows, tokens, k, d, seed=0):
+    """``combine`` by products and by gathers on one buffer whose rows
+    past ``rows`` are NaN, against a float64 sum of the real rows: in
+    float32 (the cast a no-op) to float32 rounding of a sum of <= k
+    rows, in bf16 to the cast's rounding; and against each other."""
+    import jax.numpy as jnp
+    from veles.znicz_tpu.ops.expert_ffn import pair_moves
+    gen = numpy.random.RandomState(seed)
+    order = gen.permutation(tokens * k).astype(numpy.int32)
+    inv = numpy.argsort(order).astype(numpy.int32)
+    raw = gen.standard_normal((tokens * k, d)).astype(numpy.float32)
+    raw[rows:] = numpy.nan
+    sorting = (jnp.asarray(order), jnp.asarray(inv),
+               jnp.asarray(rows, jnp.int32))
+    for dtype, tol in ((jnp.float32, 4e-7 * k), (jnp.bfloat16, 2 ** -8)):
+        buffer = jnp.asarray(raw, dtype)
+        rounded = numpy.asarray(buffer.astype(jnp.float32), numpy.float64)
+        want = numpy.where((inv < rows)[:, None], rounded[inv], 0.0) \
+            .reshape(tokens, k, d).sum(1)
+        bound = tol * numpy.maximum(1.0, numpy.abs(want))
+        products, gathers = (numpy.asarray(
+            pair_moves(k, sparse)[1](buffer, *sorting).astype(jnp.float32))
+            for sparse in (True, False))
+        for form in (products, gathers):
+            assert (numpy.abs(form - want) <= bound).all()
+        assert (numpy.abs(products - gathers) <= bound).all()
+    assert rows or not products.any()
+
+
+@pytest.mark.parametrize("case", sorted(THIN_CASES))
+def test_thin_share_combine_reads_the_real_pairs(
+        case, poisoned_rows, chunk_of_16, monkeypatch):  # noqa: F811
+    """A thin held share at ``rows`` = 0, off a chunk's edge, one full
+    chunk and every held pair, the rows past them poisoned: output,
+    input gradient and every parameter's gradient against the
+    reference; the loop's trips, ``ceil(rows / chunk)``; and
+    ``combine``'s product form against its gather form on one buffer."""
+    from veles.znicz_tpu.ops import expert_ffn
+    chosen, pairs, chunk = THIN_CASES[case]
+    if chunk:
+        monkeypatch.setattr(expert_ffn, "CHUNK", chunk)
+    chunk = chunk or chunk_of_16
+    fwd, _ = lfm2.check_unit(ExpertFFN, THIN, THIN_MODEL, chosen=chosen)
+    assert fwd.sparse
+    feed, fwd, _, x, _, comp = build(ExpertFFN, **THIN)
+    _, outs = step_aux(fwd, comp, feed,
+                       rigged(fwd, comp.gather_params(), chosen), x)
+    rows = int(outs["moe_pairs_" + fwd.name])
+    if pairs is None:       # the router's own choice: off a chunk's edge
+        assert 0 < rows < B * S * 4 and rows % chunk
+    else:
+        assert rows == pairs
+    assert int(outs["moe_touched_" + fwd.name]) == -(-rows // chunk) * chunk
+    combine_forms_agree(rows, B * S, 4, D)
+
+
+@pytest.mark.parametrize("experts,top_k,held,sparse", [
+    (8, 2, (0, 8), False),      # LFM2's layer: every expert held
+    (8, 2, (2, 6), False),      # half held: the gathers still
+    (24, 4, (3, 5), True),      # 2 of 24: under an eighth
+    (320, 8, (0, 8), True),     # Solar-Open2's share, 8 of 320
+])
+def test_the_held_share_picks_the_combine(experts, top_k, held, sparse,
+                                          monkeypatch):
+    """``ExpertFFN.sparse`` (under an eighth of the experts held) picks
+    the product form, which holds a loop of one-hot products and no
+    gather; every other share traces the gathers, ``k`` of them and no
+    loop."""
+    import jax
+    import jax.numpy as jnp
+    from veles.znicz_tpu.ops import expert_ffn
+    asked = []
+    moves = expert_ffn.pair_moves
+    monkeypatch.setattr(expert_ffn, "pair_moves",
+                        lambda k, thin: asked.append((k, thin))
+                        or moves(k, thin))
+    feed, fwd, _, x, _, comp = build(
+        ExpertFFN, experts=experts, top_k=top_k, hidden=32,
+        experts_held=held)
+    assert fwd.sparse == sparse
+    step_aux(fwd, comp, feed, comp.gather_params(), x)
+    assert asked == [(top_k, sparse)]
+    n = B * S * top_k
+    jaxpr = str(jax.make_jaxpr(moves(top_k, sparse)[1])(
+        jnp.zeros((n, D), jnp.bfloat16), jnp.arange(n), jnp.arange(n),
+        jnp.int32(n)))
+    assert ("while[" in jaxpr, "dot_general[" in jaxpr) == (sparse,) * 2
+    assert jaxpr.count("gather[") == (0 if sparse else top_k)
 
 
 # -- the chunked recurrence against the token-by-token one -------------------
@@ -590,6 +701,32 @@ def test_counters_and_gauges_ride_the_metric_fetch(tiny_lm):
             assert registry.counter_total(
                 "veles_moe_pairs_total", layer=unit.name) == S * 2
     assert registry.counter_total("veles_moe_dropped_pairs_total") == 0
+
+
+@pytest.mark.parametrize("experts,top_k,held", [
+    (8, 2, [2, 6]),         # half held: the gathers read every row
+    (24, 4, [3, 5]),        # 2 of 24, sparse: the real pairs' chunks
+])
+def test_combine_rows_are_counted_by_the_form_that_ran(
+        tiny_lm, chunk_of_16, experts, top_k, held):  # noqa: F811
+    """After one step ``veles_moe_combine_rows_total`` reads the row
+    stages' count where the share is sparse, and ``T x k`` elsewhere
+    (where the row stages touched fewer)."""
+    root.lm.model.update({"moe_experts": experts, "moe_top_k": top_k,
+                          "experts_held": held})
+    wf = tiny_lm.create_workflow()
+    wf.initialize(device="cpu")
+    wf.run()
+    registry = telemetry.get_registry()
+    units = [u for u in wf.forwards if isinstance(u, ExpertFFN)]
+    assert len(units) == 4
+    for unit in units:
+        combined, touched = (registry.counter_total(
+            name, layer=unit.name) for name in (
+            "veles_moe_combine_rows_total", "veles_moe_rows_touched_total"))
+        assert 0 < touched < S * top_k and touched % chunk_of_16 == 0
+        assert combined == (touched if unit.sparse else S * top_k)
+    assert [u.sparse for u in units] == [experts > 8] * 4
 
 
 def test_kernel_chunks_are_counted_where_the_kernels_run(tiny_lm,

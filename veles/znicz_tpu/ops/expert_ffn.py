@@ -33,7 +33,11 @@ the chunks of ``CHUNK`` rows that hold a real pair
 (:func:`over_prefix`: a loop of ``ceil(rows / chunk)`` trips).
 Past the last chunk a buffer is not even written; the one reader of
 such rows, the token side of ``combine``, takes a pair past ``rows``
-as zero whatever its row holds. The buffers keep their worst-case
+as zero whatever its row holds. Where the held share is thin
+(``sparse``: under an eighth of the rows are expected to be real) it
+reads only the chunks that hold a real pair, as one-hot products in
+the same loop; elsewhere it gathers each token's ``k`` rows, all
+``T x k`` of them. The buffers keep their worst-case
 shapes, so a layer that holds every expert runs every chunk and
 nothing can drop. ``[W1 | W3]`` is one array, so the up-projections
 are one grouped product. Gradients reach the router through ``p``
@@ -49,7 +53,9 @@ Counters, advanced when the step's metrics are replayed on the host
 processed: trips x chunk, read from the loop itself; over
 ``veles_moe_buffer_rows{layer}``, the gauge of ``T x k``, it says how
 far the buffer is from the work, over the pairs what a chunk's
-rounding costs), ``veles_moe_dropped_pairs_total``
+rounding costs), ``veles_moe_combine_rows_total{layer}`` (rows of
+the buffer a step's ``combine`` read: the row stages' count where the
+share is sparse, ``T x k`` elsewhere), ``veles_moe_dropped_pairs_total``
 (:func:`misplaced_pairs`: held pairs whose row of the buffer lies
 outside their expert's group; 0 while sort, group sizes and buffer
 agree) and the gauge ``veles_moe_load_max_over_mean{layer}``.
@@ -96,21 +102,40 @@ def unwritten(shape, dtype, after):
         default=lambda after: jax.lax.empty(shape, dtype))
 
 
+def prefix_loop(trip, rows, size, carry):
+    """``i + 1, carry = trip(i, chunk, real, carry)`` for the chunks
+    ``i`` of a ``size``-row buffer that intersect ``[0, rows)``: a loop
+    of ``ceil(rows / chunk)`` trips, ``chunk`` the largest divisor of
+    ``size`` shared with ``CHUNK``. ``real`` (chunk, 1) says which rows
+    of the chunk lie inside the prefix: ``trip`` masks the tail of the
+    last one. -> (carry, rows touched = trips x chunk). ``trip`` counts
+    the index itself, between its work and its writes: the row stages
+    then trace the same program, operation for operation, as when the
+    loop was theirs alone (``tests/test_solar_open2.py`` hashes it)."""
+    import jax
+    import jax.numpy as jnp
+    chunk = math.gcd(size, CHUNK)
+
+    def body(state):
+        i, carry = state
+        real = (i * chunk + jnp.arange(chunk) < rows)[:, None]
+        return trip(i, chunk, real, carry)
+
+    trips, carry = jax.lax.while_loop(
+        lambda state: state[0] * chunk < rows, body,
+        (jnp.zeros((), rows.dtype), carry))
+    return carry, trips * chunk
+
+
 def over_prefix(fn, rows, reads, buffers):
     """The row chunks of ``buffers`` that intersect ``[0, rows)``, each
     replaced by ``fn(real, *chunks of reads, *chunks of buffers)``:
-    ``ceil(rows / chunk)`` trips of slice, work, update in place.
-    ``real`` (chunk, 1) says which rows of the chunk lie inside the
-    prefix: ``fn`` masks the tail of the last one. The other chunks
-    are left as they are: those of an :func:`unwritten` buffer hold no
-    defined value. -> (buffers, rows touched = trips x chunk)."""
+    :func:`prefix_loop`'s trips of slice, work, update in place. The
+    other chunks are left as they are: those of an :func:`unwritten`
+    buffer hold no defined value. -> (buffers, rows touched)."""
     import jax
-    import jax.numpy as jnp
-    chunk = math.gcd(buffers[0].shape[0], CHUNK)
 
-    def trip(carry):
-        i, buffers = carry
-        real = (i * chunk + jnp.arange(chunk) < rows)[:, None]
+    def trip(i, chunk, real, buffers):
         done = fn(real, *(
             jax.lax.dynamic_slice_in_dim(a, i * chunk, chunk)
             for a in reads + buffers))
@@ -118,10 +143,7 @@ def over_prefix(fn, rows, reads, buffers):
             jax.lax.dynamic_update_slice_in_dim(b, c, i * chunk, 0)
             for b, c in zip(buffers, done))
 
-    trips, buffers = jax.lax.while_loop(
-        lambda carry: carry[0] * chunk < rows, trip,
-        (jnp.zeros((), rows.dtype), buffers))
-    return buffers, trips * chunk
+    return prefix_loop(trip, rows, buffers[0].shape[0], buffers)
 
 
 def prefix_stage(fn):
@@ -157,14 +179,17 @@ def prefix_stage(fn):
 
 
 @functools.lru_cache(maxsize=None)
-def pair_moves(k):
+def pair_moves(k, sparse):
     """(dispatch, combine) for ``k`` experts a token: the two moves
     between the (T, d) tokens and the (T x k, d) buffer of token-expert
     pairs sorted by ``order`` (``inv`` its inverse), of which the first
-    ``rows`` are real. They are each other's transpose, and each is
-    written as a gather: left to jax, the transpose of a gather is a
-    scatter-add, which the TPU runs row by row and, in bf16, sums in
-    bf16."""
+    ``rows`` are real. They are each other's transpose, and neither is
+    left to jax: the transpose of a gather is a scatter-add, which the
+    TPU runs row by row and, in bf16, sums in bf16. ``sparse``: under
+    an eighth of the rows are expected to be real
+    (``ExpertFFN.sparse``), and ``combine`` reads only the chunks that
+    hold them, as one-hot products, where its gathers would read all
+    ``T x k`` rows."""
     import jax
     import jax.numpy as jnp
 
@@ -177,17 +202,42 @@ def pair_moves(k):
             rows, (order,),
             (unwritten((order.size,) + x.shape[1:], x.dtype, x),))[0][0]
 
-    @jax.custom_vjp
-    def combine(buffer, order, inv, rows):
-        """Token t: the sum of its k pairs' rows, added in float32;
-        a pair past ``rows`` adds nothing, whatever its row holds —
-        k gathers of T rows each: one gather of all T x k rows wants a
-        copy into (T, k, d) tiles before the sum (3.8 against 2.8 ms
-        on a v5e at 65,536 rows of 2048, PR 28)."""
+    def gathered(buffer, order, inv, rows):
+        # k gathers of T rows each: one gather of all T x k rows wants
+        # a copy into (T, k, d) tiles before the sum (3.8 against 2.8
+        # ms on a v5e at 65,536 rows of 2048, PR 28)
         where = inv.reshape(buffer.shape[0] // k, k).T
         return sum(jnp.where((where[j] < rows)[:, None],
                              buffer[where[j]], 0).astype(jnp.float32)
                    for j in range(k)).astype(buffer.dtype)
+
+    def by_products(buffer, order, inv, rows):
+        # a trip adds onehot(token of each row)^T @ its chunk: bf16
+        # operands, exact ones, a float32 sum
+        tokens = buffer.shape[0] // k
+
+        def trip(i, chunk, real, total):
+            pairs = jax.lax.dynamic_slice_in_dim(order, i * chunk, chunk)
+            onehot = pairs[:, None] // k == jnp.arange(tokens)
+            # masked BEFORE the product: a row past ``rows`` may hold
+            # anything, and 0 * NaN would reach its token
+            part = jnp.where(real, jax.lax.dynamic_slice_in_dim(
+                buffer, i * chunk, chunk), 0)
+            return i + 1, total + jax.lax.dot_general(
+                onehot.astype(buffer.dtype), part,
+                (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+
+        total, _ = prefix_loop(
+            trip, rows, buffer.shape[0],
+            jnp.zeros((tokens,) + buffer.shape[1:], jnp.float32))
+        return total.astype(buffer.dtype)
+
+    @jax.custom_vjp
+    def combine(buffer, order, inv, rows):
+        """Token t: the sum of its k pairs' rows, added in float32;
+        a pair past ``rows`` adds nothing, whatever its row holds."""
+        return (by_products if sparse else gathered)(
+            buffer, order, inv, rows)
 
     dispatch.defvjp(
         lambda x, *sorting: (dispatch(x, *sorting), sorting),
@@ -244,7 +294,7 @@ class ExpertFFN(VjpForward):
         #: under an eighth of the pair buffer's rows are expected to be
         #: real (a token's top_k experts, the share of them held here):
         #: keeping the buffers for the backward costs more than making
-        #: them again
+        #: them again, and ``combine`` reads the real pairs' chunks alone
         self.sparse = 8 * (hi - lo) < self.experts
         self.scaling = float(scaling)
         self.eps = float(eps)
@@ -302,7 +352,7 @@ class ExpertFFN(VjpForward):
                 dtype=jnp.int32)
             rows = sizes.sum()
             inv = jnp.argsort(order)
-            dispatch, combine = pair_moves(k)
+            dispatch, combine = pair_moves(k, self.sparse)
 
         # Every row stage runs over the chunks of the buffers that hold
         # real pairs (``over_prefix``), as the grouped products stop at
@@ -421,11 +471,17 @@ class ExpertFFN(VjpForward):
             "veles_moe_rows_touched_total", "Rows of the pair buffer an "
             "expert layer's row stages processed (chunks run x chunk)",
             ("layer",)).labels(self.name).inc(touched)
+        rows = self.input.size // self.input.shape[-1] * self.top_k
+        telemetry.counter(
+            "veles_moe_combine_rows_total", "Rows of the pair buffer an "
+            "expert layer's combine read (the row stages' chunks where "
+            "the held share is sparse, all of them elsewhere)",
+            ("layer",)).labels(self.name).inc(
+                touched if self.sparse else rows)
         telemetry.gauge(
             "veles_moe_buffer_rows", "Rows of an expert layer's pair "
             "buffer: tokens a step x experts a token, the worst case",
-            ("layer",)).labels(self.name).set(
-                self.input.size // self.input.shape[-1] * self.top_k)
+            ("layer",)).labels(self.name).set(rows)
 
     step_dropped = property(lambda self: None)
 
