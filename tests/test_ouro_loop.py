@@ -233,15 +233,33 @@ def test_recomputed_backward_equals_the_stored_one(lm_config):
     assert checked == 1 + 2 * 8 + 1 + 2 + 1
 
 
+#: the attention cores a test runs, as ``root.lm.model`` selects them
+CORES = {"dense": {}, "scan": {"attn_block": 16},
+         "pallas": {"attn_block": 16, "attn_impl": "pallas"}}
+
+
+def attention_layers(wf):
+    return [u.name for u in wf.forwards if isinstance(u, GQAttention)]
+
+
+def kept_cores(registry, wf):
+    return [registry.counter_total("veles_loop_kept_cores_total",
+                                   layer=name)
+            for name in attention_layers(wf)]
+
+
 def test_counters_and_exports_ride_the_metric_fetch(lm_config):
     _, make = lm_config
     wf = make()
     registry = telemetry.get_registry()
     before = registry.counter_total("veles_loop_steps_total")
     passes = registry.counter_total("veles_loop_passes_total")
+    kept = kept_cores(registry, wf)
     tree, _, history, _, train = trained(wf)
     assert registry.counter_total("veles_loop_steps_total") == before + 1
     assert registry.counter_total("veles_loop_passes_total") == passes + T
+    # the dense core keeps nothing: every recomputation runs it again
+    assert len(kept) == 2 and kept_cores(registry, wf) == kept
     text = registry.render_prometheus()
 
     def gauges(name):
@@ -264,6 +282,60 @@ def test_counters_and_exports_ride_the_metric_fetch(lm_config):
     # the step's "loss" is the total: expected cross entropy less the
     # entropy term, not any exit's own
     assert abs(history[0]["train"]["loss"] - (ce * mass).sum()) > 1e-3
+    # a flash core's every recomputed application takes the pass's
+    # kept (out, lse): T a layer and step, one for each pass
+    wf = make(**CORES["scan"])
+    kept = kept_cores(registry, wf)
+    wf.run()
+    assert [now - was for now, was in
+            zip(kept_cores(registry, wf), kept)] == [T, T]
+    root.lm.model.update({"attn_block": None})
+
+
+def equations(jaxpr, stack=""):
+    """Every equation of ``jaxpr`` and of the jaxprs inside it, with the
+    whole name stack it runs under: (equation, stack)."""
+    for eqn in jaxpr.eqns:
+        path = stack + "/" + str(eqn.source_info.name_stack)
+        yield eqn, path
+        for value in eqn.params.values():
+            for item in value if isinstance(value, (list, tuple)) \
+                    else [value]:
+                inner = getattr(item, "jaxpr", item)
+                if hasattr(inner, "eqns"):
+                    yield from equations(inner, path)
+
+
+#: the operation that is a core's kernel, by core
+KERNEL = {"dense": "dot_general", "scan": "scan", "pallas": "pallas_call"}
+
+
+@pytest.mark.parametrize("core", sorted(CORES))
+def test_recomputation_takes_the_flash_core_from_the_forward_pass(
+        lm_config, core):
+    """The backward pass's ``lax.scan`` body holds the attention core's
+    backward kernels and, on a flash core, no forward kernel: the
+    recomputed layer takes (out, lse) from the forward pass. The dense
+    core keeps nothing (its residual is S x S a head): its forward runs
+    again there."""
+    import jax
+    _, make = lm_config
+    wf = make(**CORES[core])
+    fn, args, _, _, _ = wf.xla_step._epoch_program(1)
+    backward = [(eqn, path) for eqn, path
+                in equations(jax.make_jaxpr(fn)(*args).jaxpr)
+                if eqn.primitive.name == "scan" and eqn.params["reverse"]]
+    assert len(backward) == 1
+    eqn, path = backward[0]
+    kernels = {"fwd": 0, "bwd": 0}
+    for inner, where in equations(eqn.params["jaxpr"].jaxpr, path):
+        if inner.primitive.name == KERNEL[core] and "veles.core" in where:
+            kernels["fwd" if "/veles.fwd.GQAttention." in where
+                    else "bwd"] += 1
+    layers = len(attention_layers(wf))
+    assert kernels["bwd"] >= layers
+    assert kernels["fwd"] == (2 * layers if core == "dense" else 0)
+    root.lm.model.update({"attn_block": None, "attn_impl": None})
 
 
 def test_metrics_published_names_the_sinks_a_step_filled():

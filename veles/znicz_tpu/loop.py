@@ -25,22 +25,30 @@ class Loop:
     forward units applied to each pass's state after the passes, up to
     the evaluator. A body unit's ``TAPS`` are values of each pass that
     the evaluator wants beside the exits (a gate); their cotangents
-    come back to the unit's gradient half as ``err_<tap>``.
+    come back to the unit's gradient half as ``err_<tap>``. A body unit
+    that sets ``kept`` in a forward pass's visit (one whose
+    ``pullbacks`` is False) declares a value its recomputation may take
+    from that pass instead of making it again: the loop stacks it out
+    of the passes, sets it in the unit's recompute visit of the same
+    pass before the unit runs there, and exports ``kept_<unit>``, the
+    recomputed applications a step that took it (a static count).
 
     The step it traces, every unit under its usual scope
     ``veles.<role>.<Class>.<name>`` in a visit of the step's context
     (:meth:`FlowContext.visit`):
 
     1. the passes, a ``lax.scan``: forward units alone, each
-       segment's input and the pass's state and taps stacked out;
+       segment's input, the pass's state, taps and kept values stacked
+       out;
     2. the exits, a ``lax.scan`` over the passes' states: exit units,
        the evaluator's visit (``xla_run``, fed what its ``loop_begin``
        made of the taps) and the exits' gradient units — one exit's
        logits live at a time; then the evaluator's ``loop_end`` closes
        the loss over all exits;
     3. the passes backward, a ``lax.scan`` in reverse: per segment the
-       recomputed forward, then its gradient units in reverse; the
-       state's cotangent is the carry, the exit's is added on entry;
+       recomputed forward (handed the pass's kept values), then its
+       gradient units in reverse; the state's cotangent is the carry,
+       the exit's is added on entry;
     4. ONE update: every looped gradient unit's solver on the float32
        sum of its visits' gradients (``veles.update``).
 
@@ -115,13 +123,17 @@ class Loop:
                         run(v, unit)
                     h = v.get(segment[-1], "output")
             tapped = {key: v.get(u, tap) for u, tap, key in taps}
-            return h, (tuple(saved) if train else (), h, tapped)
+            kept = {u.name: v.values[(u.name, "kept")] for u in self.body
+                    if train and (u.name, "kept") in v.values}
+            return h, (tuple(saved) if train else (), h, tapped, kept)
 
         h0 = ctx.get(self.body[0], "input")
-        _, (saved, states, tapped) = jax.lax.scan(
+        _, (saved, states, tapped, kept) = jax.lax.scan(
             forward_pass, h0, None, length=self.steps)
         for unit, tap, key in taps:
             ctx.set(unit, tap, tapped[key])
+        for name in kept:
+            ctx.export("kept_" + name, jnp.int32(self.steps))
 
         exit_gds = [gd_of[u] for u in reversed(self.exits)] \
             if train else []
@@ -151,7 +163,7 @@ class Loop:
 
         def backward_pass(carry, xs):
             dh, acc = carry
-            saved, dh_exit, dtapped = xs
+            saved, dh_exit, dtapped, kept = xs
             v = ctx.visit(grads=acc)
             dh = (dh.astype(jnp.float32) + dh_exit.astype(jnp.float32)) \
                 .astype(dh.dtype)
@@ -163,10 +175,15 @@ class Loop:
                     # cotangent it will meet: left free, the scheduler
                     # may run every segment's forward first and hold
                     # all their residuals at once
-                    x, dh = jax.lax.optimization_barrier((x, dh))
+                    handed = {u.name: kept[u.name] for u in segment
+                              if u.name in kept}
+                    x, dh, handed = jax.lax.optimization_barrier(
+                        (x, dh, handed))
                     with jax.named_scope("veles.recompute"):
                         v.set(segment[0], "input", x)
                         for unit in segment:
+                            if unit.name in handed:
+                                v.set(unit, "kept", handed[unit.name])
                             run(v, unit)
                     v.set(gd_of[segment[-1]], "err_output", dh)
                     for unit in reversed(segment):
@@ -182,7 +199,8 @@ class Loop:
             backward_pass, (jnp.zeros_like(h0),
                             self.zeros(ctx, body_gds)),
             (saved, dh_exits,
-             {key: dtaps[tap] for _, tap, key in taps}), reverse=True)
+             {key: dtaps[tap] for _, tap, key in taps}, kept),
+            reverse=True)
         sums = dict(exit_acc, **body_acc)
         for gd in exit_gds + body_gds:
             if sums[gd.name]:

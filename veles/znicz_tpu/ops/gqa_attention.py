@@ -358,9 +358,21 @@ class GQAttention(VjpForward, MultiHeadAttention):
             return self.finish(ctx, post, merged, *gate)
 
         q, k, v, *gate = self.traced(ctx, project, pre, x)
-        kr, vr = self.repeated(k, v)
-        with _core_scope():
-            out, saved = self.attend(ctx, mode, q, kr, vr)
+        kept = ctx.get(self, "kept")
+        if kept is not None:
+            # a loop's recomputation, handed the core's (out, lse) by
+            # the forward pass that made them: no second forward kernel
+            out, saved = kept[0], kept
+        else:
+            kr, vr = self.repeated(k, v)
+            with _core_scope():
+                out, saved = self.attend(ctx, mode, q, kr, vr)
+            if ctx.train and not ctx.pullbacks \
+                    and mode in ("pallas", "scan"):
+                # a loop's forward pass, whose backward recomputes this
+                # unit: the flash cores' residual is O(S) a row, worth
+                # keeping (``Loop``'s ``kept``); the dense core's is S²
+                ctx.set(self, "kept", saved)
         y = self.traced(ctx, finish, post, self._merge(out), *gate,
                         has_aux=self.gate == "head")
         if self.gate == "head":
@@ -394,10 +406,17 @@ class GQAttention(VjpForward, MultiHeadAttention):
         return ([("attn_gate_" + self.name, "step_gate")]
                 if self.gate == "head" else []) + (
             [("window_visited_" + self.name, "step_visited")]
-            if self.window is not None else [])
+            if self.window is not None else []) + [
+            ("kept_" + self.name, "step_kept")]
 
     def metrics_published(self, fresh):
         """``XLAStep``'s hook, once a training step's sinks are filled."""
+        if "step_kept" in fresh:
+            telemetry.counter(
+                "veles_loop_kept_cores_total", "Recomputed applications "
+                "of an attention layer that took the core's out and lse "
+                "from the loop's forward pass, training steps",
+                ("layer",)).labels(self.name).inc(self.step_kept)
         if "step_gate" in fresh:
             telemetry.gauge(
                 "veles_attn_gate_mean", "Last step: mean of an "
