@@ -535,6 +535,111 @@ def test_pairwise_tensor_is_a_diagonal_block(c, block):
         == c // block * sizes(block).count(pairwise)
 
 
+# -- the chunks' triangular system: products against triangular_solve ------
+
+#: ``product_solve``'s error against float64 over ``triangular_solve``'s
+#: own, for the solution and both cotangents, at most (read 0.17-2.35
+#: on the cases below: aligned keys are the worst, where the block
+#: products cancel to the alternating inverse of ``I + 1.99 N``)
+SOLVE_FACTOR = 4.0
+#: ... over an error no smaller than this (float32's epsilon), so a
+#: solve that is exact by chance does not set the bar at zero
+SOLVE_FLOOR = 2.0 ** -23
+
+SOLVE_CASES = dict(
+    {case: RECURRENCE_CASES[case] + (64, False)
+     for case in RECURRENCE_CASES},
+    # every k_t the same unit vector and no decay: |L_ts| = b = 1.99
+    aligned_keys=(0.0, 0.01, 1.99, 64, True),
+    # 16 does not divide 72: one block, a sequence that is one chunk
+    chunk_72=(1.0, 0.05, 1.0, 72, False))
+
+
+def chunk_system(case, n=512, dk=128, dv=128):
+    """``n`` chunks' systems as ``chunked_delta_rule`` makes them, in
+    float64: ``L = diag(b) A`` (diagonal and all), ``R = diag(b) [V |
+    exp(G) K]``; and a cotangent for the solution."""
+    scale, dt, step, c, aligned = SOLVE_CASES[case]
+    gen = numpy.random.RandomState(23)
+    k = unit_rows(gen.randn(n, 1 if aligned else c, dk))
+    k = numpy.broadcast_to(k, (n, c, dk))
+    a = decays(gen, (n, c, dk), scale, dt) if scale else numpy.zeros(
+        (n, c, dk))
+    g = numpy.cumsum(a, 1)
+    l = numpy.zeros((n, c, c))
+    for t in range(c):      # decays masked before the exponential
+        l[:, t, :t + 1] = (k[:, t, None] * k[:, :t + 1] * numpy.exp(
+            g[:, t, None] - g[:, :t + 1])).sum(-1)
+    l *= step
+    r = step * numpy.concatenate(
+        [gen.randn(n, c, dv), numpy.exp(g) * k], -1)
+    return l, r, gen.randn(n, c, dv + dk)
+
+
+def substitute(l, r):
+    """``(I + strict_lower(l))^-1 r`` in float64, row by row: numpy's
+    elementwise loops and no BLAS or LAPACK, whose threads stall when
+    several test processes share the cores."""
+    w = numpy.zeros_like(r)
+    for t in range(r.shape[-2]):
+        w[:, t] = r[:, t] - (l[:, t, :t, None] * w[:, :t]).sum(-2)
+    return w
+
+
+@pytest.mark.parametrize("case", sorted(SOLVE_CASES))
+def test_product_solve_against_triangular_solve_and_float64(case):
+    """At the cell's widths (512 chunks' systems of 64 in blocks of 16,
+    right-hand sides 256 wide): the solution and both cotangents of
+    ``product_solve`` and of ``triangular_solve`` against float64 on
+    the same float32 inputs; the products' error is within
+    ``SOLVE_FACTOR`` of the solve's; in chunks of 64, and in one chunk
+    of 72 that no block divides."""
+    import jax
+    import jax.numpy as jnp
+    l, r, dw = (numpy.asarray(t, numpy.float32).astype(numpy.float64)
+                for t in chunk_system(case))
+    w = substitute(l, r)
+    dr = substitute(l.swapaxes(-1, -2)[..., ::-1, ::-1],
+                    dw[..., ::-1, :])[..., ::-1, :]
+    want = (w, dr, -numpy.tril(numpy.einsum("nik,njk->nij", dr, w), -1))
+    if case == "aligned_keys":
+        assert numpy.abs(numpy.tril(l, -1)).max() > 1.98
+
+    def solve_by(solver):
+        def total(l, r):
+            w = solver(l, r)
+            return (w * jnp.asarray(dw, jnp.float32)).sum(), w
+        (_, w), (dl, dr) = jax.jit(jax.value_and_grad(
+            total, argnums=(0, 1), has_aux=True))(
+            *(jnp.asarray(t, jnp.float32) for t in (l, r)))
+        return [float(numpy.abs(numpy.asarray(got, numpy.float64)
+                                - theirs).max() / numpy.abs(theirs).max())
+                for got, theirs in zip((w, dr, dl), want)]
+
+    mine = solve_by(delta.product_solve)
+    theirs = solve_by(lambda l, r: jax.lax.linalg.triangular_solve(
+        l, r, left_side=True, lower=True, unit_diagonal=True))
+    for got, bar in zip(mine, theirs):
+        assert got <= SOLVE_FACTOR * max(bar, SOLVE_FLOOR), (mine, theirs)
+
+
+@pytest.mark.parametrize("block", [
+    16,     # the cell's chunk of 64 in blocks of 16
+    64,     # the chunk is one block
+])
+def test_cpu_layer_at_the_cells_widths_holds_no_solve(block, monkeypatch):
+    """A delta-rule layer of 2 heads of 128 over one chunk of 64,
+    forward + backward lowered for the CPU: no ``triangular_solve`` in
+    the program (the op's name, or the LAPACK call it lowers to on the
+    CPU), whether blocks cut the chunk or not."""
+    monkeypatch.setattr(delta, "CHUNK", 64)
+    monkeypatch.setattr(delta, "BLOCK", block)
+    text = program_text(DeltaAttention, dict(DELTA, heads=2, head_dim=128),
+                        debug_info=True)
+    assert not re.search(r"custom_call @lapack_\w*trsm", text)
+    assert "triangular_solve" not in text
+
+
 # -- the share sums to the model ---------------------------------------------
 
 
@@ -754,6 +859,31 @@ def test_kernel_chunks_are_counted_where_the_kernels_run(tiny_lm,
         assert registry.counter_total(
             "veles_delta_kernel_chunks_total", layer=layer) == S // 16
     got = wf.decision.history[0]
+    for phase in ("validation", "train"):
+        assert abs(got[phase]["loss"] - want[phase]["loss"]) < 1e-5
+
+
+def test_tiny_lm_trains_to_the_triangular_solves_loss(tiny_lm,
+                                                     monkeypatch):
+    """The tiny LM's step with the chunks' systems by products (chunks
+    of 16 in blocks of 4) trains to the losses of the same step with
+    ``triangular_solve`` in their place, to 1e-5."""
+    import jax
+
+    def run():
+        prng.seed_all(5)
+        wf = tiny_lm.create_workflow()
+        wf.initialize(device="cpu")
+        wf.run()
+        return wf.decision.history[0]
+
+    monkeypatch.setattr(delta, "BLOCK", 4)
+    with telemetry.scoped():
+        got = run()
+        monkeypatch.setattr(delta, "product_solve", lambda l, r: (
+            jax.lax.linalg.triangular_solve(
+                l, r, left_side=True, lower=True, unit_diagonal=True)))
+        want = run()
     for phase in ("validation", "train"):
         assert abs(got[phase]["loss"] - want[phase]["loss"]) < 1e-5
 

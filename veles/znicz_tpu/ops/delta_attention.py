@@ -54,9 +54,18 @@ bounded. Where ``BLOCK`` does not divide the chunk (a sequence that is
 one chunk of an odd length) the chunk is one block.
 
 The triangular system (the delta rule inside a chunk) is solved for
-all chunks at once, it does not depend on the state; the three lines
-that do are the pass over chunks, :func:`state_pass`: one algorithm,
-two implementations. Where the step compiles for a TPU, ``dk`` and
+all chunks at once, it does not depend on the state:
+:func:`product_solve`, the inverse of ``I + diag(b) A`` by forward
+substitution over blocks that double (:func:`unit_lower_inverse`), then
+one product with the right-hand side, all float32 products at
+``Precision.HIGHEST``, under one ``jax.custom_vjp`` that keeps the
+inverse and the solution: its backward is two products and no solve.
+The inverse is ``C^3 log2 C`` multiply-adds a chunk, against ``C^2 (dk
++ dv)`` for a substitution: as much at ``CHUNK``, and on the matrix
+unit; a sequence that is one long chunk (an odd length) pays the
+difference. The three lines that depend on the state are the pass over
+chunks, :func:`state_pass`: one algorithm, two implementations. Where
+the step compiles for a TPU, ``dk`` and
 ``dv`` are whole 128-lane tiles and the chunks hold ``CHUNK`` tokens
 (:func:`state_pass_kernels`: the shape and the platform decide, no
 key) it is a pair of Pallas kernels under one ``jax.custom_vjp``
@@ -96,6 +105,7 @@ recurrence that blows up under the negative eigenvalues ``b > 1``
 allows shows here before the loss does).
 """
 
+import functools
 import math
 
 import numpy
@@ -196,6 +206,83 @@ def chunk_scores(q, k, g):
     return tuple(jnp.concatenate(side, -2) for side in zip(*rows))
 
 
+def _product(a, b):
+    import jax
+    import jax.numpy as jnp
+    return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
+
+
+def unit_lower_inverse(l):
+    """``(I + L)^-1`` for ``l`` (..., C, C) float32, of which the
+    strictly lower triangle is ``L``: forward substitution by blocks
+    that double, on whole C x C matrices. Where ``T_m`` inverts every
+    diagonal block of ``m`` rows of ``I + L`` (zeros elsewhere), the
+    inverse of two neighbouring blocks is
+
+        [[T_1, 0], [-T_2 L_21 T_1, T_2]] = (I - P_m(T_m L)) T_m
+
+    ``P_m`` keeping the blocks of ``m`` below the diagonal blocks of
+    ``m`` inside each block of ``2 m``. So ``N_m = T_m L`` follows
+    ``N_2m = (I - P_m(N_m)) N_m``, ONE batched product a level, from
+    ``N_1 = L`` to the whole chunk, and ``T = I - N_C`` (``T (I + L) =
+    I``). Every value made is a block of a partial inverse times ``L``:
+    no power of ``L`` appears, so nothing grows past what the inverse
+    itself holds. The identity rides inside the product, so a level's
+    output is the new ``N`` and the one before it dies: XLA fuses a
+    subtraction after the product into every later reader and then
+    keeps every level's product alive (on a v5e, six 16 MB buffers a
+    head group)."""
+    import jax
+    import jax.numpy as jnp
+    c = l.shape[-1]
+    row = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    # below 2 m where row and column lie in one block of 2 m, at least
+    # m where they lie in different blocks of m
+    apart = row ^ col
+    eye = (row == col).astype(l.dtype)
+    n = jnp.where(row > col, l, 0.0)
+    m = 1
+    while m < c:
+        n = _product(
+            eye - jnp.where((apart >= m) & (apart < 2 * m), n, 0.0), n)
+        m *= 2
+    return eye - n
+
+
+def _solve_forward(l, r):
+    t = unit_lower_inverse(l)
+    w = _product(t, r)
+    return w, (t, w)
+
+
+def _solve_backward(saved, dw):
+    import jax
+    import jax.numpy as jnp
+    t, w = saved
+    with jax.named_scope("veles.delta"):
+        dr = _product(t.swapaxes(-1, -2), dw)
+        return -jnp.tril(_product(dr, w.swapaxes(-1, -2)), -1), dr
+
+
+def product_solve(l, r):
+    """``W`` with ``(I + L) W = R`` for ``l`` (..., C, C), of which the
+    strictly lower triangle is ``L``, and ``r`` (..., C, m), float32:
+    ``T R`` with ``T`` = :func:`unit_lower_inverse`, float32 products
+    at ``Precision.HIGHEST``. Under one ``jax.custom_vjp`` that keeps
+    ``T`` and ``W``: ``dR = T^T dW``, ``dL = -strict_lower(dR W^T)``,
+    two products and no solve."""
+    return _product_solve()(l, r)
+
+
+@functools.lru_cache(maxsize=None)
+def _product_solve():
+    import jax
+    solve = jax.custom_vjp(lambda l, r: _solve_forward(l, r)[0])
+    solve.defvjp(_solve_forward, _solve_backward)
+    return solve
+
+
 def delta_rule(q, k, v, a, beta, kernels=None):
     """The recurrence above for ``q, k, a`` (B, S, H, dk), ``v``
     (B, S, H, dv), ``beta`` (B, S, H), float32, from a zero state;
@@ -246,9 +333,8 @@ def chunked_delta_rule(q, k, v, a, beta, kernels=None):
         jax.checkpoint(lambda x: chunk_scores(*x), prevent_cse=False),
         (q, k, g), batch_size=CHUNKS_AT_ONCE)
     grown = jnp.exp(g)
-    w = jax.lax.linalg.triangular_solve(
-        beta * scores, beta * jnp.concatenate([v, grown * k], -1),
-        left_side=True, lower=True, unit_diagonal=True)
+    w = product_solve(
+        beta * scores, beta * jnp.concatenate([v, grown * k], -1))
     last = g[..., -1:, :]
     o, state = state_pass(
         w[..., :dv], w[..., dv:], grown * q, reads, jnp.exp(last - g) * k,
