@@ -58,9 +58,10 @@ BUDGET_S = 1150.0
 
 #: ONE table of sizes: every phase's command line and every kernel
 #: shape is built from an entry here. "full" is what the chip runs
-#: (bench.py LM_ROWS["110M"] / ["110M_s8k"]); "tiny" is the same lines
-#: at toy widths, run on the CPU by tests/test_chip_smoke.py so a typo
-#: is found there and not on chip time.
+#: (the benchmark's lm110m configuration has these widths); "tiny" is
+#: the same lines at toy widths, run on the CPU by
+#: tests/test_chip_smoke.py so a typo is found there and not on chip
+#: time.
 SIZES = {
     "full": {
         "model": {"dim": 768, "heads": 12, "layers": 12,

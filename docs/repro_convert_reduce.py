@@ -57,11 +57,10 @@ Variant definitions:
 PALLAS-KERNEL OUTCOME (ISSUE 14): exactness is pinned on CPU
 interpret mode (``tests/test_pallas_grads.py``, atol at the existing
 gd bounds) and, compiled for the chip, by ``chip_smoke.py``'s kernel
-phase; ``bench.py`` tracks ``bias_grad_step_seconds``. The in-program
-step delta on a v5e is NOT MEASURED: this script times `kernel` /
-`ctx_kernel` alongside the original variants, so one run on a TPU
-fills the table, and the comparison to make is ``ctx_kernel −
-ctx_nobias`` against ``ctx − ctx_nobias``.
+phase. The in-program step delta on a v5e is NOT MEASURED: this
+script times `kernel` / `ctx_kernel` alongside the original variants,
+so one run on a TPU fills the table, and the comparison to make is
+``ctx_kernel − ctx_nobias`` against ``ctx − ctx_nobias``.
 """
 
 import sys

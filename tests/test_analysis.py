@@ -2299,21 +2299,18 @@ def test_cli_precommit_invocation(tmp_path, capsys):
 
 
 def test_repo_wide_zero_findings_gate():
-    """THE gate: the whole veles package — and bench.py, which
-    builds samples from target-advertised geometry — stays at zero
-    findings, the four taint rules included.
+    """THE gate: the whole veles package stays at zero findings, the
+    four taint rules included.
 
-    If this fails, `velescli lint veles bench.py` reproduces it
-    locally with file:line + a fix hint per finding. Fix the code,
-    or — for a documented false positive / deliberate design — add
+    If this fails, `velescli lint veles` reproduces it locally with
+    file:line + a fix hint per finding. Fix the code, or — for a
+    documented false positive / deliberate design — add
     `# zlint: disable=RULE (reason)` on the flagged line."""
     import veles
     pkg = os.path.dirname(os.path.abspath(veles.__file__))
-    repo = os.path.dirname(pkg)
-    findings = analyze_paths([pkg, os.path.join(repo, "bench.py")],
-                             base=repo)
+    findings = analyze_paths([pkg], base=os.path.dirname(pkg))
     assert findings == [], (
-        "zlint found %d violation(s) in veles/ + bench.py:\n%s"
+        "zlint found %d violation(s) in veles/:\n%s"
         % (len(findings), "\n".join(f.render() for f in findings)))
 
 

@@ -561,36 +561,3 @@ class _DeadThread:
     @staticmethod
     def is_alive():
         return False
-
-
-# -- bench acceptance (slow soak) --------------------------------------
-
-
-@pytest.mark.slow
-def test_bench_continuous_beats_sequential_2x():
-    """ISSUE 11 acceptance: >=2x aggregate tokens/s for continuous
-    batching over sequential per-request decode at 8 concurrent
-    streams (the bench row's own code path; measured ~7x on the CI
-    container)."""
-    import bench
-    seq, cont, first = bench.generate_decode_tokens_per_sec()
-    assert cont >= 2.0 * seq, (seq, cont)
-    assert first is not None and first < 5.0
-
-
-def test_bench_generate_rows_shape(monkeypatch):
-    """The bench wrapper records the three keys (or one error key)
-    and the directionality table knows first-token latency is a
-    cost."""
-    import bench
-    assert any(s in "generate_first_token_latency_s"
-               for s in bench._LOWER_BETTER)
-    monkeypatch.setattr(
-        bench, "generate_decode_tokens_per_sec",
-        lambda **kw: (100.0, 400.0, 0.02))
-    extra = {}
-    bench._generate_rows(extra)
-    assert extra == {
-        "generate_tokens_per_sec_sequential": 100.0,
-        "generate_tokens_per_sec_continuous": 400.0,
-        "generate_first_token_latency_s": 0.02}

@@ -1,8 +1,8 @@
 """Cluster health plane (ISSUE 8): the time-series ring, readiness
 probes, SLO burn-rate alerts, the fleet scraper (`velescli top`), the
-503+Retry-After rejection path, trace-correlated JSONL logs and the
-bench self-check — unit level first, then the end-to-end chaos
-acceptance run (master + 2 slaves under ChaosProxy).
+503+Retry-After rejection path and trace-correlated JSONL logs — unit
+level first, then the end-to-end chaos acceptance run (master + 2
+slaves under ChaosProxy).
 
 Determinism: unit-level SLO/ring tests drive ``HealthMonitor.tick``
 with injected timestamps (no sampler thread, no wall-clock luck); the
@@ -511,118 +511,6 @@ def test_top_once_renders_dashboard(capsys):
     assert rc == 0
     out = capsys.readouterr().out
     assert "veles fleet" in out and "TARGET" in out
-
-
-# -- bench self-check --------------------------------------------------
-
-
-def test_bench_self_check_flags_directional_regressions(tmp_path,
-                                                        capsys):
-    import bench
-    baseline = {
-        "n": 1, "rc": 0,
-        "parsed": {
-            "metric": "mnist_train_steps_per_sec", "value": 1000.0,
-            "extra": {
-                "cifar_conv_images_per_sec": 200.0,
-                "grad_sync_wire_bytes_per_step_int8": 100000,
-                "lm_57M_tokens_per_sec": 50000.0,
-                "lm_57M_tokens_per_sec_best": 60000.0,
-                "calibration_matmul8k_bf16_tflops": 150.0,
-                "dist_scaling_steps_per_sec_n2": 100.0,
-                "dist_scaling_efficiency_n2": 0.8,
-                "profiler_overhead_pct": 1.0,
-                "generate_tokens_per_sec_continuous": 4000.0,
-                "generate_first_token_latency_s": 0.01,
-                "lm_mfu_s8192": 0.35,
-                "bias_grad_step_seconds": 0.002,
-                "serving_cache_bytes_int8": 200000,
-                "serving_throughput_rps_int8": 3000.0,
-                "model_stats_overhead_pct": 0.5,
-                "loadgen_shed_rate_pct": 1.0,
-                "serving_rejected_per_sec": 10.0,
-                "routed_capacity_rps_at_p99_slo": 100.0,
-                "lint_full_tree_seconds": 10.0,
-                "lint_full_tree_warm_seconds": 2.0,
-                "some_row_error": "boom",
-            }}}
-    path = tmp_path / "BENCH_r07.json"
-    path.write_text(json.dumps(baseline))
-    report = {
-        "metric": "mnist_train_steps_per_sec", "value": 800.0,
-        "extra": {
-            "cifar_conv_images_per_sec": 195.0,       # -2.5%: fine
-            "grad_sync_wire_bytes_per_step_int8": 150000,  # +50%: bad
-            "lm_57M_tokens_per_sec": 55000.0,         # +10%: fine
-            # ISSUE 9: scaling rows are throughput/efficiency figures
-            # — DOWN is the bad direction for both families
-            "dist_scaling_steps_per_sec_n2": 50.0,    # -50%: bad
-            "dist_scaling_efficiency_n2": 0.4,        # -50%: bad
-            # ISSUE 10: profiler overhead is a COST — UP is bad
-            "profiler_overhead_pct": 2.5,             # +150%: bad
-            # ISSUE 11: decode throughput DOWN and first-token
-            # latency UP are the bad directions
-            "generate_tokens_per_sec_continuous": 2000.0,  # -50%: bad
-            "generate_first_token_latency_s": 0.05,        # +400%: bad
-            # ISSUE 14: an MFU ratio is a utilization figure — DOWN
-            # is bad (explicitly in bench._HIGHER_BETTER, immune to
-            # any lower-better substring); kernel step seconds and
-            # the quantized cache footprint are costs — UP is bad;
-            # quantized serving rps is throughput — DOWN is bad
-            "lm_mfu_s8192": 0.20,                          # -43%: bad
-            "bias_grad_step_seconds": 0.004,               # +100%: bad
-            "serving_cache_bytes_int8": 400000,            # +100%: bad
-            "serving_throughput_rps_int8": 3300.0,         # +10%: fine
-            # ISSUE 15: in-graph model-stat cost is an overhead — UP
-            # is the bad direction ("overhead" is in _LOWER_BETTER)
-            "model_stats_overhead_pct": 1.8,               # +260%: bad
-            # ISSUE 18: shed/rejected rates are costs — UP is bad;
-            # routed capacity carries a "p99" substring but is a
-            # capacity figure (bench._HIGHER_BETTER) — DOWN is bad
-            "loadgen_shed_rate_pct": 5.0,                  # +400%: bad
-            "serving_rejected_per_sec": 20.0,              # +100%: bad
-            "routed_capacity_rps_at_p99_slo": 50.0,        # -50%: bad
-            # ISSUE 20: lint wall times are costs ("seconds" is in
-            # _LOWER_BETTER) — a warm-cache regression means the
-            # incremental cache stopped earning its keep
-            "lint_full_tree_seconds": 9.0,                 # -10%: fine
-            "lint_full_tree_warm_seconds": 6.0,            # +200%: bad
-        }}
-    regressed = bench.self_check(report, threshold_pct=10.0,
-                                 baseline_path=str(path))
-    err = capsys.readouterr().err
-    # throughput DOWN 20% and byte-count UP 50% regress; the small
-    # dip, the improvement, _best and calibration keys don't
-    assert set(regressed) == {"mnist_train_steps_per_sec",
-                              "grad_sync_wire_bytes_per_step_int8",
-                              "dist_scaling_steps_per_sec_n2",
-                              "dist_scaling_efficiency_n2",
-                              "profiler_overhead_pct",
-                              "generate_tokens_per_sec_continuous",
-                              "generate_first_token_latency_s",
-                              "lm_mfu_s8192",
-                              "bias_grad_step_seconds",
-                              "serving_cache_bytes_int8",
-                              "model_stats_overhead_pct",
-                              "loadgen_shed_rate_pct",
-                              "serving_rejected_per_sec",
-                              "routed_capacity_rps_at_p99_slo",
-                              "lint_full_tree_warm_seconds"}
-    assert "REGRESSION" in err and "warn-only" in err
-    assert "_best" not in err.split("rows in baseline")[0]
-    # no baseline -> a note, no crash, nothing regressed
-    assert bench.self_check(report, baseline_path=str(
-        tmp_path / "missing.json")) == []
-
-
-def test_bench_latest_artifact_natural_order(tmp_path):
-    import bench
-    for n in (2, 10, 9):
-        (tmp_path / ("BENCH_r%02d.json" % n)).write_text("{}")
-    assert bench._latest_bench_artifact(str(tmp_path)).endswith(
-        "BENCH_r10.json")
-    assert bench._latest_bench_artifact(
-        str(tmp_path / "empty")) is None
 
 
 # -- snapshot-store breaker flips /readyz ------------------------------

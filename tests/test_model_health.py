@@ -815,15 +815,3 @@ def test_e2e_divergence_rollback_two_slaves():
     events = [e for e in telemetry.tracer.recent_events(100)
               if e.get("event") == "model_divergence"]
     assert any(e.get("verdict") == "diverged" for e in events)
-
-
-# -- bench row ---------------------------------------------------------
-
-
-@pytest.mark.slow
-def test_model_stats_overhead_row_under_acceptance():
-    """The bench acceptance (<2%) on this container — slow-marked:
-    the off-on-off loop compiles three program variants."""
-    import bench
-    pct = bench.model_stats_overhead_pct(measure_chunks=2)
-    assert 0.0 <= pct < 2.0, pct

@@ -1,7 +1,7 @@
 """Real-dataset auto-ingest (VERDICT r2 item 4): staging idx/binary
 files under root.common.dirs.datasets switches every loader off the
 synthetic stand-ins with ZERO code changes; provenance records the
-source + validation level so bench numbers stay labelled."""
+source + validation level so every number names its data."""
 
 import gzip
 import struct
@@ -104,7 +104,7 @@ def test_workflow_trains_on_staged_real_data(staged_datasets):
 
 
 def test_bench_json_carries_data_tag(staged_datasets):
-    """bench.py labels which data fed each number."""
+    """The provenance record labels which data fed a run."""
     datasets.load_mnist(n_train=30, n_valid=10)
     tags = {k: v.get("source")
             for k, v in datasets.data_provenance().items()}

@@ -12,6 +12,7 @@ import numpy
 import pytest
 
 import veles.prng as prng
+from veles import telemetry
 from veles.config import root
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -403,6 +404,31 @@ def test_wire_protocol_carries_all_params():
     gd.apply_data_from_slave(update)
     numpy.testing.assert_allclose(
         fwd.weights_out.mem, before + 0.25, atol=1e-6)
+
+
+def test_wire_bytes_counted_per_frame():
+    """veles_wire_bytes_total accounts every frame both ways."""
+    import socket
+    import threading
+    from veles.server import recv_frame, send_frame
+    a, b = socket.socketpair()
+    try:
+        reg = telemetry.get_registry()
+        payload = ("job", {"x": numpy.zeros(64).tolist()}, 1, 0)
+        got = []
+        t = threading.Thread(target=lambda: got.append(recv_frame(b)))
+        t.start()
+        send_frame(a, payload)
+        t.join(timeout=10)
+        assert got and got[0] == payload
+        tx = reg.counter_total("veles_wire_bytes_total",
+                               direction="tx")
+        rx = reg.counter_total("veles_wire_bytes_total",
+                               direction="rx")
+        assert tx == rx and tx > 36     # header+tag+payload
+    finally:
+        a.close()
+        b.close()
 
 
 def test_drop_slave_requeues():

@@ -686,14 +686,3 @@ def test_http_snapshot_store_lists_absolute_url_hrefs(caplog):
     finally:
         httpd.shutdown()
         httpd.server_close()
-
-
-def test_bench_serving_row_runs():
-    """bench.py's serving_throughput_rps: in-process, no sockets, no
-    device required."""
-    import bench
-    rps, fill, cache = bench.serving_throughput_rps(duration=0.3,
-                                                    clients=4)
-    assert cache > 0
-    assert rps > 0
-    assert fill >= 1.0

@@ -11,20 +11,27 @@ Checked here: the child spans and their ordinal, the tiling, the
 histogram, the three profiler annotations (through a stand-in and in a
 real ``jax.profiler`` trace read back with the benchmark's reader), the
 compile counter behind ``compiles``, and the line the run's end prints.
+Every dispatch path (epoch scan, stream windows, per step) also sets
+the samples/s and tokens/s gauges, and traces each of its programs
+once.
 """
 
+import collections
 import io
+import re
 import sys
 import threading
 
 import jax
+import numpy
 import pytest
 
 from veles import telemetry
+from veles.accelerated_units import StepCompiler
 from veles.znicz_tpu import xla_step
 
 from test_xla_step_hyper import (  # noqa: F401 (the fixture applies here)
-    _restore_config, dispatches, tiny_lm)
+    _restore_config, dispatches, tiny_lm, tiny_mlp)
 
 PARENT = "xla.dispatch.epoch"
 PHASE_FAMILY = "veles_xla_dispatch_phase_seconds"
@@ -279,3 +286,91 @@ def test_the_launcher_prints_the_line_after_the_unit_table(capsys):
     launcher.run()
     err = capsys.readouterr().err
     assert err.index("xla_step") < err.index("dispatch phases: ")
+
+
+# -- the rates of a dispatch, and the traces behind it ------------------------
+
+#: the perceptron's path for each dispatch kind
+MLP_MODES = {"epoch": "scan", "stream": "stream", "step": "step"}
+SAMPLES = "veles_step_samples_per_second"
+TOKENS = "veles_step_tokens_per_second"
+
+
+def build(case, name):
+    """(workflow, dispatch kind) of a test case, two epochs long: the
+    perceptron on one of the three paths, or the toy LM on a
+    data-parallel mesh of four."""
+    if case == "lm_dp4":
+        return tiny_lm(name, epochs=2, data_parallel=4), "epoch"
+    return tiny_mlp(name, MLP_MODES[case], epochs=2), case
+
+
+def step_families():
+    """The ``veles_step_*`` and ``veles_perf_*`` families a scrape
+    renders."""
+    text = telemetry.get_registry().render_prometheus()
+    return set(re.findall(r"^(veles_(?:step|perf)_\w+?)[{ ]", text, re.M))
+
+
+@pytest.mark.parametrize("case", ("epoch", "stream", "step", "lm_dp4"))
+def test_step_metrics_on_real_run(case):
+    """After a CPU training run, a scrape has the samples/s gauge of
+    the path that ran (tokens/s too for an LM loader) and no other
+    step or cost-model family, the memory gauges registered."""
+    from veles import profiling
+    wf, kind = build(case, "StepRates_" + case)
+    wf.run()
+    profiling.register_memory_gauges()
+    lm = case == "lm_dp4"
+    assert step_families() == ({SAMPLES, TOKENS} if lm else {SAMPLES})
+    rates = {name: telemetry.gauge(name, "", ("kind",)).labels(kind).value
+             for name in step_families()}
+    assert rates[SAMPLES] > 0
+    if lm:
+        # 16 tokens a sample
+        assert rates[TOKENS] == pytest.approx(16 * rates[SAMPLES])
+
+
+def test_tokens_per_second_for_lm_loaders():
+    """An LM loader's (mb, S) integer minibatch yields a tokens/s
+    gauge; float image batches must not."""
+
+    class FakeMem:
+        def __init__(self, arr):
+            self.mem = arr
+
+    from veles.znicz_tpu.xla_step import XLAStep
+    step = XLAStep.__new__(XLAStep)
+    step.loader = type("L", (), {})()
+    step.loader.minibatch_data = FakeMem(
+        numpy.zeros((4, 32), numpy.int32))
+    assert XLAStep._tokens_per_sample(step) == 32
+    step.loader.minibatch_data = FakeMem(
+        numpy.zeros((4, 784), numpy.float32))
+    assert XLAStep._tokens_per_sample(step) is None
+
+
+@pytest.mark.parametrize("case", ("epoch", "stream", "step", "lm_dp4"))
+def test_first_dispatch_traces_the_step_once(case, monkeypatch):
+    """Over two epochs of dispatches, the step body of each program
+    (its class segment: train or evaluation units) is traced once:
+    nothing but the jit call traces a program, and the second epoch
+    reuses what the first one traced."""
+    traced = collections.Counter()
+    trace_step = StepCompiler.trace_step
+
+    def counting(self, params, state, hyper, key, train, units, bind):
+        traced[train, tuple(unit.name for unit in units)] += 1
+        return trace_step(self, params, state, hyper, key, train, units,
+                          bind)
+
+    monkeypatch.setattr(StepCompiler, "trace_step", counting)
+    wf, kind = build(case, "Traces_" + case)
+    wf.run()
+    if kind == "step":
+        # two epochs of 2 evaluation and 6 training minibatches
+        assert wf.xla_step.step_index == 16
+    else:
+        assert dispatches(kind) == 2
+    assert sorted(train for train, _ in traced) == [False, True]
+    assert set(traced.values()) == {1}, traced

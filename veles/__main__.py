@@ -906,7 +906,7 @@ def main(argv=None):
     if argv and argv[0] == "loadgen":
         # open-loop tenant-mix load generator (veles/loadgen.py):
         # per-tenant goodput/p99/shed curves + the
-        # routed_capacity_rps_at_p99_slo bench row
+        # routed_capacity_rps_at_p99_slo summary row
         from veles.loadgen import loadgen_main
         return loadgen_main(argv[1:])
     m = Main(argv)

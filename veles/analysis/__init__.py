@@ -112,10 +112,8 @@ hashes over each module's import closure, so warm full-tree runs
 re-analyze only what changed with byte-identical output — the
 documented pre-commit line is ``velescli lint --changed-only --cache
 .zlint-cache --format sarif``. The tier-1 gate
-``tests/test_analysis.py`` keeps the whole ``veles/`` package (plus
-``bench.py``) at zero findings, and ``bench.py`` tracks the
-analyzer's own cold/warm full-tree wall time as
-``lint_full_tree_seconds`` / ``lint_full_tree_warm_seconds``.
+``tests/test_analysis.py`` keeps the whole ``veles/`` package at zero
+findings.
 """
 
 from veles.analysis.core import (          # noqa: F401  (public API)

@@ -1,7 +1,7 @@
 """Cluster health plane: metrics history, probes, SLO burn-rate alerts.
 
 PRs 3 and 6 gave every process rich raw instruments (telemetry
-registry, distributed traces, flight recorder, perf ledger); this
+registry, distributed traces, flight recorder); this
 module turns them into an OPERATIONAL surface that can answer "is
 this process healthy, is it meeting its objectives, and why not":
 
@@ -71,10 +71,10 @@ DEFAULT_PREFIXES = (
     "veles_checkpoint_", "veles_slo_", "veles_grad_",
     "veles_reactor_",
     # memory accounting (ISSUE 10, veles/profiling.py): host RSS/fds,
-    # device allocator stats, perf-ledger + forward-cache estimates —
-    # ring-sampled so /metrics/history carries memory TRAJECTORIES
-    # and SLO objectives can fire on leaks
-    "veles_host_", "veles_device_", "veles_perf_",
+    # device allocator stats, forward-cache estimates — ring-sampled
+    # so /metrics/history carries memory TRAJECTORIES and SLO
+    # objectives can fire on leaks
+    "veles_host_", "veles_device_",
     # fleet control (ISSUE 13, veles/router.py): routed-request
     # counters/latency and backend inflight — ring-sampled so SLO
     # objectives can fire on router-observed p99 and the autoscaler's
@@ -368,9 +368,9 @@ class HealthMonitor(Logger):
         """One flat ``{series_key: value}`` snapshot of the selected
         registry families (+ custom series fns)."""
         # memory accounting rides the monitor tick (ISSUE 10): the
-        # veles_host_*/veles_device_*/veles_perf_* set_function gauges
-        # are (re-)registered against the ACTIVE registry here, so
-        # every monitored process exports them, registry swaps (test
+        # veles_host_*/veles_device_* set_function gauges are
+        # (re-)registered against the ACTIVE registry here, so every
+        # monitored process exports them, registry swaps (test
         # isolation) re-acquire them, and device kinds that only exist
         # once jax finishes backend init still show up
         try:

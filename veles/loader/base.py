@@ -87,8 +87,7 @@ class Loader(Unit, IDistributable):
         self._inflight = {}
 
         # telemetry: epoch counter/gauge plus per-class minibatch and
-        # sample counters (samples-per-second = rate() over the scrape;
-        # bench.py reads its throughput rows from these same counters)
+        # sample counters (samples-per-second = rate() over the scrape)
         self._tele_epochs = telemetry.LazyChild(
             lambda: telemetry.counter(
                 "veles_loader_epochs_total", "Epochs served",

@@ -20,8 +20,7 @@ The summary row is the capacity number ROADMAP item 4 asks for::
 
 — the highest offered rps stage at which the FIRST configured tenant
 (the "compliant" one by convention) kept its p99 inside
-``--p99-slo-ms`` with a shed rate under ``--max-shed``. ``bench.py
---self-check`` knows this key is higher-is-better.
+``--p99-slo-ms`` with a shed rate under ``--max-shed``.
 """
 
 import argparse

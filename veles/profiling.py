@@ -23,8 +23,7 @@ surface, answering three "why" questions with zero restart required:
 * **who holds the memory?** — :func:`register_memory_gauges`:
   ``veles_host_rss_bytes`` / ``veles_host_open_fds`` from
   ``/proc/self``, ``veles_device_memory_bytes{kind}`` from jax device
-  ``memory_stats()`` when an accelerator is present, plus the perf
-  ledger's per-program size estimates (``veles/perf.py``) and the
+  ``memory_stats()`` when an accelerator is present, plus the
   serving registry's per-model forward-cache estimate. All of them
   are sampled into the health ring (``veles/health.py``
   ``DEFAULT_PREFIXES``), so ``/metrics/history`` carries memory
@@ -437,16 +436,6 @@ def register_memory_gauges(registry=None):
         "veles_host_open_fds",
         "Open file descriptors of this process (/proc/self/fd)"
     ).set_function(lambda: _mem_snapshot()[0]["open_fds"])
-    from veles import perf
-    ledger_g = registry.gauge(
-        "veles_perf_ledger_programs",
-        "Compiled step programs currently held by the perf ledger")
-    ledger_g.set_function(lambda: perf.ledger.sizes()["programs"])
-    registry.gauge(
-        "veles_perf_ledger_est_bytes",
-        "Summed per-program I/O footprint estimate of the ledger's "
-        "live compiled programs (jaxpr-derived, not an HBM meter)"
-    ).set_function(lambda: perf.ledger.sizes()["est_bytes"])
     dev_fam = registry.gauge(
         "veles_device_memory_bytes",
         "Accelerator memory by allocator statistic, summed over "

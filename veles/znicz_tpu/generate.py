@@ -332,8 +332,8 @@ def _cache_key(sig, steps):
     """Compiled-decoder cache key: the shape/sampling signature plus
     a WEAKREF per step unit. ``id(u)`` keyed here once — but a freed
     unit's reallocated id can alias a stale compiled decoder built
-    for different weights/architecture (the same hazard PerfLedger
-    fixed with weakrefs in veles/perf.py). Weakrefs compare by
+    for different weights/architecture (any cache keyed by the id of
+    a collectable object has this hazard). Weakrefs compare by
     referent identity while alive and never equal a new object after
     death, and their hash is cached at insert time, so dead keys stay
     safely hashable until evicted."""

@@ -24,8 +24,7 @@ from veles.config import root
 logger = logging.getLogger("veles.datasets")
 
 #: provenance of the LAST load per dataset key — which source fed the
-#: numbers (bench.py stamps this into its JSON so every recorded
-#: metric says whether it ran on real or synthetic data)
+#: numbers, real or synthetic data
 _PROVENANCE = {}
 
 

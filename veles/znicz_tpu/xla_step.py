@@ -25,7 +25,7 @@ import time
 
 import numpy
 
-from veles import perf, telemetry
+from veles import telemetry
 from veles.accelerated_units import StepCompiler
 from veles.loader.base import CLASS_TRAIN
 from veles.units import Unit
@@ -45,6 +45,25 @@ def _record_dispatch(kind, warm, start, dt, **args):
         telemetry.tracer.add_complete(
             "xla.dispatch.%s" % kind, start, dt,
             warm=bool(warm), **args)
+
+
+def _record_rates(kind, seconds, samples, tokens_per_sample):
+    """Data throughput of one completed dispatch: ``samples`` (each of
+    ``tokens_per_sample`` tokens, None where the data has no tokens)
+    consumed in ``seconds`` of wall time."""
+    if seconds <= 0:
+        return
+    if samples:
+        telemetry.gauge(
+            "veles_step_samples_per_second",
+            "Samples consumed per second by the latest dispatch",
+            ("kind",)).labels(kind).set(samples / seconds)
+    if samples and tokens_per_sample:
+        telemetry.gauge(
+            "veles_step_tokens_per_second",
+            "Tokens consumed per second by the latest dispatch "
+            "(LM loaders)", ("kind",)).labels(kind).set(
+                samples * tokens_per_sample / seconds)
 
 
 #: the phases that tile the host's time from one dispatch to the next
@@ -533,11 +552,6 @@ class XLAStep(Unit):  # zlint: disable=checkpoint-state (params/state/step_index
                 self._pre_epoch_state = copy(self.state)
                 self._pre_epoch_step_index = self.step_index
             self.step_index += serves_per_epoch * n_epochs
-            # cost BEFORE the call: analysis traces the program from
-            # its live arguments, and donation invalidates them
-            # afterwards
-            cost = perf.ledger.cost(
-                ("epoch", id(fn), n_epochs, serves_per_epoch), fn, args)
             _record_host_leaves("epoch", args)
             compiled = _compilations()
         t0 = time.perf_counter()
@@ -559,11 +573,8 @@ class XLAStep(Unit):  # zlint: disable=checkpoint-state (params/state/step_index
                                   ("fetch", t_fetch, t0 + dt)):
             _record_phase("epoch", phase, start, end - start, **ids)
         self._replay_open.append((t0 + dt, ids))
-        samples = n_epochs * int(loader.total_samples)
-        tps = self._tokens_per_sample()
-        perf.ledger.record_dispatch(
-            "epoch", cost, dt, samples=samples,
-            tokens=samples * tps if tps else None)
+        _record_rates("epoch", dt, n_epochs * int(loader.total_samples),
+                      self._tokens_per_sample())
         if warm:
             # a clean (compile-free) run of this program: usable for
             # sizing the next chunk
@@ -720,7 +731,6 @@ class XLAStep(Unit):  # zlint: disable=checkpoint-state (params/state/step_index
             stage(j)
         outs_per_cls = {cls: [] for cls, _, _ in plan}
         pending = []       # (cls, device outputs) — fetch lags by one
-        epoch_flops = epoch_bytes = 0.0
         for i, (cls, valids_w, _) in enumerate(spans):
             train = cls == CLASS_TRAIN
             units = self.train_units if train else self.eval_units
@@ -741,10 +751,6 @@ class XLAStep(Unit):  # zlint: disable=checkpoint-state (params/state/step_index
             self.step_index += len(valids_w)
             args = (self.params, self.state, stacked, valids_w, hyper,
                     key0)
-            w_cost = perf.ledger.cost(
-                ("window", id(fn), len(valids_w)), fn, args)
-            epoch_flops += w_cost.flops
-            epoch_bytes += w_cost.bytes
             _record_host_leaves("stream", args)
             self.params, self.state, outs = fn(*args)
             pending.append((cls, outs))
@@ -774,12 +780,8 @@ class XLAStep(Unit):  # zlint: disable=checkpoint-state (params/state/step_index
         dt_epoch = time.perf_counter() - t_epoch0
         _record_dispatch("stream", warm, t_epoch0, dt_epoch,
                          windows=len(spans))
-        samples = int(loader.total_samples)
-        tps = self._tokens_per_sample()
-        perf.ledger.record_dispatch(
-            "stream", perf.StepCost(epoch_flops, epoch_bytes),
-            dt_epoch, samples=samples,
-            tokens=samples * tps if tps else None)
+        _record_rates("stream", dt_epoch, int(loader.total_samples),
+                      self._tokens_per_sample())
 
     def _run_per_step(self):
         import jax
@@ -801,7 +803,6 @@ class XLAStep(Unit):  # zlint: disable=checkpoint-state (params/state/step_index
         self.step_index += 1
         args = (self.params, self.state, batch,
                 self._device_hyper("step"), key)
-        cost = perf.ledger.cost(("step", id(fn)), fn, args)
         _record_host_leaves("step", args)
         t0 = time.perf_counter()
         params, state, outputs = fn(*args)
@@ -810,11 +811,9 @@ class XLAStep(Unit):  # zlint: disable=checkpoint-state (params/state/step_index
         self._publish_metrics(outputs)
         # _publish_metrics fetched scalar metrics, so the wall time
         # above includes real device execution, not just the enqueue
-        samples = int(getattr(self.loader, "minibatch_size", 0) or 0)
-        tps = self._tokens_per_sample()
-        perf.ledger.record_dispatch(
-            "step", cost, time.perf_counter() - t0, samples=samples,
-            tokens=samples * tps if tps else None)
+        _record_rates("step", time.perf_counter() - t0,
+                      int(getattr(self.loader, "minibatch_size", 0) or 0),
+                      self._tokens_per_sample())
 
     def _tokens_per_sample(self):
         """Tokens one sample carries, for the tokens/s gauge: an LM
