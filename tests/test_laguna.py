@@ -697,9 +697,10 @@ def test_an_unknown_operator_is_refused_with_the_models_own_named(
         tiny_lm):
     root.lm.model.update({"layers": ["sliding_attention", "windowed"]})
     with pytest.raises(ValueError, match="has the operators 'conv', "
-                       "'delta_attention', 'full_attention', "
-                       "'plain_attention', 'gated_nope_attention', "
-                       "'sliding_attention', got"):
+                       "'delta_attention', 'eva_attention', "
+                       "'full_attention', 'plain_attention', "
+                       "'gated_nope_attention', 'sliding_attention', "
+                       "got"):
         tiny_lm.build_layers()
 
 
@@ -708,8 +709,9 @@ def test_without_its_operators_the_pattern_is_refused(tiny_lm):
     is no name it knows."""
     del root.lm.model.operators
     with pytest.raises(ValueError, match="has the operators 'conv', "
-                       "'delta_attention', 'full_attention', "
-                       "'plain_attention', 'gated_nope_attention', got"):
+                       "'delta_attention', 'eva_attention', "
+                       "'full_attention', 'plain_attention', "
+                       "'gated_nope_attention', got"):
         tiny_lm.build_layers()
     root.lm.model.operators = {}
 

@@ -922,9 +922,14 @@ def test_numpy_device_refuses_the_new_units(tiny_lm):
 
 
 @pytest.mark.parametrize("patch,message", [
-    ({"layers": ["delta_attention", "window"]},
-     "has the operators 'conv', 'delta_attention', 'full_attention', "
-     "'plain_attention', 'gated_nope_attention', got"),
+    # (the id the case had before the operator list gained
+    # 'eva_attention')
+    pytest.param(
+        {"layers": ["delta_attention", "window"]},
+        "has the operators 'conv', 'delta_attention', 'eva_attention', "
+        "'full_attention', 'plain_attention', 'gated_nope_attention', got",
+        id="patch0-has the operators 'conv', 'delta_attention', "
+        "'full_attention', 'plain_attention', 'gated_nope_attention', got"),
     ({"layers": ["delta_attention"], "dense_layers": 1, "ut_steps": 2,
       "ffn_hidden": 32}, "no delta_attention"),
     ({"layers": ["delta_attention"], "dense_layers": 1,

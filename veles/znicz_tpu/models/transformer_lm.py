@@ -16,7 +16,8 @@ import numpy
 from veles import prng
 from veles.config import root
 from veles.loader.fullbatch import FullBatchLoader
-from veles.znicz_tpu.ops.evaluator import EvaluatorLM, EvaluatorLoopLM
+from veles.znicz_tpu.ops.evaluator import (
+    EvaluatorHeadsLM, EvaluatorLM, EvaluatorLoopLM)
 from veles.znicz_tpu.standard_workflow import StandardWorkflow
 
 root.lm.update({
@@ -44,7 +45,9 @@ root.lm.update({
     # remat (with stacked=True): activation-checkpoint the block scan
     # — stash only layer inputs, recompute caches in the backward;
     # ~+1/3 compute for an O(heads*seq/12) stash cut (the (B, S)
-    # envelope knob for the stacked path; docs/PARALLELISM.md)
+    # envelope knob for the stacked path; docs/PARALLELISM.md); with
+    # block="pre_norm" the SwiGLU feed-forwards stash only their input
+    # and run their forward again in the backward
     # block: "post_ln" (the block above) or "pre_norm": pre-norm (RMS)
     # residual layers, each an operator and a feed-forward, no bias, no
     # absolute positions, a final RMS norm before the head. `layers`
@@ -86,9 +89,17 @@ root.lm.update({
     # every pass's state exits through the one head, an exit gate
     # (ops/exit_gate.py) weighs the exits per token, and the loss is
     # the expected cross entropy less exit_entropy_weight times the
-    # exit distribution's entropy (EvaluatorLoopLM). Every key is a
-    # shape or a constant the model's config states, none a tuning
-    # knob.
+    # exit distribution's entropy (EvaluatorLoopLM). pre_norm's
+    # "eva_attention" is EVA attention (ops/eva_attention.py): exact
+    # softmax over the query's own window of eva_window tokens and over
+    # one learned summary of each eva_chunk tokens of every earlier
+    # window, in one softmax. residual_dtype ("float32"; None: the
+    # device's activation type) is the type of the residual stream
+    # between pre_norm's units, logits_dtype that of the head's
+    # logits; pred_heads > 1 makes the head that many untied heads in
+    # one product, head j predicting the token j + 1 ahead, the loss
+    # their mean (EvaluatorHeadsLM). Every key is a shape or a constant
+    # the model's config states, none a tuning knob.
     "model": {"dim": 64, "heads": 4, "layers": 2, "ffn_hidden": 128,
               "attn_block": None, "attn_impl": None,
               "pallas_tile": None, "moe_experts": 0,
@@ -102,7 +113,10 @@ root.lm.update({
               "ut_steps": 1, "exit_entropy_weight": 0.0,
               "moe_shared_hidden": 0, "delta_heads": None,
               "delta_head_dim": None, "delta_conv_kernel": 4,
-              "delta_gate_rank": None, "operators": {}},
+              "delta_gate_rank": None, "operators": {},
+              "eva_window": 2048, "eva_chunk": 16,
+              "residual_dtype": None, "logits_dtype": None,
+              "pred_heads": 1},
     "train": {"learning_rate": 0.05, "gradient_moment": 0.9,
               "weights_decay": 0.0},
     "decision": {"max_epochs": 8, "fail_iterations": 50},
@@ -287,7 +301,8 @@ def pre_norm_body(m, t):
     operators = layer_operators(m)
     attention = attention_operators(m)
     # every operator a pre_norm layer can have
-    known = ("conv", "delta_attention") + tuple(attention)
+    known = ("conv", "delta_attention", "eva_attention") \
+        + tuple(attention)
     if set(operators) - set(known):
         raise ValueError("block='pre_norm' has the operators %s, got %r"
                          % (", ".join(map(repr, known)), operators))
@@ -305,11 +320,13 @@ def pre_norm_body(m, t):
             "(at ut_steps=1) data-parallel; not with stacked=%r / "
             "parallel %r" % (m.get("stacked"), busy))
     dense = m.get("dense_layers", 0) >= len(operators)
-    if looped and not (dense and "delta_attention" not in operators):
+    own_counters = {"delta_attention", "eva_attention"} & set(operators)
+    if looped and (not dense or own_counters or heads_of(m) > 1):
         raise ValueError(
             "ut_steps=%r loops layers with a SwiGLU feed-forward "
-            "(dense_layers = the layers' number) and no delta_attention: "
-            "the expert and delta-rule layers' counters are one visit's"
+            "(dense_layers = the layers' number), no delta_attention or "
+            "eva_attention and one prediction head: the expert, "
+            "delta-rule and EVA layers' counters are one visit's"
             % (steps,))
     if norm == "sandwich" and not (
             dense and set(operators) <= set(attention)):
@@ -317,12 +334,20 @@ def pre_norm_body(m, t):
                          "a SwiGLU feed-forward")
     eps = m.get("norm_eps", 1e-5)
     sandwich = {"sandwich": True} if norm == "sandwich" else {}
+    stream = stream_keys(m)
+    remat = {"recompute": True} if m.get("remat") else {}
+    attending = dict(attention_kernel_keys(m), heads=m.heads,
+                     kv_heads=m.get("kv_heads"),
+                     head_dim=m.get("head_dim"),
+                     rope_theta=m.get("rope_theta", 1e6), eps=eps,
+                     **sandwich)
     body = []
     for index, kind in enumerate(operators):
         if kind == "conv":
             body.append({
                 "type": "short_conv",
-                "->": {"kernel": m.get("conv_kernel", 3), "eps": eps},
+                "->": dict(kernel=m.get("conv_kernel", 3), eps=eps,
+                           **stream),
                 "<-": dict(t), "loop": "segment"})
         elif kind == "delta_attention":
             body.append({
@@ -332,22 +357,24 @@ def pre_norm_body(m, t):
                        or m.get("head_dim"),
                        "kernel": m.get("delta_conv_kernel", 4),
                        "gate_rank": m.get("delta_gate_rank"),
-                       "eps": eps},
+                       "eps": eps, **stream},
+                "<-": dict(t), "loop": "segment"})
+        elif kind == "eva_attention":
+            body.append({
+                "type": "eva_attention",
+                "->": dict(attending, chunk=m.get("eva_chunk", 16),
+                           local_window=m.get("eva_window", 2048),
+                           **stream),
                 "<-": dict(t), "loop": "segment"})
         else:
             body.append({
                 "type": "gqa_attention",
-                "->": dict(dict(attention_kernel_keys(m), heads=m.heads,
-                                kv_heads=m.get("kv_heads"),
-                                head_dim=m.get("head_dim"),
-                                rope_theta=m.get("rope_theta", 1e6),
-                                eps=eps, **sandwich),
-                           **attention[kind]),
+                "->": dict(attending, **attention[kind], **stream),
                 "<-": dict(t), "loop": "segment"})
         if index < m.get("dense_layers", 0):
             body.append({"type": "swiglu_ffn",
                          "->": dict(hidden=m.ffn_hidden, eps=eps,
-                                    **sandwich),
+                                    **sandwich, **stream, **remat),
                          "<-": dict(t), "loop": "body"})
         else:
             body.append({
@@ -359,13 +386,32 @@ def pre_norm_body(m, t):
                        "scaling": m.get("moe_scaling", 1.0),
                        "bias_stddev": m.get("moe_bias_stddev", 0.0),
                        "shared_hidden": m.get("moe_shared_hidden", 0),
-                       "eps": eps},
+                       "eps": eps, **stream},
                 "<-": dict(t)})
-    body.append({"type": "rms_norm", "->": {"eps": eps}, "<-": dict(t),
-                 "loop": "body"})
+    body.append({"type": "rms_norm", "->": dict(eps=eps, **stream),
+                 "<-": dict(t), "loop": "body"})
     if looped:
         body.append({"type": "exit_gate", "<-": dict(t), "loop": "body"})
     return body
+
+
+def stream_keys(m):
+    """The units' ``output_dtype`` where ``residual_dtype`` sets one:
+    the embedding's and pre_norm's layers' outputs and err_inputs."""
+    dtype = m.get("residual_dtype")
+    if dtype and m.get("block", "post_ln") != "pre_norm":
+        raise ValueError("residual_dtype is pre_norm's, got block %r"
+                         % m.get("block", "post_ln"))
+    return {"output_dtype": dtype} if dtype else {}
+
+
+def heads_of(m):
+    """``pred_heads``: the head's prediction heads (1: next token)."""
+    heads = m.get("pred_heads")
+    heads = 1 if heads is None else int(heads)
+    if heads < 1:
+        raise ValueError("pred_heads is at least 1, got %r" % (heads,))
+    return heads
 
 
 def attention_kernel_keys(m):
@@ -388,11 +434,13 @@ def build_layers():
     layers = [{"type": "embedding",
                "->": dict({"vocab_size": vocab, "dim": m.dim},
                           **({"add_positions": False} if pre_norm
-                             else {})),
+                             else {}), **stream_keys(m)),
                "<-": dict(t)}]
+    logits = m.get("logits_dtype")
     head = {"type": "token_dense",
-            "->": dict({"output_features": vocab},
-                       **({"include_bias": False} if pre_norm else {})),
+            "->": dict({"output_features": vocab * heads_of(m)},
+                       **({"include_bias": False} if pre_norm else {}),
+                       **({"output_dtype": logits} if logits else {})),
             "<-": dict(t), "loop": "exit"}
     if pre_norm:
         return layers + pre_norm_body(m, t) + [head]
@@ -474,6 +522,8 @@ def lm_evaluator_factory(wf, last):
             wf, name="evaluator", steps=steps,
             entropy_weight=m.get("exit_entropy_weight", 0.0))
         ev.link_attrs(wf.forwards[-2], "gate")   # the exit gate's tap
+    elif heads_of(m) > 1:
+        ev = EvaluatorHeadsLM(wf, name="evaluator", heads=heads_of(m))
     else:
         ev = EvaluatorLM(wf, name="evaluator")
     ev.link_attrs(last, ("input", "output"))

@@ -103,6 +103,11 @@ class Forward(AcceleratedUnit):
         self.bias_filling = kwargs.get("bias_filling", "constant")
         self.bias_stddev = kwargs.get("bias_stddev", 0.0)
         self.prng = prng.get(kwargs.get("prng_key", "default"))
+        #: dtype of the unit's output in a compiled step (and, in the
+        #: units of a residual stream, of its err_input): None is the
+        #: device's activation type; "float32" keeps a residual stream
+        #: or logits in float32 (``stream_dtype``)
+        self.output_dtype = kwargs.get("output_dtype")
 
     # weight materialisation ------------------------------------------
 
@@ -133,6 +138,12 @@ class Forward(AcceleratedUnit):
             if self.bias_filling != "constant" or self.bias_stddev:
                 self.fill_array(self.bias, self.bias_filling,
                                 self.bias_stddev or 0.01)
+
+    def stream_dtype(self, ctx):
+        """The dtype of this unit's output in the step ``ctx``
+        compiles: ``output_dtype``, else the activation type."""
+        return numpy.dtype(self.output_dtype) if self.output_dtype \
+            else ctx.act_dtype
 
     @property
     def batch_size(self):

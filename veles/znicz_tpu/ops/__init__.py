@@ -15,7 +15,7 @@ from veles.znicz_tpu.ops.gd import (  # noqa: F401
 )
 from veles.znicz_tpu.ops.evaluator import (  # noqa: F401
     EvaluatorBase, EvaluatorSoftmax, EvaluatorMSE, EvaluatorLM,
-    EvaluatorLoopLM,
+    EvaluatorLoopLM, EvaluatorHeadsLM,
 )
 from veles.znicz_tpu.ops.conv import (  # noqa: F401
     Conv, ConvTanh, ConvRELU, ConvStrictRELU, ConvSigmoid,
@@ -68,6 +68,9 @@ from veles.znicz_tpu.ops.gqa_attention import (  # noqa: F401
 )
 from veles.znicz_tpu.ops.delta_attention import (  # noqa: F401
     DeltaAttention, GDDeltaAttention,
+)
+from veles.znicz_tpu.ops.eva_attention import (  # noqa: F401
+    EvaAttention, GDEvaAttention,
 )
 from veles.znicz_tpu.ops.exit_gate import (  # noqa: F401
     ExitGate, GDExitGate,

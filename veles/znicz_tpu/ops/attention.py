@@ -88,7 +88,7 @@ class TokenDenseBase(Forward):
         ctx.set(self, "output",
                 self._forward(jnp, x, p["weights"], p.get("bias"),
                               ctx.dot)
-                .astype(ctx.act_dtype))
+                .astype(self.stream_dtype(ctx)))
 
     # -- loss-tail protocol (the 1F1B fold) ---------------------------
     # ops/transformer_stack.py replays the units BETWEEN the block

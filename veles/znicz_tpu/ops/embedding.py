@@ -66,7 +66,8 @@ class EmbeddingForward(Forward):
         ids = ctx.get(self, "input").astype(jnp.int32)
         table = ctx.unit_params(self)["weights"]
         ctx.set(self, "output",
-                self._forward(jnp, ids, table).astype(ctx.act_dtype))
+                self._forward(jnp, ids, table).astype(
+                    self.stream_dtype(ctx)))
 
 
 @gradient_for(EmbeddingForward)

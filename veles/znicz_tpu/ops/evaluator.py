@@ -273,6 +273,44 @@ class EvaluatorLM(EvaluatorBase):
         ctx.export("n_err", wrong.astype(jnp.int32))
 
 
+class EvaluatorHeadsLM(EvaluatorLM):
+    """Cross entropy of ``heads`` prediction heads over one state: the
+    logits (B, S, heads x V) are head ``j``'s (B, S, V) in columns
+    ``[j V, (j + 1) V)``, and head ``j`` predicts the token ``j + 1``
+    ahead, ``labels[:, t + j]`` (the labels are the next-token shift).
+    The positions whose target lies past the row have none and are
+    masked; the loss is the mean over heads of each head's mean over
+    its targets,
+
+        loss = 1/heads sum_j sum_{t < S - j} CE_j(t) / (valid (S - j)),
+
+    and ``err = (softmax - onehot) mask_j / (heads valid (S - j))``
+    its gradient; ``n_err`` counts head 0's wrong next tokens."""
+
+    def __init__(self, workflow, heads=1, **kwargs):
+        super().__init__(workflow, **kwargs)
+        self.heads = int(heads)
+
+    def _compute(self, xp, logits, labels, valid):
+        b, s, wide = logits.shape
+        n = self.heads
+        logits = logits.reshape(b, s, n, wide // n)
+        targets = xp.stack([xp.concatenate(
+            [labels[:, j:], xp.full((b, j), -1, labels.dtype)], axis=1)
+            for j in range(n)], axis=2)                     # (b, s, n)
+        logp, probs, onehot = self._softmax_ce_core(xp, logits, targets)
+        rows = (xp.arange(b) < valid).astype(logits.dtype)
+        weight = (targets >= 0).astype(logits.dtype) \
+            * rows[:, None, None] / (
+                valid.astype(logits.dtype) * n
+                * (s - xp.arange(n)).astype(logits.dtype))
+        err = ((probs - onehot) * weight[..., None]).reshape(b, s, wide)
+        loss = -((logp * onehot).sum(axis=-1) * weight).sum()
+        wrong = ((xp.argmax(logits[:, :, 0], axis=-1) != labels)
+                 & (rows[:, None] > 0)).sum()
+        return err, loss, wrong
+
+
 class EvaluatorLoopLM(EvaluatorLM):
     """The loss of a looped layer stack (``znicz_tpu.loop.Loop``):
     every pass ``t`` of ``steps`` exits through the one head, and a

@@ -380,7 +380,7 @@ class GQAttention(VjpForward, MultiHeadAttention):
             if ctx.train:
                 ctx.export("attn_gate_" + self.name, aux["gate"])
         ctx.set(self, "output",
-                (x.astype(jnp.float32) + y).astype(ctx.act_dtype))
+                (x.astype(jnp.float32) + y).astype(self.stream_dtype(ctx)))
         if ctx.train:
             ctx.set(self, "core", (mode, q, k, v, saved))
             if self.window is not None:
@@ -464,5 +464,6 @@ class GDGQAttention(GDVjp):
             t.astype(q.dtype) for t in (dq, dk, dv)) + tuple(dgate))
         if self.need_err_input:
             ctx.set(self, "err_input",
-                    (dx.astype(jnp.float32) + err).astype(ctx.act_dtype))
+                    (dx.astype(jnp.float32) + err).astype(
+                        f.stream_dtype(ctx)))
         self.apply_grads(ctx, dict(grads, **pre))

@@ -5,11 +5,14 @@
 ``weights`` holds ``[W1 | W3]`` (d x 2f), so the two up-projections
 are one product. With ``sandwich`` the sub-layer's float32 output takes
 a gain of its own before the residual add: ``y = x + rms(...; g_out)``.
+With ``recompute`` nothing of the sub-layer but its input lives from
+the forward to the backward, which runs it again
+(``vjp_units.recomputed``): the model's ``remat`` in the pre-norm block.
 """
 
 from veles.znicz_tpu.nn_units import forward_unit, gradient_for
 from veles.znicz_tpu.ops.vjp_units import (
-    GDVjp, Products, VjpForward, rms_norm)
+    GDVjp, Products, VjpForward, recomputed, rms_norm)
 
 
 def swiglu(h13, keep=None):
@@ -31,8 +34,9 @@ class SwiGLUFFN(VjpForward):
     PARAMS = ("weights", "weights2", "norm")
 
     def __init__(self, workflow, hidden=None, eps=1e-5, sandwich=False,
-                 **kwargs):
+                 recompute=False, **kwargs):
         self.sandwich = bool(sandwich)
+        self.recompute = bool(recompute)
         if self.sandwich:
             self.PARAMS = type(self).PARAMS + ("norm_out",)
         super().__init__(workflow, **kwargs)
@@ -51,6 +55,11 @@ class SwiGLUFFN(VjpForward):
         return specs
 
     def apply(self, ctx, p, x):
+        if self.recompute:
+            return recomputed(lambda p, x: self.ffn(ctx, p, x))(p, x)
+        return self.ffn(ctx, p, x)
+
+    def ffn(self, ctx, p, x):
         import jax
         import jax.numpy as jnp
         mm = Products(ctx)

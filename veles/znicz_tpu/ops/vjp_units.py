@@ -236,7 +236,7 @@ class VjpForward(Forward):
     def xla_run(self, ctx):
         x = ctx.get(self, "input")
         trainable, buffers = self.split_params(ctx)
-        act = ctx.act_dtype
+        act = self.stream_dtype(ctx)
 
         def apply(tp, x):
             out = self.apply(ctx, dict(tp, **buffers), x)
@@ -272,5 +272,5 @@ class GDVjp(GradientDescentBase):
         err = ctx.get(self, "err_output").reshape(y.shape).astype(y.dtype)
         grads, dx = self.pull(ctx, "apply", err)
         if self.need_err_input:
-            ctx.set(self, "err_input", dx.astype(ctx.act_dtype))
+            ctx.set(self, "err_input", dx.astype(f.stream_dtype(ctx)))
         self.apply_grads(ctx, grads)

@@ -26,6 +26,23 @@ exactly against the dense formulation in tests.
 import numpy
 
 
+def merge_partial(out, lse, o_b, lse_b):
+    """logsumexp-merge of two NORMALIZED partial attentions over
+    disjoint key sets: ``out`` (float32) and ``o_b`` with their lse
+    (B, H, S) -> (the float32 out over the union, its lse). A part
+    that saw no key has lse -inf and weighs 0; where neither saw one
+    (lse == lse_b == -inf) both coefficients are 0, not nan. The ring's
+    steps (``parallel/ring.py``) and EVA's local and remote parts
+    (``ops/eva_attention.py``) merge here."""
+    import jax.numpy as jnp
+    new_lse = jnp.logaddexp(lse, lse_b)
+    empty = jnp.isneginf(new_lse)
+    c1 = jnp.where(empty, 0.0, jnp.exp(lse - new_lse))
+    c2 = jnp.where(empty, 0.0, jnp.exp(lse_b - new_lse))
+    return (out * c1[..., None]
+            + o_b.astype(jnp.float32) * c2[..., None]), new_lse
+
+
 def _hidden(kpos, qpos, window):
     """(S, block) bool: the keys a query under a ``window`` does not
     see — those after it and those ``window`` or more tokens before it

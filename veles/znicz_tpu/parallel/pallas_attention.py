@@ -108,6 +108,17 @@ diagonal tile and the one before it, both masked, none plain: what the
 tile's rounding costs is the tile choice's to win back (a 256 tile
 visits 1.5 times the band, a 128 tile 1.25).
 
+A ``prefix`` (span_q, span_k) (static; EVA's remote part) gives
+the K-loop pair keys of a length of their own, S_k = S / span_q x
+span_k, and has query ``t`` see the first ``(t // span_q) x span_k`` of
+them: the summaries of every whole span before its own. The forward's
+K loop runs to that bound for its Q block, the backward's Q loop from
+the first Q tile of the span after its K tile's
+(:func:`_prefix_tiles`, :func:`_prefix_first_q`); the blocks divide the
+spans, so no tile is cut and none is masked. A block of the first span
+sees no key and writes out 0, lse -inf. At S = 16384, spans (2048,
+128) a head's row holds 7,340,032 such pairs (:func:`prefix_pairs`).
+
 Consumed by ``MultiHeadAttention(attn_impl="pallas")``; backward is
 wired through the explicit GD unit (znicz style), so no custom-VJP
 registration is needed — autodiff never touches these.
@@ -309,6 +320,32 @@ def visited_pairs(s, block_q, block_k, window):
     return tiles * block_q * block_k
 
 
+def _prefix_tiles(qi, block_q, block_k, prefix):
+    """K tiles the forward's Q block ``qi`` visits under a ``prefix``
+    (span_q, span_k): the keys of every WHOLE span of ``span_q`` queries
+    before the block's, ``span_k`` keys a span."""
+    span_q, span_k = prefix
+    return (qi * block_q) // span_q * (span_k // block_k)
+
+
+def _prefix_first_q(ki, block_q, block_k, prefix):
+    """The first Q tile that sees the backward's K block ``ki`` under a
+    ``prefix``: the first of the span after the one its keys sum up."""
+    span_q, span_k = prefix
+    return ((ki * block_k) // span_k + 1) * span_q // block_q
+
+
+def prefix_pairs(s, prefix):
+    """Query-key pairs a row of ``s`` queries attends under a
+    ``prefix`` (span_q, span_k): query ``t`` sees ``(t // span_q) x
+    span_k`` keys (7,340,032 at S = 16384, spans (2048, 128)). The
+    kernels' tiles divide the spans, so their bounds visit exactly
+    these pairs and mask none."""
+    span_q, span_k = prefix
+    spans = s // span_q
+    return span_q * span_k * spans * (spans - 1) // 2
+
+
 def band_pairs(s, window=None):
     """Query-key pairs a causal row of length ``s`` attends: query
     ``t`` sees ``min(t + 1, window)`` keys (4,063,488 at S = 8192,
@@ -319,7 +356,7 @@ def band_pairs(s, window=None):
 
 
 def _fwd_kernel(q_ref, k_ref, vt_ref, o_ref, lse_ref, *, block_q,
-                block_k, n_kb, causal, scale, window=None):
+                block_k, n_kb, causal, scale, window=None, prefix=None):
     """K-LOOP forward, one q block a program, the K row and the
     TRANSPOSED V row (dh, S) resident. The score tile is held
     transposed, (keys on sublanes, queries on lanes), as the one-tile
@@ -370,7 +407,10 @@ def _fwd_kernel(q_ref, k_ref, vt_ref, o_ref, lse_ref, *, block_q,
     m0 = jnp.full((1, block_q), -jnp.inf, jnp.float32)
     l0 = jnp.zeros((1, block_q), jnp.float32)
     acc0 = jnp.zeros((dh, block_q), jnp.float32)
-    if window is not None:
+    if prefix is not None:
+        # the keys of the spans before this block's, no tile cut
+        spans = [(0, _prefix_tiles(qi, block_q, block_k, prefix), False)]
+    elif window is not None:
         # ... and K blocks wholly older than the window likewise: the
         # mask is paid where an edge of the band cuts a tile
         spans = _band_spans_fwd(qi, block_q, block_k, window)
@@ -383,13 +423,18 @@ def _fwd_kernel(q_ref, k_ref, vt_ref, o_ref, lse_ref, *, block_q,
     else:
         spans = [(0, n_kb, False)]
     m, l, acc_t = _split_loop(spans, make_body, (m0, l0, acc0))
-    o_ref[0] = (acc_t / l).T.astype(o_ref.dtype)
+    if prefix is not None:
+        # a block of the first span sees no key: out 0, lse -inf
+        l_out = jnp.where(l > 0, l, 1.0)
+    else:
+        l_out = l
+    o_ref[0] = (acc_t / l_out).T.astype(o_ref.dtype)
     lse_ref[0] = m + jnp.log(l)                     # (1, bq)
 
 
 def _dkvq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                  dk_ref, dv_ref, dqt_ref, *, block_q, block_k, n_qb,
-                 causal, scale, window=None):
+                 causal, scale, window=None, prefix=None):
     """FUSED backward: one pass over the (q-block, k-block) pairs
     computes dk, dv AND dq, 5 block matmuls and 1 exp pass per pair
     (a dq kernel beside a dk/dv kernel would recompute s and dp: 7
@@ -456,7 +501,11 @@ def _dkvq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     dk0 = jnp.zeros((bk, dh), jnp.float32)
     dv0 = jnp.zeros((bk, dh), jnp.float32)
-    if window is not None:
+    if prefix is not None:
+        # from the first Q block of the span after this K block's
+        spans = [(_prefix_first_q(ki, block_q, block_k, prefix), n_qb,
+                  False)]
+    elif window is not None:
         # ... and stop at the last Q block that still sees this K block
         spans = _band_spans_bwd(ki, block_q, block_k, window, n_qb)
     elif causal:
@@ -625,36 +674,69 @@ def _band(window, causal, s):
     return None if window >= s else int(window)
 
 
+def _keys(q, k, block_q, block_k, causal, window, prefix):
+    """(S, keys, block_q, block_k, window as the kernels take it) of a
+    call: the blocks clipped to the lengths and checked to divide them;
+    a ``prefix`` checked against its spans, of a row that is neither
+    causal nor windowed."""
+    s, sk = q.shape[2], k.shape[2]
+    block_q, block_k = min(block_q, s), min(block_k, sk)
+    if s % block_q or sk % block_k:
+        raise ValueError("blocks (%d, %d) do not divide sequences "
+                         "(%d, %d)" % (block_q, block_k, s, sk))
+    if prefix is None:
+        if sk != s:
+            raise ValueError("%d keys for %d queries need a prefix"
+                             % (sk, s))
+        return s, sk, block_q, block_k, _band(window, causal, s)
+    span_q, span_k = prefix
+    if causal or window is not None:
+        raise ValueError("a prefix row is not causal and has no window")
+    if span_q % block_q or span_k % block_k or s % span_q \
+            or sk != s // span_q * span_k:
+        raise ValueError(
+            "prefix spans (%d, %d) over sequences (%d, %d) in blocks "
+            "(%d, %d): the blocks divide the spans, the spans the "
+            "sequences" % (span_q, span_k, s, sk, block_q, block_k))
+    return s, sk, block_q, block_k, None
+
+
 def flash_attention_fwd(q, k, v, causal=True, block_q=128,
-                        block_k=128, interpret=None, window=None):
-    """q/k/v: (B, H, S, dh) → (out (B, H, S, dh), lse (B, H, S)
-    f32); exact. Blocks must divide S. ``interpret``: False = the
-    real Mosaic kernel, True = the Pallas interpreter (how the CPU
-    tests run the same code); None asks jax's default device
-    (:func:`_on_tpu`). The shape alone picks the kernel: a sequence
-    that is one tile (blocks == S <= ``TILE_MAX_S``) runs
+                        block_k=128, interpret=None, window=None,
+                        prefix=None):
+    """q (B, H, S, dh), k/v (B, H, S_k, dh) → (out (B, H, S, dh), lse
+    (B, H, S) f32); exact softmax over the keys a query sees: the
+    causal prefix of its own position (``causal``), a band under a
+    ``window``, all S_k keys (neither), or, with ``prefix`` = (span_q,
+    span_k), the first ``(t // span_q) x span_k`` keys for query ``t``
+    (the summaries of every whole span before its own: EVA's remote
+    part, ``ops/eva_attention.py``). Without a prefix S_k = S; with one
+    S_k = S / span_q x span_k, the blocks divide the spans, so no tile
+    is cut, and a query of the first span sees no key: out 0, lse -inf.
+    Blocks must divide the lengths. ``interpret``: False = the real
+    Mosaic kernel, True = the Pallas interpreter (how the CPU tests run
+    the same code); None asks jax's default device (:func:`_on_tpu`).
+    The shape alone picks the kernel: a sequence that is one tile
+    (blocks == S <= ``TILE_MAX_S``, no prefix) runs
     ``_tile_fwd_kernel``, any other the K-loop ``_fwd_kernel``, which
-    takes V as (BH, dh, S) — one XLA transpose a call. Both write lse
+    takes V as (BH, dh, S_k) — one XLA transpose a call. Both write lse
     lane-dense as (BH, 1, S), the layout the backward reads."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
-    b, h, s, dh = q.shape
-    block_q = min(block_q, s)
-    block_k = min(block_k, s)
-    if s % block_q or s % block_k:
-        raise ValueError("blocks (%d, %d) do not divide sequence %d"
-                         % (block_q, block_k, s))
+    b, h, _, dh = q.shape
+    s, sk, block_q, block_k, window = _keys(
+        q, k, block_q, block_k, causal, window, prefix)
     if interpret is None:
         interpret = not _on_tpu()
-    window = _band(window, causal, s)
     scale = numpy.float32(1.0 / numpy.sqrt(dh))
     flat = (b * h, s, dh)
-    qf, kf, vf = (t.reshape(flat) for t in (q, k, v))
+    qf = q.reshape(flat)
+    kf, vf = (t.reshape(b * h, sk, dh) for t in (k, v))
     out_shape = [jax.ShapeDtypeStruct(flat, q.dtype),
                  jax.ShapeDtypeStruct((b * h, 1, s), jnp.float32)]
-    if block_q == block_k == s <= TILE_MAX_S:
+    if prefix is None and block_q == block_k == s <= TILE_MAX_S:
         rows = _tile_rows(b * h)
         tensor, lanes = _tile_specs(rows, s, dh)
         out, lse = pl.pallas_call(
@@ -669,18 +751,19 @@ def flash_attention_fwd(q, k, v, causal=True, block_q=128,
             **_tile_params(rows, s, dh, q.dtype.itemsize, interpret),
         )(qf, kf, vf)
         return (out.reshape(b, h, s, dh), lse.reshape(b, h, s))
-    blocked, full, _ = _specs(block_q, s, dh)
+    blocked, _, _ = _specs(block_q, s, dh)
+    _, full, _ = _specs(block_k, sk, dh)
     # V as (BH, dh, S): one O(S*dh) XLA transpose a call, so that the
     # kernel's P.V product needs none a pair and V's resident row is
     # lane-dense; lse leaves as (1, block_q) blocks of (BH, 1, S)
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, block_q=block_q,
-                          block_k=block_k, n_kb=s // block_k,
+                          block_k=block_k, n_kb=sk // block_k,
                           causal=causal, scale=scale,
-                              window=window),
+                          window=window, prefix=prefix),
         grid=(b * h, s // block_q),
         in_specs=[blocked, full,
-                  pl.BlockSpec((1, dh, s), lambda bh, i: (bh, 0, 0))],
+                  pl.BlockSpec((1, dh, sk), lambda bh, i: (bh, 0, 0))],
         out_specs=[blocked,
                    pl.BlockSpec((1, 1, block_q),
                                 lambda bh, i: (bh, 0, i))],
@@ -692,12 +775,16 @@ def flash_attention_fwd(q, k, v, causal=True, block_q=128,
 
 def flash_attention_bwd(q, k, v, out, lse, dout, causal=True,
                         block_q=128, block_k=128, interpret=None,
-                        delta=None, window=None):
-    """Block-recomputation backward → (dq, dk, dv), exact. ``delta``:
-    optional precomputed ``rowsum(dout*out)`` (B, H, S) f32 — callers
-    that invoke this kernel repeatedly on the same out/dout (the ring's
-    per-step inner backward) hoist it to avoid re-reading both tensors
-    from HBM every call.
+                        delta=None, window=None, prefix=None):
+    """Block-recomputation backward → (dq, dk, dv), exact, of
+    :func:`flash_attention_fwd` with the same ``causal``, ``window``
+    and ``prefix``. ``delta``: optional precomputed ``rowsum(dout*out)``
+    (B, H, S) f32 — callers that invoke this kernel repeatedly on the
+    same out/dout (the ring's per-step inner backward) hoist it to
+    avoid re-reading both tensors from HBM every call. ``out`` and
+    ``lse`` may be those of a softmax over MORE keys than this call's
+    (the ring's and EVA's merge): each part's gradient is then its
+    share of the whole's.
 
     The shape alone picks the kernel, as in the forward: a sequence
     that is one tile runs ``_tile_bwd_kernel``, any other the fused
@@ -710,18 +797,15 @@ def flash_attention_bwd(q, k, v, out, lse, dout, causal=True,
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
-    b, h, s, dh = q.shape
-    block_q = min(block_q, s)
-    block_k = min(block_k, s)
-    if s % block_q or s % block_k:
-        raise ValueError("blocks (%d, %d) do not divide sequence %d"
-                         % (block_q, block_k, s))
+    b, h, _, dh = q.shape
+    s, sk, block_q, block_k, window = _keys(
+        q, k, block_q, block_k, causal, window, prefix)
     if interpret is None:
         interpret = not _on_tpu()
-    window = _band(window, causal, s)
     scale = numpy.float32(1.0 / numpy.sqrt(dh))
     flat = (b * h, s, dh)
-    qf, kf, vf, dof = (t.reshape(flat) for t in (q, k, v, dout))
+    qf, kf, vf, dof = (t.reshape((b * h,) + t.shape[2:])
+                       for t in (q, k, v, dout))
     lse_lanes = lse.reshape(b * h, 1, s)
     if delta is None:
         delta = (dout.astype(jnp.float32)
@@ -729,7 +813,7 @@ def flash_attention_bwd(q, k, v, out, lse, dout, causal=True,
     delta_lanes = delta.astype(jnp.float32).reshape(b * h, 1, s)
     shape = (b, h, s, dh)
     grad = jax.ShapeDtypeStruct(flat, q.dtype)
-    if block_q == block_k == s <= TILE_MAX_S:
+    if prefix is None and block_q == block_k == s <= TILE_MAX_S:
         rows = _tile_rows(b * h)
         tensor, lanes = _tile_specs(rows, s, dh)
         dq, dk, dv = pl.pallas_call(
@@ -746,7 +830,8 @@ def flash_attention_bwd(q, k, v, out, lse, dout, causal=True,
         return (dq.reshape(shape), dk.reshape(shape),
                 dv.reshape(shape))
     _, qfull, qfull_lanes = _specs(block_q, s, dh)
-    kblocked, _, _ = _specs(block_k, s, dh)
+    kblocked, _, _ = _specs(block_k, sk, dh)
+    kgrad = jax.ShapeDtypeStruct((b * h, sk, dh), q.dtype)
     # dq: full-row f32 accumulator, TRANSPOSED (dh, S) so that it is
     # lane-dense, block index CONSTANT in ki so the sequential grid
     # revisits (and keeps) it in VMEM
@@ -765,16 +850,17 @@ def flash_attention_bwd(q, k, v, out, lse, dout, causal=True,
         functools.partial(_dkvq_kernel, block_q=block_q,
                           block_k=block_k, n_qb=s // block_q,
                           causal=causal, scale=scale,
-                              window=window),
-        grid=(b * h, s // block_k),
+                          window=window, prefix=prefix),
+        grid=(b * h, sk // block_k),
         in_specs=[qfull, kblocked, kblocked, qfull, qfull_lanes,
                   qfull_lanes],
         out_specs=[kblocked, kblocked, dqt_full_f32],
-        out_shape=[grad, grad,
+        out_shape=[kgrad, kgrad,
                    jax.ShapeDtypeStruct((b * h, dh, s), jnp.float32)],
         interpret=interpret,
         **params,
     )(qf, kf, vf, dof, lse_lanes, delta_lanes)
     # transpose and convert in one XLA pass
+    kshape = (b, h, sk, dh)
     return (dq_t.swapaxes(1, 2).astype(q.dtype).reshape(shape),
-            dk.reshape(shape), dv.reshape(shape))
+            dk.reshape(kshape), dv.reshape(kshape))

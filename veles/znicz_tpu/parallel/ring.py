@@ -24,8 +24,8 @@ Per ring step a three-way branch on (source shard vs mine) picks
 causal-kernel / full-kernel / skip-entirely — the skip recovers the
 causal-ring optimisation the Pallas kernel's loop bound gives on a
 single chip — and the normalized partials merge by logsumexp
-(``_merge_partial``). ``inner=None`` keeps the original fused dense
-block (the short-shard default).
+(``flash.merge_partial``). ``inner=None`` keeps the original fused
+dense block (the short-shard default).
 
 Usage: wrap in ``shard_map`` with q/k/v sharded on the sequence dim —
 :func:`ring_self_attention` does the plumbing given a mesh.
@@ -143,19 +143,6 @@ def _inner_kernels(inner, block, dot=None, interpret=None):
     return fwd, bwd
 
 
-def _merge_partial(out, lse, o_b, lse_b):
-    """logsumexp-merge of two NORMALIZED partial attentions. Guards
-    the both-empty case (lse == lse_b == -inf -> coefficient 0, not
-    nan)."""
-    import jax.numpy as jnp
-    new_lse = jnp.logaddexp(lse, lse_b)
-    empty = jnp.isneginf(new_lse)
-    c1 = jnp.where(empty, 0.0, jnp.exp(lse - new_lse))
-    c2 = jnp.where(empty, 0.0, jnp.exp(lse_b - new_lse))
-    return (out * c1[..., None]
-            + o_b.astype(jnp.float32) * c2[..., None]), new_lse
-
-
 def _ring_branches(causal, src, my, run_causal, run_full, run_skip):
     """The per-ring-step three-way dispatch: diagonal shard -> causal
     kernel, past shard -> full kernel, future shard -> skip (its
@@ -176,6 +163,7 @@ def ring_attention_fwd_flash(q, k0, v0, axis_name, causal, n_dev,
     :func:`ring_attention_fwd`."""
     import jax.numpy as jnp
     from jax import lax
+    from veles.znicz_tpu.parallel.flash import merge_partial
 
     b, h, sb, dh = q.shape
     kern_fwd, _ = _inner_kernels(inner, block, dot, interpret)
@@ -191,7 +179,7 @@ def ring_attention_fwd_flash(q, k0, v0, axis_name, causal, n_dev,
             lambda _: kern_fwd(q, k_cur, v_cur, False),
             lambda _: (jnp.zeros_like(q),
                        jnp.full((b, h, sb), -jnp.inf, jnp.float32)))
-        out, lse = _merge_partial(out, lse, o_b, lse_b)
+        out, lse = merge_partial(out, lse, o_b, lse_b)
         k_nxt = lax.ppermute(k_cur, axis_name, perm)
         v_nxt = lax.ppermute(v_cur, axis_name, perm)
         return k_nxt, v_nxt, out, lse
