@@ -640,6 +640,71 @@ def test_cpu_layer_at_the_cells_widths_holds_no_solve(block, monkeypatch):
     assert "triangular_solve" not in text
 
 
+# -- the chunks' running sum of log-decays: a product with a triangle --------
+
+#: ``running_sum``'s error against float64 over ``jnp.cumsum``'s own, for
+#: the value and the cotangent, at most
+SUM_FACTOR = 4.0
+
+
+@pytest.mark.parametrize("c", [
+    64,     # the cell's chunks
+    72,     # a sequence that is one odd chunk
+])
+@pytest.mark.parametrize("scale,dt", [
+    (1.0, 0.05),    # mild channels
+    (16.0, 1.0),    # about -16 a token: G near -1,000 within a chunk
+])
+def test_running_sum_against_cumsum_and_float64(scale, dt, c):
+    """At the cell's widths (64 chunks of 8 heads of 128; one chunk
+    where 72 tokens are one): ``G`` by the product with a triangle of
+    ones and by ``jnp.cumsum`` against the float64 running sum of the
+    same float32 log-decays, and the cotangent of a weighted sum of
+    ``G`` (the reversed running sum of the weights) likewise; the
+    product's error is within ``SUM_FACTOR`` of the cumsum's."""
+    import jax
+    import jax.numpy as jnp
+    gen = numpy.random.RandomState(29)
+    shape = (64 if c == 64 else 1, 1, 8, c, 128)
+    a = numpy.asarray(decays(gen, shape, scale, dt), numpy.float32)
+    w = numpy.asarray(gen.randn(*shape), numpy.float32)
+    if scale == 16.0:
+        assert a.sum(-2).min() < -900.0
+    a64, w64 = a.astype(numpy.float64), w.astype(numpy.float64)
+    want = (numpy.cumsum(a64, -2),
+            numpy.cumsum(w64[..., ::-1, :], -2)[..., ::-1, :])
+
+    def errors(running):
+        # the weights an argument: a constant cotangent is folded
+        value, got = jax.jit(lambda a, w: (running(a), jax.grad(
+            lambda a: (running(a) * w).sum())(a)))(
+            jnp.asarray(a), jnp.asarray(w))
+        return [float(numpy.abs(numpy.asarray(x, numpy.float64) - y).max()
+                      / numpy.abs(y).max())
+                for x, y in zip((value, got), want)]
+
+    mine = errors(delta.running_sum)
+    theirs = errors(lambda a: jnp.cumsum(a, axis=-2))
+    for got, bar in zip(mine, theirs):
+        assert got <= SUM_FACTOR * max(bar, SOLVE_FLOOR), (mine, theirs)
+
+
+@pytest.mark.parametrize("op", [
+    "cumsum",           # the primitive's name in the program
+    "reduce_window",    # what it lowers to
+])
+def test_cpu_layer_at_the_cells_widths_holds_no_running_sum_window(
+        op, monkeypatch):
+    """A delta-rule layer of 2 heads of 128 over one chunk of 64,
+    forward + backward lowered for the CPU: the chunk's running sum of
+    log-decays is a product, no ``cumsum`` and no ``reduce_window`` in
+    the program."""
+    monkeypatch.setattr(delta, "CHUNK", 64)
+    text = program_text(DeltaAttention, dict(DELTA, heads=2, head_dim=128),
+                        debug_info=True)
+    assert op not in text
+
+
 # -- the share sums to the model ---------------------------------------------
 
 

@@ -22,8 +22,10 @@ three projections are one product and one pass of taps
 The recurrence proper (:func:`delta_rule`) runs under
 ``veles.delta``, in float32, ``CHUNK`` tokens at a time. With
 ``G_t = a_1 + ... + a_t`` inside a chunk that starts from the state
-``S_0``, and ``E_ts = exp(G_t - G_s)`` (a vector over the ``dk``
-channels, taken for ``s <= t`` only, where it is at most 1):
+``S_0`` (:func:`running_sum`: the chunk's ``a`` times a lower triangle
+of ones, one float32 product), and ``E_ts = exp(G_t - G_s)`` (a vector
+over the ``dk`` channels, taken for ``s <= t`` only, where it is at
+most 1):
 
     A_ts = sum_c k_t k_s E_ts  (s < t),   B_ts = sum_c q_t k_s E_ts (s <= t)
     (I + diag(b) A) [W_v | W_k] = diag(b) [V | exp(G) * K]
@@ -212,6 +214,23 @@ def _product(a, b):
     return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
 
 
+def running_sum(a):
+    """``G_t = a_1 + ... + a_t`` along the chunk axis of ``a`` (..., C,
+    dk), float32: ONE product with a lower triangle of ones at
+    ``Precision.HIGHEST``, on the matrix unit; its transpose is the
+    product with the upper triangle. ``jnp.cumsum`` lowers on a TPU to
+    a ``reduce_window`` as wide as the chunk, C adds an element on the
+    vector unit, and as much again for its transpose."""
+    import jax
+    c = a.shape[-2]
+    # iotas of a's own type: int32 ones would be the same as
+    # unit_lower_inverse's, whose masks would then come in this
+    # product's layout and be copied
+    row = jax.lax.broadcasted_iota(a.dtype, (c, c), 0)
+    col = jax.lax.broadcasted_iota(a.dtype, (c, c), 1)
+    return _product((row >= col).astype(a.dtype), a)
+
+
 def unit_lower_inverse(l):
     """``(I + L)^-1`` for ``l`` (..., C, C) float32, of which the
     strictly lower triangle is ``L``: forward substitution by blocks
@@ -328,7 +347,7 @@ def chunked_delta_rule(q, k, v, a, beta, kernels=None):
 
     q, k, v, a = (chunks(t.astype(f32)) for t in (q, k, v, a))
     beta = chunks(beta.astype(f32)[..., None])
-    g = jnp.cumsum(a, axis=-2)
+    g = running_sum(a)
     scores, reads = jax.lax.map(
         jax.checkpoint(lambda x: chunk_scores(*x), prevent_cse=False),
         (q, k, g), batch_size=CHUNKS_AT_ONCE)
